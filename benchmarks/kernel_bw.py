@@ -17,10 +17,9 @@ of the (kernel, format, p, n) grid reports
 
 Kernels covered: ``decompress`` (codec alone), ``matvec`` /
 ``rmatvec`` (fused basis contractions), ``block_dots`` /
-``block_combine`` (fused block-GMRES contractions, per block width p),
-and ``ell_spmv`` (fused-operand SpMV).  On this CPU container the Pallas
-kernels execute in interpret mode, so wall times (and hence GB/s) are
-orientation only — the committed snapshot records the *trajectory* and is
+``block_combine`` (fused block-GMRES contractions, per block width p).
+On a CPU backend the Pallas kernels execute in interpret mode, so wall
+times (and hence GB/s) are orientation only — the committed snapshot records the *trajectory* and is
 regenerated on real accelerators by ``python -m benchmarks.run --only
 kernel_bw``.
 
@@ -43,7 +42,6 @@ DEFAULT_NS = (8192, 32768)
 DEFAULT_PS = (2, 8)
 DEFAULT_FORMATS = ("frsz2_32", "frsz2_16")
 BASIS_ROWS = 12          # m: compressed rows per basis for the contractions
-ELL_WIDTH = 27           # stencil-like row width for the SpMV cell
 TOL = 2e-5
 SCHEMA_KEYS = ("kernel", "storage", "p", "n", "bytes", "eff_bytes",
                "wall_s", "gbps", "eff_gbps", "memcpy_gbps", "ratio",
@@ -186,35 +184,6 @@ def _block_cells(storage: str, p: int, n: int, memcpy_gbps: float, rng):
     return cells
 
 
-def _spmv_cells(storage: str, n: int, memcpy_gbps: float, rng):
-    """ELL SpMV with a fused FRSZ2-compressed operand vector."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.core import frsz2 as F
-    from repro.kernels import ops
-    from repro.sparse.csr import ELL
-
-    spec = _spec_of(storage)
-    w = ELL_WIDTH
-    cols = jnp.asarray(rng.integers(0, n, (n, w)), jnp.int32)
-    vals = jnp.asarray(rng.standard_normal((n, w)), spec.dtype)
-    E = ELL(cols, vals, (n, n))
-    x = jnp.asarray(rng.standard_normal(n), spec.dtype)
-    bc = F.compress(x, spec)
-    xd = F.decompress(bc)
-    ref = E.matvec(xd, kernel=False)
-    xcomp = float(F.storage_nbytes(n, spec))
-    xdense = float(n * np.dtype(spec.dtype).itemsize)
-
-    wall, y = _wall(lambda: ops.ell_spmv(vals, cols, bc, interpret=None))
-    if y is None:  # layout outside the kernel contract: report the fallback
-        wall, y = _wall(lambda: E.matvec(xd, kernel=False))
-    return [_cell("ell_spmv", storage, 1, n, E.nbytes() + xcomp + xdense,
-                  E.nbytes() + 2 * xdense, wall, memcpy_gbps,
-                  _max_err(y, ref))]
-
-
 def run(ns=DEFAULT_NS, ps=DEFAULT_PS, formats=DEFAULT_FORMATS,
         check: bool = False, json_path: str | None = None,
         snapshot_path: str | None = None):
@@ -234,7 +203,6 @@ def run(ns=DEFAULT_NS, ps=DEFAULT_PS, formats=DEFAULT_FORMATS,
     for storage in formats:
         for n in ns:
             cells = _codec_cells(storage, n, memcpy, rng)
-            cells += _spmv_cells(storage, n, memcpy, rng)
             for p in ps:
                 cells += _block_cells(storage, p, n, memcpy, rng)
             for c in cells:
@@ -281,7 +249,7 @@ def _schema_failures(rows, snapshot_path: str | None):
                 break
         kernels = {c.get("kernel") for c in rws}
         want = {"decompress", "matvec", "rmatvec", "block_dots",
-                "block_combine", "ell_spmv"}
+                "block_combine"}
         if not want <= kernels:
             failures.append(f"{source}: kernels missing "
                             f"{sorted(want - kernels)}")

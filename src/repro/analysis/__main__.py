@@ -23,9 +23,7 @@ Modes
     PR diff.
 
 Determinism: the audits pin ``repro.kernels.ops.INTERPRET = True``
-themselves, and the sharded children are spawned with
-``REPRO_INTERPRET`` scrubbed from their environment, so results do not
-depend on the caller's shell.
+themselves, so results do not depend on the caller's shell.
 """
 from __future__ import annotations
 
@@ -85,7 +83,6 @@ def _run_child(flag: str, fallback_path: str, fallback_rule: str) -> list[Findin
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
-    env.pop("REPRO_INTERPRET", None)  # audits pin interpret mode themselves
     proc = subprocess.run(
         [sys.executable, "-m", "repro.analysis", flag],
         capture_output=True, text=True, env=env,

@@ -10,7 +10,7 @@ cannot diagnose it at trace time because each shard's trace is identical —
 the divergence only exists across devices at runtime.
 
 The walker abstractly interprets shard-variance through the jaxpr: inside
-``shard_map``, an input is *varying* iff its ``in_names`` bind it to a mesh
+``shard_map``, an input is *varying* iff its ``in_specs`` bind it to a mesh
 axis; reductions over the mesh axis (``psum``/``pmean``/``pmax``/``pmin``/
 ``all_gather`` without ``axis_index_groups``) produce *invariant* outputs —
 the mechanism that keeps the real solver's convergence predicates in
@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 from repro.analysis.report import Finding
@@ -79,7 +80,7 @@ class CollectiveSite:
 
 
 def _open(j):
-    return j.jaxpr if isinstance(j, jax.core.ClosedJaxpr) else j
+    return j.jaxpr if isinstance(j, jex_core.ClosedJaxpr) else j
 
 
 def _body_jaxpr(params):
@@ -87,9 +88,14 @@ def _body_jaxpr(params):
     remat, shard_map...), or None."""
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
         sub = params.get(key)
-        if isinstance(sub, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+        if isinstance(sub, (jex_core.ClosedJaxpr, jex_core.Jaxpr)):
             return _open(sub)
     return None
+
+
+def _binds_axis(spec) -> bool:
+    """True iff a shard_map PartitionSpec splits some dim over a mesh axis."""
+    return any(entry is not None for entry in spec)
 
 
 def _axis_names(params) -> tuple[str, ...]:
@@ -147,7 +153,7 @@ class _Walker:
         env: dict = {}
 
         def val(atom):
-            if isinstance(atom, jax.core.Literal):
+            if isinstance(atom, jex_core.Literal):
                 return False
             return env.get(atom, False)
 
@@ -194,10 +200,10 @@ class _Walker:
         params = eqn.params
         mesh = {str(k): int(v) for k, v in dict(params["mesh"].shape).items()}
         sub = _open(params["jaxpr"])
-        vals = [bool(names) for names in params["in_names"]]
+        vals = [_binds_axis(spec) for spec in params["in_specs"]]
         vals = (vals + [True] * len(sub.invars))[:len(sub.invars)]
         self.walk(sub, vals, mesh, here, loops)
-        outs = [bool(names) for names in params["out_names"]]
+        outs = [_binds_axis(spec) for spec in params["out_specs"]]
         return (outs + [True] * len(eqn.outvars))[:len(eqn.outvars)]
 
     def _while(self, eqn, ivals, mesh, here, loops):

@@ -23,18 +23,18 @@ problems and checks invariants only traces make visible:
   under ``jax.transfer_guard("disallow")``.
 
 Determinism: every entry point pins ``repro.kernels.ops.INTERPRET =
-True`` explicitly (the env-var auto-detect must not decide what CI
-measures) and enables x64.  The sharded audits need 8 devices; the CLI
+True`` explicitly (the backend must not decide what CI measures) and
+enables x64.  The sharded audits need 8 devices; the CLI
 (``repro.analysis.__main__``) re-execs itself with
-``--xla_force_host_platform_device_count=8`` and a scrubbed
-``REPRO_INTERPRET`` to run :func:`run_sharded_audits` in a child
-process.
+``--xla_force_host_platform_device_count=8`` to run
+:func:`run_sharded_audits` in a child process.
 """
 from __future__ import annotations
 
 import importlib
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -60,7 +60,7 @@ def _pin_environment():
     jax.config.update("jax_enable_x64", True)     # f64 checks non-vacuous
     from repro.kernels import ops
 
-    ops.INTERPRET = True                          # not the env auto-detect
+    ops.INTERPRET = True                          # not the backend default
 
 
 def _problem(n: int = 180):
@@ -270,9 +270,9 @@ _F64 = np.dtype(np.float64)
 
 
 def _sub_jaxprs(value):
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jex_core.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:

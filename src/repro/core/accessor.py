@@ -48,11 +48,19 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.core import frsz2 as F
 
 #: VREG lane count of the Pallas kernel layouts (repro.kernels.ops.LANES,
 #: duplicated here so the core protocol does not import the kernel stack).
 _KERNEL_LANES = 128
+
+#: Precision of every dense product in the solve (basis contractions here,
+#: the small Hessenberg/QR products in ``repro.solver``).  A TPU's default
+#: f32 pass rounds operands to bfloat16, which would cap Arnoldi
+#: orthogonality near 2^-8; HIGHEST is full f32 there and changes nothing
+#: in f64.  The Pallas kernels contract at the same precision.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 __all__ = [
     "StorageFormat",
@@ -135,7 +143,7 @@ class StorageFormat:
     def dots(self, store, w, arith_dtype, n: int):
         """h = V @ w (unmasked)."""
         V = self.read_all(store, arith_dtype, n)
-        return V @ w.astype(arith_dtype)
+        return jnp.matmul(V, w.astype(arith_dtype), precision=HIGHEST)
 
     def reduce_partials(self, x):
         """Reduce a locally-computed contraction against the basis.
@@ -151,7 +159,7 @@ class StorageFormat:
     def combine(self, store, h, arith_dtype, n: int):
         """y = h @ V (unmasked)."""
         V = self.read_all(store, arith_dtype, n)
-        return h.astype(arith_dtype) @ V
+        return jnp.matmul(h.astype(arith_dtype), V, precision=HIGHEST)
 
     # -- block-basis contract -------------------------------------------------
     def block_align(self) -> int:
@@ -176,14 +184,16 @@ class StorageFormat:
         """
         V = self.read_all(store, arith_dtype, p * n_seg)
         V = V.reshape(-1, p, n_seg)[..., :n]
-        return jnp.einsum("ian,bn->iab", V, W.astype(arith_dtype))
+        return jnp.einsum("ian,bn->iab", V, W.astype(arith_dtype),
+                          precision=HIGHEST)
 
     def block_combine(self, store, Y, arith_dtype, n: int, p: int,
                       n_seg: int):
         """``out[b] = sum_{i,a} Y[i,a,b] V[i,a]``, returned in the padded
         segment layout ``(b, n_seg)`` (the accessor trims to ``n``)."""
         V = self.read_all(store, arith_dtype, p * n_seg).reshape(-1, p, n_seg)
-        return jnp.einsum("iab,ian->bn", Y.astype(arith_dtype), V)
+        return jnp.einsum("iab,ian->bn", Y.astype(arith_dtype), V,
+                          precision=HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -807,16 +817,20 @@ def _build_emul(name, **ctx):
     return emulator_by_name(name.partition(":")[2])
 
 
-def format_by_name(name: str, *, arith_dtype=jnp.float64, bs: int = 32,
+def format_by_name(name: str, *, arith_dtype=None, bs: int = 32,
                    use_kernels: bool = False, rounding: str = "truncate",
                    target_rrn: float | None = None, m: int | None = None):
     """Resolve a storage format from the :data:`FORMATS` table.
 
     Exact names first ('float64', …), then family prefixes: 'frsz2_XX',
-    'mixed[:k|auto[:tail]]', 'emul:…'.  ``target_rrn``/``m`` are solve
-    context for self-sizing formats (``mixed:auto`` derives its head size
-    from them); the solvers thread their arguments through automatically.
+    'mixed[:k|auto[:tail]]', 'emul:…'.  ``arith_dtype`` defaults to the
+    backend's (:func:`repro.runtime.arith_dtype`).  ``target_rrn``/``m``
+    are solve context for self-sizing formats (``mixed:auto`` derives its
+    head size from them); the solvers thread their arguments through
+    automatically.
     """
+    if arith_dtype is None:
+        arith_dtype = runtime.arith_dtype()
     ctx = dict(arith_dtype=arith_dtype, bs=bs, use_kernels=use_kernels,
                rounding=rounding, target_rrn=target_rrn, m=m)
     if name in FORMATS:

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import require_codec_dtype
+
 __all__ = [
     "FrszSpec",
     "BlockCompressed",
@@ -110,6 +112,7 @@ class FrszSpec:
             raise ValueError(f"unknown rounding {self.rounding!r}")
         if self.bs < 1:
             raise ValueError("bs must be positive")
+        require_codec_dtype(self.dtype)
 
     # -- derived ------------------------------------------------------------
     @property
@@ -227,12 +230,22 @@ def _split_ieee(x: jax.Array, spec: FrszSpec):
 
 
 def _encode_block(sign, e, sig, emax, spec: FrszSpec):
-    """Steps 3-5: normalize to e_max, prepend sign, cut to l bits."""
+    """Steps 3-5 for ``(..., bs)`` blocks sharing ``emax (...)``."""
+    return _encode_values(sign, e, sig, emax[..., None], spec)
+
+
+def _encode_values(sign, e, sig, emax, spec: FrszSpec):
+    """Steps 3-5: normalize to e_max, prepend sign, cut to l bits.
+
+    ``emax`` holds each value's block exponent, broadcast to ``e``'s shape
+    (the Pallas kernels pass per-lane exponents of a 2-D tile).
+    """
     ieee = spec.ieee
     ucode = ieee["uint"]
     mant = ieee["mant"]
     l = spec.l
-    k = (emax[..., None] - e).astype(jnp.int32)  # zeros have e=0 -> huge k -> code 0
+    # zeros have e=0 -> huge k -> code 0
+    k = emax.astype(jnp.int32) - e.astype(jnp.int32)
     # target: fixed point with 1 integer bit + (l-2) fraction bits
     # c_sig = sig * 2^(l-2) / 2^(mant+k)  ->  shift = mant - (l-2) + k
     shift = mant - (l - 2) + k
@@ -258,7 +271,9 @@ def _encode_block(sign, e, sig, emax, spec: FrszSpec):
     )
     csig = jnp.where(big, jnp.zeros_like(csig), csig)
     field_max = jnp.asarray((1 << (l - 1)) - 1, ucode)
-    csig = jnp.minimum(csig, field_max)  # overflow clamp (nearest-rounding edge)
+    # overflow clamp (nearest-rounding edge); a select, since Mosaic has no
+    # unsigned min
+    csig = jnp.where(csig > field_max, field_max, csig)
     c = (sign << (l - 1)) | csig
     return c
 
@@ -294,6 +309,13 @@ def compress(x: jax.Array, spec: FrszSpec = FRSZ2_32) -> BlockCompressed:
 
 
 def _decode_block(c: jax.Array, emax: jax.Array, spec: FrszSpec) -> jax.Array:
+    """``(..., bs)`` codes sharing ``emax (...)`` -> values."""
+    return _decode_values(c, emax[..., None], spec)
+
+
+def _decode_values(c: jax.Array, emax: jax.Array, spec: FrszSpec) -> jax.Array:
+    """Codes -> values, with ``emax`` each code's block exponent broadcast
+    to ``c``'s shape (the Pallas kernels pass per-lane exponents)."""
     ieee = spec.ieee
     ucode = ieee["uint"]
     mant, expbits, l = ieee["mant"], ieee["expbits"], spec.l
@@ -305,7 +327,7 @@ def _decode_block(c: jax.Array, emax: jax.Array, spec: FrszSpec) -> jax.Array:
     # step 2: k = number of prefixed zeros in the (l-1)-wide field
     k = _field_clz(csig, l - 1).astype(jnp.int32)
     k = jnp.where(zero, jnp.zeros_like(k), k)
-    e = emax[..., None].astype(jnp.int32) - k
+    e = emax.astype(jnp.int32) - k
     # step 3: drop the leading 1; nf = l-2-k fraction bits remain
     nf = l - 2 - k
     frac = csig ^ jnp.where(

@@ -18,10 +18,6 @@ Three small modules:
                  norm/reduction hook (local vs psum-over-axis), threaded
                  through the GMRES cycle so the whole device-resident
                  driver runs inside ``shard_map``.
-
-Also installs a ``jax.shard_map`` forward-compat shim on jax versions that
-only ship ``jax.experimental.shard_map`` (callers use the modern spelling
-with ``axis_names=…, check_vma=…``).
 """
 from repro.dist import act_sharding, collectives, context, sharding
 from repro.dist.act_sharding import constrain

@@ -60,24 +60,6 @@ def halo_wire_spec(dtype) -> F.FrszSpec:
     return WIRE_SPEC
 
 
-# -- jax.shard_map forward-compat shim --------------------------------------
-# jax >= 0.5 exposes jax.shard_map(..., axis_names=..., check_vma=...);
-# on older versions route the modern spelling to jax.experimental.shard_map.
-if not hasattr(jax, "shard_map"):  # pragma: no cover - version dependent
-
-    def _shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                   axis_names=None, check_vma=None, **kw):
-        from jax.experimental.shard_map import shard_map as _sm
-
-        check_rep = kw.pop("check_rep", None)
-        if check_rep is None:
-            check_rep = bool(check_vma) if check_vma is not None else False
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep, **kw)
-
-    jax.shard_map = _shard_map
-
-
 def psum(x, axis_name: str):
     """Plain psum through the audited wire layer.
 

@@ -23,7 +23,6 @@ import dataclasses
 
 import jax.numpy as jnp
 
-# also installs the jax.shard_map forward-compat shim on import
 from repro.dist import collectives as _collectives
 
 __all__ = ["DistContext", "LOCAL"]

@@ -15,8 +15,7 @@ TPU adaptation of the paper's CUDA design (Sec. IV-C):
 
 Layout convention for all kernels: codes are presented as a 2-D array of
 shape (M, 128) — ``M = nb * bs / 128`` rows of 128 lanes — and exponents as
-(M, G) where ``G = 128 / bs`` exponents cover one row (G >= 1; for
-bs > 128 a single exponent covers R = bs/128 consecutive rows).
+(M, G) where ``G = 128 / bs`` exponents cover one row (``bs`` divides 128).
 Wrappers in ``ops.py`` do the reshaping / padding.
 """
 from __future__ import annotations
@@ -28,26 +27,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import frsz2 as F
-from repro.core.frsz2 import _decode_block, _encode_block, _split_ieee
+from repro.core.frsz2 import _decode_values, _encode_values, _split_ieee
 
 LANES = 128
 
 
-def _expand_exps_row(e_tile: jax.Array, bs: int) -> jax.Array:
-    """(R, G) block exponents -> (R, 128) per-lane exponents."""
-    R, G = e_tile.shape
-    if G == 1:
-        return jnp.broadcast_to(e_tile, (R, LANES))
-    return jnp.repeat(e_tile, bs, axis=1)
+def lane_exponents(e: jax.Array, bs: int, first: int, width: int = LANES
+                   ) -> jax.Array:
+    """Per-lane exponents of one ``width``-lane code chunk.
+
+    ``e (R, *)`` holds block exponents; the chunk's first block is column
+    ``first`` and ``bs`` divides 128.  Built from single-lane broadcasts and
+    selects only: Mosaic lowers neither the ``(R, G) -> (R, G, bs)``
+    reshape nor ``jnp.repeat`` along lanes.
+    """
+    R = e.shape[0]
+    out = jnp.broadcast_to(e[:, first:first + 1], (R, width))
+    if bs < width:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (R, width), 1)
+        for g in range(1, -(-width // bs)):
+            out = jnp.where(lane >= g * bs, e[:, first + g:first + g + 1], out)
+    return out
 
 
-def _collapse_exps_row(e_lanes: jax.Array, bs: int) -> jax.Array:
-    """(R, 128) per-lane exponents -> (R, G) block maxima."""
-    R = e_lanes.shape[0]
+def block_max(e: jax.Array, bs: int) -> list[jax.Array]:
+    """``(R, 128)`` signed per-lane exponents -> ``128 / bs`` ``(R, 1)``
+    block maxima (Mosaic reduces signed, not unsigned, integers)."""
     if bs >= LANES:
-        return e_lanes.max(axis=1, keepdims=True)
-    G = LANES // bs
-    return e_lanes.reshape(R, G, bs).max(axis=2)
+        return [e.max(axis=1, keepdims=True)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, e.shape, 1)
+    return [jnp.where((lane >= g * bs) & (lane < (g + 1) * bs), e, 0)
+            .max(axis=1, keepdims=True) for g in range(LANES // bs)]
 
 
 # ---------------------------------------------------------------------------
@@ -56,13 +66,8 @@ def _collapse_exps_row(e_lanes: jax.Array, bs: int) -> jax.Array:
 
 
 def _decompress_kernel(c_ref, e_ref, o_ref, *, spec: F.FrszSpec):
-    c = c_ref[...]
-    e = _expand_exps_row(e_ref[...], spec.bs)
-    # _decode_block consumes emax of shape c.shape[:-1] and broadcasts the
-    # trailing axis itself; here exponents are already per-lane, so feed it
-    # lane-shaped data with a fake trailing axis.
-    out = _decode_block(c[..., None], e, spec)[..., 0]
-    o_ref[...] = out
+    e = lane_exponents(e_ref[...], spec.bs, 0)
+    o_ref[...] = _decode_values(c_ref[...], e, spec)
 
 
 def decompress_2d(codes2d: jax.Array, exps2d: jax.Array, spec: F.FrszSpec,
@@ -92,14 +97,18 @@ def decompress_2d(codes2d: jax.Array, exps2d: jax.Array, spec: F.FrszSpec,
 
 def _compress_kernel(x_ref, c_ref, e_ref, *, spec: F.FrszSpec):
     # bs <= 128 only: the block max never crosses a VREG row (ops.py enforces)
-    x = x_ref[...]
-    sign, e, sig = _split_ieee(x, spec)
-    emax = _collapse_exps_row(e, spec.bs)  # (R, G), stays in the uint dtype
-    emax_lanes = _expand_exps_row(emax, spec.bs)  # (R, 128)
-    c = _encode_block(sign[..., None], e[..., None], sig[..., None],
-                      emax_lanes, spec)[..., 0]
+    sign, e, sig = _split_ieee(x_ref[...], spec)
+    e = e.astype(jnp.int32)
+    emax = block_max(e, spec.bs)
+    for g, eg in enumerate(emax):
+        e_ref[:, g:g + 1] = eg.astype(e_ref.dtype)
+    emax_lanes = emax[0]
+    if len(emax) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, e.shape, 1)
+        for g in range(1, len(emax)):
+            emax_lanes = jnp.where(lane >= g * spec.bs, emax[g], emax_lanes)
+    c = _encode_values(sign, e, sig, emax_lanes, spec)
     c_ref[...] = c.astype(c_ref.dtype)
-    e_ref[...] = emax.astype(e_ref.dtype)
 
 
 def compress_2d(x2d: jax.Array, spec: F.FrszSpec, *, block_rows: int = 256,

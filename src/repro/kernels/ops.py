@@ -1,27 +1,24 @@
 """Public, jit-friendly wrappers around the Pallas FRSZ2 kernels.
 
-Handles layout/padding so callers can use logical shapes; dispatches to the
-pure-jnp reference on CPU-hostile cases.
+Handles layout and padding so callers can use logical shapes.
 
-Interpret mode is **auto-detected**: kernels run compiled on accelerator
-backends (TPU/GPU) and in Pallas interpret mode when only CPU is present.
-Two overrides, checked in order:
-
-  * ``repro.kernels.ops.INTERPRET = True/False`` — programmatic pin
-    (``None``, the default, means auto);
-  * ``REPRO_INTERPRET=1|0|auto`` environment variable;
-
-and every wrapper still accepts an explicit ``interpret=`` argument that
-beats both.
+Dispatch rule, keyed on the backend: kernels run compiled on TPU and in
+Pallas interpret mode everywhere else.  Tests may pin interpret mode with
+``repro.kernels.ops.INTERPRET = True`` or an explicit ``interpret=``
+argument; on a TPU backend such a pin is an error, so no solver-path kernel
+can silently run interpreted on the chip.
 
 Kernel-path constraints (TPU alignment, see frsz2_kernel.py docstring):
   * aligned code widths only: l in {8, 16, 32}
   * bs divides 128 (a block never straddles a VREG row)
+
+Formats outside those constraints use the pure-jnp codec; every shape
+inside them runs the kernel (ragged edges are padded or masked, never
+routed to a jnp twin).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -30,31 +27,30 @@ import numpy as np
 from repro.core import frsz2 as F
 from repro.kernels import frsz2_kernel as K
 from repro.kernels import frsz2_dot as KD
-from repro.kernels import frsz2_block as KB
-from repro.kernels import ell_spmv as KE
 from repro.kernels import decode_attn as KA
 
 LANES = 128
 
-#: tri-state interpret pin: ``None`` = auto-detect (env var, then backend);
-#: ``True``/``False`` forces interpret/compiled for all wrapper calls that
-#: don't pass ``interpret=`` explicitly.
+#: interpret pin for tests: ``None`` follows the backend; ``True`` forces
+#: interpret mode (refused on TPU), ``False`` forces compiled kernels.
 INTERPRET: bool | None = None
 
-_ACCEL_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
-_TRUTHY = ("1", "true", "yes", "on")
-_FALSY = ("0", "false", "no", "off")
 
-
-def _default_interpret() -> bool:
-    if INTERPRET is not None:
-        return INTERPRET
-    env = os.environ.get("REPRO_INTERPRET", "").strip().lower()
-    if env in _TRUTHY:
-        return True
-    if env in _FALSY:
-        return False
-    return jax.default_backend() not in _ACCEL_BACKENDS
+def _resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode for one kernel call: the explicit argument, else the
+    :data:`INTERPRET` pin, else ``True`` exactly when the backend is not TPU.
+    """
+    if interpret is None:
+        interpret = INTERPRET
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise RuntimeError(
+            "Pallas interpret mode was requested on a TPU backend; the chip "
+            "path runs compiled kernels only (unset ops.INTERPRET and drop "
+            "interpret=True)")
+    return bool(interpret)
 
 
 def kernel_supported(spec: F.FrszSpec) -> bool:
@@ -65,11 +61,9 @@ def kernel_supported(spec: F.FrszSpec) -> bool:
 def _pick_block_rows(M: int, cap: int = 256) -> tuple[int, int]:
     """``(M_pad, br)``: rows padded to a supported multiple, then tiled.
 
-    Earlier revisions returned the largest divisor of the *raw* row count,
-    which degenerated to a row-per-grid-step kernel (``br=1``) for prime or
-    odd ``M``.  Rows are now padded up to the f32 sublane multiple (8)
-    first, so the chosen tile is always >= 8 rows; callers slice the pad
-    rows back off the kernel output.
+    Rows are padded up to the f32 sublane multiple (8) first, so the chosen
+    tile is always >= 8 rows (never a row-per-grid-step kernel for prime
+    or odd ``M``); callers slice the pad rows back off the kernel output.
     """
     M_pad = max(8, -(-M // 8) * 8)
     for br in (cap, 128, 64, 32, 16, 8):
@@ -78,51 +72,39 @@ def _pick_block_rows(M: int, cap: int = 256) -> tuple[int, int]:
     return M_pad, 8
 
 
-def _pad_rows_to(a: jax.Array, rows: int, axis: int = 0) -> jax.Array:
-    pad = rows - a.shape[axis]
-    if pad == 0:
-        return a
-    widths = [(0, 0)] * a.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(a, widths)
-
-
-def _pad_rows(a: jax.Array, mult: int, axis: int = 0):
-    n = a.shape[axis]
-    pad = (-n) % mult
-    if pad == 0:
-        return a, n
-    widths = [(0, 0)] * a.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(a, widths), n
-
-
 # ---------------------------------------------------------------------------
 # compress / decompress with logical (batch..., n) shapes
 # ---------------------------------------------------------------------------
 
 
+def _lane_rows(flat: jax.Array, width: int = LANES):
+    """1-D ``flat`` -> zero-padded ``(M_pad, width)`` rows and the tile
+    rows ``br`` of the kernel grid."""
+    total = flat.shape[0]
+    M_pad, br = _pick_block_rows(-(-total // width))
+    return jnp.pad(flat, (0, M_pad * width - total)).reshape(-1, width), br
+
+
 def compress(x: jax.Array, spec: F.FrszSpec, *, interpret: bool | None = None
              ) -> F.BlockCompressed:
-    """Kernel-backed version of ``repro.core.frsz2.compress``."""
+    """Kernel-backed version of ``repro.core.frsz2.compress``.
+
+    Blocks are laid out ``128 / bs`` to a row of 128 lanes; zero blocks pad
+    the last row and are sliced off (``bs`` divides 128, so no block
+    straddles a row).
+    """
     if not kernel_supported(spec):
         return F.compress(x, spec)
-    if interpret is None:
-        interpret = _default_interpret()
     *batch, n = x.shape
     nb = -(-n // spec.bs)
-    n_pad = nb * spec.bs
-    total = int(np.prod(batch, dtype=np.int64)) * n_pad if batch else n_pad
-    if total % LANES != 0:
-        return F.compress(x, spec)  # too ragged for the 128-lane layout
-    xp = jnp.pad(x, [(0, 0)] * len(batch) + [(0, n_pad - n)]) if n_pad != n else x
-    x2d = xp.reshape(-1, LANES).astype(spec.dtype)
-    M = x2d.shape[0]
-    M_pad, br = _pick_block_rows(M)
-    x2d = _pad_rows_to(x2d, M_pad)
-    codes2d, exps2d = K.compress_2d(x2d, spec, block_rows=br, interpret=interpret)
-    codes = codes2d[:M].reshape(*batch, nb, spec.bs)
-    exps = exps2d[:M].reshape(*batch, nb)
+    xp = jnp.pad(x, [(0, 0)] * len(batch) + [(0, nb * spec.bs - n)])
+    flat = xp.astype(spec.dtype).reshape(-1)
+    x2d, br = _lane_rows(flat)
+    codes2d, exps2d = K.compress_2d(x2d, spec, block_rows=br,
+                                    interpret=_resolve_interpret(interpret))
+    total = flat.shape[0]
+    codes = codes2d.reshape(-1)[:total].reshape(*batch, nb, spec.bs)
+    exps = exps2d.reshape(-1)[:total // spec.bs].reshape(*batch, nb)
     return F.BlockCompressed(codes=codes, exps=exps, n=n, spec=spec)
 
 
@@ -131,75 +113,87 @@ def decompress(bc: F.BlockCompressed, *, interpret: bool | None = None) -> jax.A
     spec = bc.spec
     if not kernel_supported(spec):
         return F.decompress(bc)
-    if interpret is None:
-        interpret = _default_interpret()
     *batch, nb, bs = bc.codes.shape
-    total = int(np.prod(batch, dtype=np.int64)) * nb * bs if batch else nb * bs
-    if total % LANES != 0:
-        return F.decompress(bc)
-    G = LANES // spec.bs
-    codes2d = bc.codes.reshape(-1, LANES)
-    exps2d = bc.exps.reshape(-1, G)
-    M = codes2d.shape[0]
-    M_pad, br = _pick_block_rows(M)
-    codes2d = _pad_rows_to(codes2d, M_pad)
-    exps2d = _pad_rows_to(exps2d, M_pad)
-    x2d = K.decompress_2d(codes2d, exps2d, spec, block_rows=br, interpret=interpret)
-    x = x2d[:M].reshape(*batch, nb * bs)
-    return x[..., : bc.n]
+    codes2d, br = _lane_rows(bc.codes.reshape(-1))
+    exps2d, _ = _lane_rows(bc.exps.reshape(-1), LANES // bs)
+    x2d = K.decompress_2d(codes2d, exps2d, spec, block_rows=br,
+                          interpret=_resolve_interpret(interpret))
+    x = x2d.reshape(-1)[:nb * bs * int(np.prod(batch, dtype=np.int64))]
+    return x.reshape(*batch, nb * bs)[..., : bc.n]
 
 
 # ---------------------------------------------------------------------------
-# fused decompress-matvec over a compressed row basis V (m, n)
+# fused decompress-contractions over a compressed row basis V (m, n)
 # ---------------------------------------------------------------------------
+
+
+# A whole reduction axis up to this size runs as ONE kernel tile (a single
+# MXU contraction, no cross-tile accumulation).  Longer axes tile at the
+# largest multiple of ``128 * bs`` (lane-aligned exponent tiles) up to 4096
+# values, or at ``128 * bs`` itself when that is larger.
+MAX_SINGLE_TILE = 8192
+#: f32 bytes of one decoded tile; sizes the row tile (VMEM budget).
+TILE_BYTES = 4 * 1024 * 1024
+
+
+def _tile_n(n: int, bs: int) -> int:
+    unit = LANES * bs
+    if n <= max(MAX_SINGLE_TILE, unit):
+        return n
+    return unit * max(1, 4096 // unit)
+
+
+def _tile_m(m: int, bn: int, align: int) -> int:
+    """Rows per tile: all ``m`` when the decoded tile fits the budget, else
+    the largest multiple of ``align`` that does."""
+    cap = max(align, TILE_BYTES // (4 * bn) // align * align)
+    return m if m <= cap else cap
+
+
+@functools.lru_cache(maxsize=4096)
+def _dot_layout(m: int, n: int, bs: int) -> tuple[int, int]:
+    """``(bm, bn)`` of the dots kernel over an ``(m, n)`` code matrix.
+
+    Row tiles are multiples of 32 (the sublane tile of 8-bit codes).
+    Memoized on the shape key: repeated same-shape solves — every warm
+    GMRES cycle — skip the host-side tile arithmetic entirely.
+    """
+    bn = _tile_n(n, bs)
+    return _tile_m(m, bn, 32), bn
+
+
+@functools.lru_cache(maxsize=4096)
+def _reduce_layout(m: int, n: int, bs: int) -> tuple[int, int]:
+    """``(bm, bn)`` of the combine kernel: its row tile is also the lane
+    dim of the coefficient block, so a partial row tile is 128-aligned."""
+    bn = _tile_n(n, bs)
+    return _tile_m(m, bn, LANES), bn
 
 
 def _basis_2d(bc: F.BlockCompressed):
-    """(m, nb, bs) codes -> (m, n_pad) element codes + (m, nb) exps."""
-    m, nb, bs = bc.codes.shape
-    return bc.codes.reshape(m, nb * bs), bc.exps, nb * bs
+    """(m, nb, bs) codes -> (m, nb * bs) element codes + (m, nb) exps."""
+    m = bc.codes.shape[0]
+    return bc.codes.reshape(m, -1), bc.exps
 
 
-# A whole reduction axis up to this size runs as ONE kernel tile: the dot is
-# then a single MXU contraction, bit-identical to the pure-jnp oracle (the
-# multi-tile path is Kahan-compensated but still order-sensitive).  8192 f32
-# values x 8 rows is ~256 KB of VMEM — comfortably under budget.
-MAX_SINGLE_TILE = 8192
+def _dots(codes, exps, W, spec, interpret):
+    bm, bn = _dot_layout(*codes.shape, spec.bs)
+    return KD.dots_2d(codes, exps, W.astype(spec.dtype), spec, bm=bm, bn=bn,
+                      interpret=_resolve_interpret(interpret))
 
 
-def _tile_n(n_pad: int, bn: int, bs: int) -> int:
-    if n_pad <= MAX_SINGLE_TILE:
-        return n_pad
-    bn_eff = min(bn, n_pad)
-    while n_pad % bn_eff:
-        bn_eff //= 2
-    return max(bn_eff, bs)
+def _combine(codes, exps, Y, spec, interpret):
+    bm, bn = _reduce_layout(*codes.shape, spec.bs)
+    return KD.combine_2d(codes, exps, Y.astype(spec.dtype), spec, bm=bm,
+                         bn=bn, interpret=_resolve_interpret(interpret))
 
 
-@functools.lru_cache(maxsize=4096)
-def _dot_layout(m: int, n_pad: int, bs: int, bn: int):
-    """``(ok, m_pad, bn_eff)`` for the fused basis contractions.
-
-    Memoized on the (shape, spec) key: repeated same-shape solves — every
-    warm GMRES cycle — skip the host-side tile arithmetic entirely.
-    """
-    bn_eff = _tile_n(n_pad, bn, bs)
-    ok = n_pad % bn_eff == 0 and bn_eff % LANES == 0
-    m_pad, _ = _pick_block_rows(m)
-    return ok, m_pad, bn_eff
+def _pad_to(x: jax.Array, n: int) -> jax.Array:
+    return x if x.shape[-1] == n else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, n - x.shape[-1])])
 
 
-@functools.lru_cache(maxsize=4096)
-def _reduce_layout(m: int, n_pad: int, bs: int, bn: int):
-    """``_dot_layout`` plus the row-reduction tile ``bm_eff``: a single-tile
-    m reduction when the whole decoded tile fits VMEM (the contraction is
-    then one MXU dot, no cross-tile accumulation at all)."""
-    ok, m_pad, bn_eff = _dot_layout(m, n_pad, bs, bn)
-    one_tile = m_pad <= 512 and m_pad * bn_eff * 4 <= 4 * 1024 * 1024
-    return ok, m_pad, bn_eff, (m_pad if one_tile else 8)
-
-
-def matvec(bc: F.BlockCompressed, x: jax.Array, *, bn: int = 2048,
+def matvec(bc: F.BlockCompressed, x: jax.Array, *,
            interpret: bool | None = None) -> jax.Array:
     """y = decompress(V) @ x  for V (m, n) compressed row-wise.
 
@@ -211,30 +205,17 @@ def matvec(bc: F.BlockCompressed, x: jax.Array, *, bn: int = 2048,
         return jax.vmap(
             lambda c, e, xx: matvec(
                 F.BlockCompressed(codes=c, exps=e, n=bc.n, spec=spec), xx,
-                bn=bn, interpret=interpret)
+                interpret=interpret)
         )(bc.codes, bc.exps, x)
     if not kernel_supported(spec):
         V = F.decompress(bc)
         return V @ x.astype(V.dtype)
-    if interpret is None:
-        interpret = _default_interpret()
-    codes, exps, n_pad = _basis_2d(bc)
-    m = codes.shape[0]
-    ok, m_pad, bn_eff = _dot_layout(m, n_pad, spec.bs, bn)
-    if not ok:
-        V = F.decompress(bc)
-        return V @ x.astype(V.dtype)
-    xp = x.astype(spec.dtype)
-    if n_pad != bc.n:
-        xp = jnp.pad(xp, (0, n_pad - bc.n))
-    codes = _pad_rows_to(codes, m_pad)
-    exps = _pad_rows_to(exps, m_pad)
-    y = KD.matvec_2d(codes, exps, xp[:, None], spec, bm=8, bn=bn_eff,
-                     interpret=interpret)
-    return y[:m, 0]
+    codes, exps = _basis_2d(bc)
+    return _dots(codes, exps, _pad_to(x[None, :], codes.shape[1]), spec,
+                 interpret)[:, 0]
 
 
-def rmatvec(bc: F.BlockCompressed, h: jax.Array, *, bn: int = 2048,
+def rmatvec(bc: F.BlockCompressed, h: jax.Array, *,
             interpret: bool | None = None) -> jax.Array:
     """y = h @ decompress(V)  for V (m, n) compressed row-wise.
 
@@ -245,25 +226,13 @@ def rmatvec(bc: F.BlockCompressed, h: jax.Array, *, bn: int = 2048,
         return jax.vmap(
             lambda c, e, hh: rmatvec(
                 F.BlockCompressed(codes=c, exps=e, n=bc.n, spec=spec), hh,
-                bn=bn, interpret=interpret)
+                interpret=interpret)
         )(bc.codes, bc.exps, h)
     if not kernel_supported(spec):
         V = F.decompress(bc)
         return h.astype(V.dtype) @ V
-    if interpret is None:
-        interpret = _default_interpret()
-    codes, exps, n_pad = _basis_2d(bc)
-    m = codes.shape[0]
-    ok, m_pad, bn_eff, bm_eff = _reduce_layout(m, n_pad, spec.bs, bn)
-    if not ok:
-        V = F.decompress(bc)
-        return h.astype(V.dtype) @ V
-    codes = _pad_rows_to(codes, m_pad)
-    exps = _pad_rows_to(exps, m_pad)
-    hp = jnp.pad(h.astype(spec.dtype), (0, m_pad - m))
-    y = KD.rmatvec_2d(codes, exps, hp[None, :], spec, bm=bm_eff, bn=bn_eff,
-                      interpret=interpret)
-    return y[0, : bc.n]
+    codes, exps = _basis_2d(bc)
+    return _combine(codes, exps, h[None, :], spec, interpret)[0, : bc.n]
 
 
 # ---------------------------------------------------------------------------
@@ -272,152 +241,59 @@ def rmatvec(bc: F.BlockCompressed, h: jax.Array, *, bn: int = 2048,
 
 
 @functools.lru_cache(maxsize=4096)
-def _block_layout(m: int, p: int, n_flat: int, bs: int, bn: int):
-    """``(ok, n_seg, m_pad, bn_eff)`` for the block contractions.
+def _block_layout(p: int, n_flat: int, bs: int) -> int:
+    """Segment length ``n_seg`` of a flattened block store of ``p``
+    segments, viewed by the kernels as ``(m * p, n_seg)`` rows.
 
-    The flattened store holds ``m`` rows of ``p`` segments, each ``n_seg``
-    elements; the kernels view it as ``(m * p, n_seg)``, which requires the
-    segment length to be a whole number of codec blocks *and* of VREG lane
-    groups (``BlockBasisAccessor`` aligns segments via ``block_align`` so
-    this holds for every store it builds).
+    The view requires the segment to be a whole number of codec blocks;
+    ``BlockBasisAccessor`` aligns segments via ``block_align`` so every
+    store it builds satisfies this.
     """
-    if p <= 0 or n_flat % p:
-        return False, 0, 0, 0
-    n_seg = n_flat // p
-    if n_seg % bs:
-        return False, n_seg, 0, 0
-    ok, m_pad, bn_eff = _dot_layout(m * p, n_seg, bs, bn)
-    return ok, n_seg, m_pad, bn_eff
+    if p <= 0 or n_flat % p or (n_flat // p) % bs:
+        raise ValueError(
+            f"flattened block row of {n_flat} values does not split into "
+            f"p={p} segments of whole {bs}-value codec blocks")
+    return n_flat // p
 
 
-def _block_basis_2d(bc: F.BlockCompressed, p: int, n_seg: int):
+def _block_basis_2d(bc: F.BlockCompressed, p: int):
     """Flat (m, nb, bs) codes -> (m*p, n_seg) element codes + exps."""
-    m = bc.codes.shape[0]
-    spec = bc.spec
-    codes = bc.codes.reshape(m * p, n_seg)
-    exps = bc.exps.reshape(m * p, n_seg // spec.bs)
-    return codes, exps
+    m, nb, bs = bc.codes.shape
+    n_seg = _block_layout(p, nb * bs, bc.spec.bs)
+    return (bc.codes.reshape(m * p, n_seg),
+            bc.exps.reshape(m * p, n_seg // bc.spec.bs), n_seg)
 
 
 def block_dots(bc: F.BlockCompressed, W: jax.Array, *, p: int,
-               bn: int = 2048, interpret: bool | None = None):
+               interpret: bool | None = None):
     """``H (m, p, q) = einsum('ian,bn->iab', decompress(V), W)`` fused.
 
     ``bc`` holds ``m`` flattened block rows of ``p`` segment-aligned
     per-RHS segments; ``W (q, n_log)`` with ``n_log <= n_seg`` is
     zero-padded to the segment length (pad columns of the store decode to
-    exact zeros, so the contraction is unaffected).  Returns ``None`` off
-    the kernel path — the caller owns the jnp fallback.
+    exact zeros, so the contraction is unaffected).  Returns ``None`` for
+    a spec without kernels — the caller owns the jnp route.
     """
-    spec = bc.spec
-    if not kernel_supported(spec):
+    if not kernel_supported(bc.spec):
         return None
-    m, nb, bs = bc.codes.shape
-    ok, n_seg, m_pad, bn_eff = _block_layout(m, p, nb * bs, spec.bs, bn)
-    if not ok:
-        return None
-    if interpret is None:
-        interpret = _default_interpret()
-    codes, exps = _block_basis_2d(bc, p, n_seg)
-    q, n_log = W.shape
-    X = W.astype(spec.dtype).T
-    if n_log != n_seg:
-        X = jnp.pad(X, ((0, n_seg - n_log), (0, 0)))
-    codes = _pad_rows_to(codes, m_pad)
-    exps = _pad_rows_to(exps, m_pad)
-    Y = KB.block_dots_2d(codes, exps, X, spec, bm=8, bn=bn_eff,
-                         interpret=interpret)
-    return Y[: m * p].reshape(m, p, q)
+    codes, exps, n_seg = _block_basis_2d(bc, p)
+    Y = _dots(codes, exps, _pad_to(W, n_seg), bc.spec, interpret)
+    return Y.reshape(bc.codes.shape[0], p, W.shape[0])
 
 
 def block_combine(bc: F.BlockCompressed, Y: jax.Array, *, p: int,
-                  bn: int = 2048, interpret: bool | None = None):
+                  interpret: bool | None = None):
     """``out (q, n_seg) = einsum('iab,ian->bn', Y, decompress(V))`` fused.
 
     ``Y (m, p, q)`` are the block couplings; the caller trims the result's
     segment padding back to the logical vector length.  Returns ``None``
-    off the kernel path.
+    for a spec without kernels.
     """
-    spec = bc.spec
-    if not kernel_supported(spec):
+    if not kernel_supported(bc.spec):
         return None
-    m, nb, bs = bc.codes.shape
-    ok, n_seg, m_pad, bn_eff = _block_layout(m, p, nb * bs, spec.bs, bn)
-    if not ok:
-        return None
-    if interpret is None:
-        interpret = _default_interpret()
-    codes, exps = _block_basis_2d(bc, p, n_seg)
-    q = Y.shape[-1]
-    _, _, _, bm_eff = _reduce_layout(m * p, n_seg, spec.bs, bn)
-    h = Y.astype(spec.dtype).reshape(m * p, q).T
-    h = _pad_rows_to(h, m_pad, axis=1)
-    codes = _pad_rows_to(codes, m_pad)
-    exps = _pad_rows_to(exps, m_pad)
-    out = KB.block_combine_2d(codes, exps, h, spec, bm=bm_eff, bn=bn_eff,
-                              interpret=interpret)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# ELL SpMV (optionally consuming an FRSZ2-compressed operand)
-# ---------------------------------------------------------------------------
-
-
-def spmv_use_kernel() -> bool:
-    """ELL SpMV kernel dispatch default: compiled accelerator backends only.
-
-    Unlike the basis contractions (where interpret mode is the CPU
-    correctness path and the jnp route is equivalent traffic), the jnp
-    gather SpMV is already the right CPU implementation — the Pallas
-    kernel only wins where it compiles.  ``REPRO_INTERPRET``/``INTERPRET``
-    force-interpret pins therefore also force the jnp route here.
-    """
-    if INTERPRET is not None:
-        return not INTERPRET
-    env = os.environ.get("REPRO_INTERPRET", "").strip().lower()
-    if env in _TRUTHY:
-        return False
-    return jax.default_backend() in _ACCEL_BACKENDS
-
-
-@functools.lru_cache(maxsize=4096)
-def _ell_layout(nr: int):
-    """``(nr_pad, bm)`` row padding/tiling for the ELL SpMV grid."""
-    return _pick_block_rows(nr)
-
-
-def ell_spmv(vals: jax.Array, cols: jax.Array, x, *,
-             interpret: bool | None = None):
-    """``y (nr,) = ELL(vals, cols) @ x`` through the Pallas kernel.
-
-    ``x`` is a dense vector or an FRSZ2 :class:`~repro.core.frsz2.
-    BlockCompressed` operand (fused in-register decode — the
-    compressed-halo wire format feeds the matvec directly).  Returns
-    ``None`` off the kernel path; the caller owns the jnp fallback.
-    """
-    nr, w = vals.shape
-    nr_pad, bm = _ell_layout(nr)
-    if interpret is None:
-        interpret = _default_interpret()
-    vp = _pad_rows_to(vals, nr_pad)
-    cp = _pad_rows_to(cols, nr_pad)
-    if isinstance(x, F.BlockCompressed):
-        spec = x.spec
-        if not kernel_supported(spec):
-            return None
-        nb = x.codes.shape[-2]
-        n_pad = nb * spec.bs
-        if n_pad % LANES:
-            return None
-        xcodes = x.codes.reshape(1, n_pad)
-        xexps = x.exps.reshape(1, nb)
-        y = KE.ell_spmv_frsz2_2d(vp, cp, xcodes, xexps, spec, bm=bm,
-                                 interpret=interpret)
-    else:
-        y = KE.ell_spmv_2d(vp, cp, x[None, :].astype(vals.dtype), bm=bm,
-                           interpret=interpret)
-    return y[:nr, 0]
+    codes, exps, _ = _block_basis_2d(bc, p)
+    return _combine(codes, exps, Y.reshape(codes.shape[0], -1).T, bc.spec,
+                    interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -437,8 +313,7 @@ def decode_attention(q: jax.Array, k_bc: F.BlockCompressed,
     B, H, D = q.shape
     _, Hkv, S, nbd = k_bc.exps.shape
     G = H // Hkv
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _resolve_interpret(interpret)
     if not kernel_supported(spec):
         from repro.kernels import ref
         return ref.decode_attn_ref(

@@ -3,6 +3,9 @@
   python -m repro.launch.solve --problem synth:atmosmod --n 8000 \
       --formats float64,float32,frsz2_32,float16
 
+Arithmetic follows the backend (``repro.runtime``): float32 over a float32
+operator on TPU, float64 elsewhere.
+
 ``--driver device`` (default) runs each solve as one device-resident XLA
 program (``lax.while_loop`` restart loop, zero host syncs); ``--driver
 host`` is the seed python-looped driver for overhead comparison.
@@ -44,9 +47,10 @@ import argparse
 import json
 import time
 
-import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro import runtime
 from repro.solver import gmres
 from repro.solver.gmres import gmres_batched
 from repro.sparse import make_problem, rhs_for
@@ -71,8 +75,8 @@ def solve_suite(problem: str, n: int, formats: list[str], *, m: int = 100,
                 shard_transport: str = "plain", shard_matvec: str = "auto",
                 shard_grid=None, reorder: str = "auto",
                 verbose: bool = True):
-    jax.config.update("jax_enable_x64", True)
-    A, rrn = make_problem(problem, n)
+    dtype = runtime.configure_arithmetic()
+    A, rrn = make_problem(problem, n, dtype=np.dtype(dtype))
     if target_rrn is not None:
         rrn = target_rrn
     b, x_sol = rhs_for(A)
@@ -179,6 +183,7 @@ def main(argv=None):
                          "(repro.sparse.plan)")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
     shard_grid = None
     if args.shard_grid and args.shard_grid != "auto":
         try:

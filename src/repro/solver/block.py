@@ -52,7 +52,7 @@ The hot contractions (``block_dots``/``block_combine`` in the block
 orthogonalizers and the solution update) dispatch through the
 ``StorageFormat`` protocol: FRSZ2 storage with ``use_kernels`` routes them
 through the fused decode-inside-contraction Pallas kernels
-(``repro.kernels.frsz2_block``), so the compressed block basis is expanded
+(``repro.kernels.frsz2_dot``), so the compressed block basis is expanded
 in-register per tile instead of materializing the decoded ``(m+1, p, n)``
 array in HBM each sweep (the jaxpr-level fusion proof lives in
 ``tests/test_block_kernels.py``, built on :func:`build_block_solve`).

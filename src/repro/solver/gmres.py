@@ -79,7 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.accessor import BasisAccessor
+from repro.core.accessor import HIGHEST, BasisAccessor
 from repro.dist.context import LOCAL
 from repro.solver.pipeline import (
     orthogonalizer_by_name,
@@ -87,7 +87,7 @@ from repro.solver.pipeline import (
     resolve_preconditioner,
 )
 
-__all__ = ["GmresResult", "gmres", "gmres_batched", "cb_gmres"]
+__all__ = ["GmresResult", "gmres", "gmres_batched", "cb_gmres", "solve_program"]
 
 _TINY = 1e-300
 
@@ -216,7 +216,7 @@ def _solve_and_update(acc: BasisAccessor, store, R, g, j_stop, x0, precond):
 
     def back(i, y):
         jj = m - 1 - i
-        s = gm[jj] - jnp.dot(Rm[jj], y)
+        s = gm[jj] - jnp.dot(Rm[jj], y, precision=HIGHEST)
         yi = s / Rm[jj, jj]
         return y.at[jj].set(jnp.where(active[jj], yi, 0.0))
 
@@ -361,7 +361,7 @@ def _block_solve_and_update(acc, store, R, G, j_stop, X0, precond):
 
     def back(i, Y):
         jj = mp - 1 - i
-        s = Gm[jj] - Rm[jj] @ Y
+        s = Gm[jj] - jnp.matmul(Rm[jj], Y, precision=HIGHEST)
         yi = s / Rm[jj, jj]
         return Y.at[jj].set(jnp.where(solved[jj], yi, 0.0))
 
@@ -904,34 +904,64 @@ def gmres(
             arith_dtype=arith_dtype, eta=eta, matvec=matvec, shard=shard,
             transport=shard_transport, partition_mode=shard_matvec,
             reorder=reorder, pgrid=shard_grid)
-    plan = _plan_unsharded(A, reorder, user_matvec)
+    if driver == "device":
+        solve, args, plan = solve_program(
+            A, b, x0=x0, storage=storage, policy=policy, precond=precond,
+            ortho=ortho, m=m, max_iters=max_iters, target_rrn=target_rrn,
+            arith_dtype=arith_dtype, eta=eta, matvec=matvec, reorder=reorder)
+        res = _device_result(solve(*args))
+    elif driver == "host":
+        plan, A, b, x0, accs, policy, _, matvec, precond, ortho = _prepare(
+            A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
+            matvec, target_rrn, reorder)
+        op_key, pins = _operator_key(A, user_matvec, plan)
+        res = _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn,
+                          eta, ortho, precond, x0=x0, op_key=op_key,
+                          pins=pins + (precond,))
+    else:
+        raise ValueError(f"unknown driver {driver!r}")
+    if plan is not None:
+        res.x = plan.unpermute(res.x)
+    return res
+
+
+def _prepare(A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
+             matvec, target_rrn, reorder):
+    """Plan (and permute into) the operator's coordinates, then resolve the
+    pipeline, for one unsharded solve."""
+    plan = _plan_unsharded(A, reorder, matvec)
     if plan is not None:
         precond = _permuted_precond(precond, plan)
         A = plan.operator
         b = plan.permute(b)
         if x0 is not None:
             x0 = plan.permute(x0)
-    accs, policy, arith_dtype, matvec, precond, ortho = _resolve(
+    accs, policy, arith_dtype, mv, precond, ortho = _resolve(
         A, b, storage, policy, m, arith_dtype, matvec, precond, ortho,
         target_rrn)
-    b = b.astype(arith_dtype)
+    return (plan, A, b.astype(arith_dtype), x0, accs, policy, arith_dtype,
+            mv, precond, ortho)
 
-    if driver == "host":
-        op_key, pins = _operator_key(A, user_matvec, plan)
-        res = _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn,
-                          eta, ortho, precond, x0=x0, op_key=op_key,
-                          pins=pins + (precond,))
-    elif driver != "device":
-        raise ValueError(f"unknown driver {driver!r}")
-    else:
-        x0 = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
-        solve = _cached_solve(A, user_matvec, False, matvec, accs, policy,
-                              m, max_iters, eta, target_rrn, ortho, precond,
-                              plan)
-        res = _device_result(solve(b, x0))
-    if plan is not None:
-        res.x = plan.unpermute(res.x)
-    return res
+
+def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
+                  ortho="mgs", m: int = 100, max_iters: int = 20000,
+                  target_rrn: float = 1e-14, arith_dtype=None,
+                  eta: float = 0.7071067811865475, matvec=None,
+                  reorder: str = "auto"):
+    """The one-device program that ``gmres(A, b, ...)`` runs.
+
+    Returns ``(solve, args, plan)``: the cached jitted solve, the
+    arguments ``gmres`` calls it with, and the reordering plan (``None``
+    without one).  ``solve.lower(*args).compile()`` is the executable
+    ``gmres`` runs, for reading its memory analysis and HLO.
+    """
+    plan, A, b, x0, accs, policy, arith_dtype, mv, precond, ortho = _prepare(
+        A, b, x0, storage, policy, precond, ortho, m, arith_dtype, matvec,
+        target_rrn, reorder)
+    x0 = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
+    solve = _cached_solve(A, matvec, False, mv, accs, policy, m, max_iters,
+                          eta, target_rrn, ortho, precond, plan)
+    return solve, (b, x0), plan
 
 
 def gmres_batched(

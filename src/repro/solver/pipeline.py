@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.accessor import StorageFormat, format_by_name
+from repro.core.accessor import HIGHEST, StorageFormat, format_by_name
 from repro.dist.context import LOCAL
 
 __all__ = [
@@ -207,10 +207,10 @@ def block_qr(W, dist=LOCAL, scale=None):
     for k in range(p):
         wk = W[k]
         if k:
-            r = dist.sum(Q[:k] @ wk)
-            wk = wk - r @ Q[:k]
-            r2 = dist.sum(Q[:k] @ wk)
-            wk = wk - r2 @ Q[:k]
+            r = dist.sum(jnp.matmul(Q[:k], wk, precision=HIGHEST))
+            wk = wk - jnp.matmul(r, Q[:k], precision=HIGHEST)
+            r2 = dist.sum(jnp.matmul(Q[:k], wk, precision=HIGHEST))
+            wk = wk - jnp.matmul(r2, Q[:k], precision=HIGHEST)
             T = T.at[:k, k].set(r + r2)
         nrm = dist.norm(wk)
         dep_k = nrm <= DEFLATE_RTOL * block_scale + _TINY

@@ -147,29 +147,12 @@ class ELL:
     def dtype(self):
         return self.vals.dtype
 
-    def matvec(self, x: jax.Array, *, kernel: bool | None = None) -> jax.Array:
-        """y = A @ x.
-
-        ``kernel=None`` auto-selects the Pallas ELL SpMV on compiled
-        accelerator backends (``repro.kernels.ops.spmv_use_kernel``) and
-        the jnp gather on CPU; ``True``/``False`` pin the route (the
-        kernel still honors the ``REPRO_INTERPRET`` tri-state).  ``x`` may
-        also be an FRSZ2 ``BlockCompressed`` operand on the kernel route —
-        the decode is fused into the SpMV (compressed-halo transport feeds
-        the matvec without materializing the uncompressed vector); the
-        fallback decompresses first.
-        """
-        from repro.kernels import ops as kops
-
-        if kernel is None:
-            kernel = kops.spmv_use_kernel()
-        if kernel:
-            y = kops.ell_spmv(self.vals, self.cols, x)
-            if y is not None:
-                return y
+    def matvec(self, x: jax.Array) -> jax.Array:
+        """y = A @ x (XLA gather).  ``x`` may also be an FRSZ2
+        ``BlockCompressed`` operand, decompressed first."""
         from repro.core import frsz2 as F
 
-        if isinstance(x, F.BlockCompressed):  # compressed operand fallback
+        if isinstance(x, F.BlockCompressed):
             x = F.decompress(x)
         return (self.vals * x[self.cols].astype(self.vals.dtype)).sum(axis=1)
 

@@ -1,4 +1,4 @@
-"""Fused block-contraction + ELL SpMV kernels: oracle parity, layout
+"""Fused block-contraction kernels: oracle parity, layout
 regressions, and the jaxpr-level proof that the frsz2 block cycle never
 materializes the decoded basis."""
 import jax
@@ -114,51 +114,6 @@ def test_mixed_block_store_routes_head_and_tail():
                                    rtol=2e-5, atol=2e-5)
     finally:
         ops.INTERPRET = ops_interpret
-
-
-# ---------------------------------------------------------------------------
-# property sweep: ELL SpMV kernel vs the jnp gather (dense + fused operand)
-# ---------------------------------------------------------------------------
-
-
-def _random_ell(rng, nr, w, dtype=jnp.float64):
-    from repro.sparse.csr import ELL
-
-    cols = rng.integers(0, nr, (nr, w))
-    vals = rng.standard_normal((nr, w))
-    pad = rng.random((nr, w)) < 0.2        # exercise val-0/col-0 padding
-    cols[pad] = 0
-    vals[pad] = 0.0
-    return ELL(jnp.asarray(cols, jnp.int32), jnp.asarray(vals, dtype),
-               (nr, nr))
-
-
-@given(st.integers(3, 500), st.integers(1, 9))
-@settings(max_examples=10, deadline=None)
-def test_ell_spmv_matches_gather(nr, w):
-    rng = np.random.default_rng(nr * 31 + w)
-    E = _random_ell(rng, nr, w)
-    x = jnp.asarray(rng.standard_normal(nr))
-    y_ref = E.matvec(x, kernel=False)
-    y_k = ops.ell_spmv(E.vals, E.cols, x, interpret=True)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("l", [32, 16])
-def test_ell_spmv_fused_operand_decode(l, rng):
-    spec = F.FrszSpec(bs=128, l=l, dtype=jnp.float32)
-    E = _random_ell(rng, 389, 7, dtype=jnp.float32)
-    x = jnp.asarray(rng.standard_normal(389), jnp.float32)
-    bc = F.compress(x, spec)
-    y_k = ops.ell_spmv(E.vals, E.cols, bc, interpret=True)
-    y_ref = E.matvec(F.decompress(bc), kernel=False)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref),
-                               rtol=2e-5, atol=2e-5)
-    # and through the dispatching front door
-    y_d = E.matvec(bc, kernel=True)
-    np.testing.assert_allclose(np.asarray(y_d), np.asarray(y_ref),
-                               rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -131,3 +131,22 @@ def test_pack_unpack_arbitrary_l(rng):
 def test_bits_per_value_paper_claim():
     # paper Sec. IV-C: frsz2_32 with BS=32 averages 33 bits/value
     assert F.bits_per_value(F.FrszSpec(bs=32, l=32, dtype=jnp.float64)) == 33.0
+
+
+def test_f64_codec_refused_on_tpu(monkeypatch):
+    # XLA:TPU has no f64 -> u64 bitcast-convert: a float64 FRSZ2 format
+    # raises there instead of silently running in float32
+    import jax
+
+    from repro import runtime
+    from repro.core.accessor import format_by_name
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="bitcast-convert f64->u64"):
+        F.FrszSpec(bs=32, l=32, dtype=jnp.float64)
+    with pytest.raises(ValueError, match="UNIMPLEMENTED"):
+        format_by_name("frsz2_32", arith_dtype=jnp.float64)
+    assert runtime.arith_dtype() == jnp.float32
+    assert format_by_name("frsz2_16").spec.dtype == jnp.float32
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert runtime.arith_dtype() == jnp.float64

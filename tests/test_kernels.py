@@ -90,3 +90,18 @@ def test_kernel_fallback_unaligned():
     bc = ops.compress(x, spec)
     y = ops.decompress(bc)
     assert np.allclose(np.asarray(y), np.asarray(x), atol=2e-5)
+
+
+def test_interpret_follows_backend(monkeypatch):
+    # compiled on TPU, interpreted elsewhere; an interpret pin on a TPU
+    # backend is an error, never a silent mode
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._resolve_interpret(None) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._resolve_interpret(None) is False
+    assert ops._resolve_interpret(False) is False
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        ops._resolve_interpret(True)
+    monkeypatch.setattr(ops, "INTERPRET", True)
+    with pytest.raises(RuntimeError, match="interpret mode"):
+        ops._resolve_interpret(None)

@@ -266,7 +266,7 @@ def _orthonormalize(ortho, n, m, seed, eta=0.7071067811865475):
     return np.abs(G - np.eye(m + 1)).max()
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(3, 10), st.integers(0, 10_000))
 def test_cgs2_vs_mgs_orthogonality_property(m, seed):
     """Property: both schemes keep the basis orthonormal to near machine

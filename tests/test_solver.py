@@ -111,3 +111,33 @@ def test_ell_spmv_matches_csr(rng):
     x = jnp.asarray(rng.standard_normal(A.shape[1]))
     np.testing.assert_allclose(np.asarray(A @ x), np.asarray(E @ x),
                                rtol=1e-12)
+
+
+@pytest.mark.parametrize("storage,block", [("float32", False),
+                                           ("frsz2_16", False),
+                                           ("frsz2_16", True)])
+def test_float32_solve_lowers_dots_at_highest(storage, block):
+    """Every dense product of a float32 solve asks for full f32 precision
+    by itself (a TPU's default pass rounds f32 operands to bfloat16), with
+    no process-wide matmul-precision setting."""
+    import jax
+
+    from repro.solver.block import build_block_solve
+    from repro.solver.gmres import build_device_solve
+
+    assert jax.config.jax_default_matmul_precision is None
+    A, _ = make_problem("synth:atmosmod", 512, dtype=np.float32)
+    b, _ = rhs_for(A)
+    kw = dict(storage=storage, max_iters=40, target_rrn=1e-6,
+              arith_dtype=jnp.float32)
+    if block:
+        b = jnp.stack([b, 2 * b + 1])
+        solve, _ = build_block_solve(A, b, m=5, **kw)
+    else:
+        solve, _ = build_device_solve(A, b, m=10, **kw)
+    text = jax.jit(solve).lower(b, jnp.zeros_like(b)).as_text()
+    dots = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln]
+    assert dots
+    low = [ln.strip() for ln in dots
+           if "precision = [HIGHEST, HIGHEST]" not in ln]
+    assert not low, low

@@ -1,0 +1,144 @@
+"""Ahead-of-time compiles for a described TPU v5e (no chip attached).
+
+The solver's Pallas kernels at the full single-chip size (n = 1,259,712
+rows, an m + 1 = 101 row basis, float32, l in {8, 16}) and one whole
+float32 frsz2_16 device solve at 48^3 rows must pass the TPU compiler:
+interpret-mode tests cannot see its tiling and lowering refusals.
+
+The topology is described inside a module fixture (never at import), so
+under pytest-xdist only the worker that runs this file loads the TPU
+compiler, and the file skips where no v5e can be described.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import frsz2 as F
+from repro.kernels import ops
+
+N = 1_259_712           # 108^3: the paper's atmosmodd size (Table I)
+M = 101                 # GMRES(100) basis rows
+P = 8                   # block-GMRES right-hand sides
+BS = 32                 # format_by_name's FRSZ2 block size
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tpu_compile(one_chip):
+    """Compile ``fn`` for the described chip with compiled kernels, x64 off
+    and the persistent cache off (its entries cannot be read back here);
+    returns the HLO text of the compiled program."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    pin, ops.INTERPRET = ops.INTERPRET, False
+
+    def compile_(fn, *shapes):
+        with jax.enable_x64(False):
+            args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                    for s, d in shapes]
+            return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    ops.INTERPRET = pin
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _spec(l):
+    return F.FrszSpec(bs=BS, l=l, dtype=jnp.float32)
+
+
+def _basis(l, rows, n):
+    nb = -(-n // BS)
+    return ((rows, nb, BS), F._code_dtype(l)), ((rows, nb), jnp.int32)
+
+
+def _bc(codes, exps, n, l):
+    return F.BlockCompressed(codes=codes, exps=exps, n=n, spec=_spec(l))
+
+
+@pytest.mark.parametrize("l", [8, 16])
+def test_matvec_compiles(tpu_compile, l):
+    hlo = tpu_compile(lambda c, e, x: ops.matvec(_bc(c, e, N, l), x),
+                      *_basis(l, M, N), ((N,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("l", [8, 16])
+def test_rmatvec_compiles(tpu_compile, l):
+    hlo = tpu_compile(lambda c, e, h: ops.rmatvec(_bc(c, e, N, l), h),
+                      *_basis(l, M, N), ((M,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+def _segments():
+    """Flattened block-store length: ``P`` segments of 128-aligned ``N``."""
+    return P * (-(-N // 128) * 128)
+
+
+@pytest.mark.parametrize("l", [8, 16])
+def test_block_dots_compiles(tpu_compile, l):
+    n_flat = _segments()
+    hlo = tpu_compile(
+        lambda c, e, W: ops.block_dots(_bc(c, e, n_flat, l), W, p=P),
+        *_basis(l, M, n_flat), ((P, N), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("l", [8, 16])
+def test_block_combine_compiles(tpu_compile, l):
+    n_flat = _segments()
+    hlo = tpu_compile(
+        lambda c, e, Y: ops.block_combine(_bc(c, e, n_flat, l), Y, p=P),
+        *_basis(l, M, n_flat), ((M, P, P), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("l", [8, 16])
+def test_compress_compiles(tpu_compile, l):
+    hlo = tpu_compile(lambda x: ops.compress(x, _spec(l)).codes,
+                      ((N,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("l", [8, 16])
+def test_decompress_compiles(tpu_compile, l):
+    (codes, exps) = _basis(l, 1, N)
+    hlo = tpu_compile(lambda c, e: ops.decompress(_bc(c, e, N, l)),
+                      codes, exps)
+    assert "tpu_custom_call" in hlo
+
+
+def test_frsz2_16_device_solve_compiles(tpu_compile):
+    """A whole float32 GMRES(100) solve over a fused frsz2_16 basis."""
+    from repro.core.accessor import format_by_name
+    from repro.solver.gmres import build_device_solve
+    from repro.sparse import make_problem, rhs_for
+
+    with jax.enable_x64(False):
+        A, _ = make_problem("synth:atmosmod", 48 ** 3, dtype=np.float32)
+        b, _ = rhs_for(A)
+        fmt = format_by_name("frsz2_16", use_kernels=True,
+                             arith_dtype=jnp.float32)
+        solve, _ = build_device_solve(A, b, storage=fmt, m=100,
+                                      max_iters=400, target_rrn=1e-6)
+    hlo = tpu_compile(solve, (b.shape, jnp.float32), (b.shape, jnp.float32))
+    assert "tpu_custom_call" in hlo
+    assert "f64[" not in hlo
