@@ -274,7 +274,8 @@ class FrszFormat(StorageFormat):
         return store["codes"].shape[0]
 
     def write_row(self, store, j, v):
-        bc = F.compress(v.astype(self.spec.dtype), self.spec)
+        with jax.named_scope("compress"):
+            bc = F.compress(v.astype(self.spec.dtype), self.spec)
         return {
             "codes": store["codes"].at[j].set(bc.codes),
             "exps": store["exps"].at[j].set(bc.exps),
@@ -574,10 +575,12 @@ class BasisAccessor:
         return self.fmt.empty(self.m, self.n)
 
     def write_row(self, store, j, v):
-        return self.fmt.write_row(store, j, v)
+        with jax.named_scope("store"):
+            return self.fmt.write_row(store, j, v)
 
     def read_row(self, store, j):
-        return self.fmt.read_row(store, j, self.arith_dtype, self.n)
+        with jax.named_scope("decode"):
+            return self.fmt.read_row(store, j, self.arith_dtype, self.n)
 
     def read_all(self, store):
         return self.fmt.read_all(store, self.arith_dtype, self.n)
@@ -585,16 +588,18 @@ class BasisAccessor:
     # -- hot loops ------------------------------------------------------------
     def dots(self, store, w, row_mask=None):
         """h = V @ w, masked rows zeroed.  (Orthogonalization dot products.)"""
-        h = self.fmt.dots(store, w, self.arith_dtype, self.n)
-        if row_mask is not None:
-            h = jnp.where(row_mask, h, 0.0)
-        return h
+        with jax.named_scope("dots"):
+            h = self.fmt.dots(store, w, self.arith_dtype, self.n)
+            if row_mask is not None:
+                h = jnp.where(row_mask, h, 0.0)
+            return h
 
     def combine(self, store, h, row_mask=None):
         """y = h @ V, masked rows excluded.  (Basis update / solution build.)"""
-        if row_mask is not None:
-            h = jnp.where(row_mask, h, 0.0)
-        return self.fmt.combine(store, h, self.arith_dtype, self.n)
+        with jax.named_scope("combine"):
+            if row_mask is not None:
+                h = jnp.where(row_mask, h, 0.0)
+            return self.fmt.combine(store, h, self.arith_dtype, self.n)
 
     def nbytes(self) -> int:
         return self.fmt.nbytes(self.m, self.n)

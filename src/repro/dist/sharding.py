@@ -222,9 +222,10 @@ def driver_partition_specs(accs, axis: str = "basis", batched: bool = False):
         lands each device exactly on its plan chunk;
       * ``stores`` — one Krylov store per policy level, each sharded along
         the vector dim per :func:`basis_partition_specs`;
-      * ``hist`` / ``rst`` and every scalar (``total``, ``cycles``,
-        ``restarts``, ``converged``, ``stagnated``, ``rrn``, ``prev_last``,
-        ``nbytes``) — device-invariant, replicated.
+      * ``hist`` / ``rst`` / ``cycle_len`` and every scalar (``total``,
+        ``cycles``, ``restarts``, ``converged``, ``stagnated``, ``rrn``,
+        ``prev_last``, ``nbytes``, ``op_reads``, ``steps``, ``spmvs``) —
+        device-invariant, replicated.
 
     ``accs`` is the driver's tuple of ``BasisAccessor``s (anything with an
     ``empty()`` store builder works — only shapes are inspected, via
@@ -241,7 +242,8 @@ def driver_partition_specs(accs, axis: str = "basis", batched: bool = False):
         stores=store_specs,
         total=P(), cycles=P(), restarts=P(), converged=P(),
         stagnated=P(), rrn=P(), prev_last=P(), nbytes=P(),
-        op_reads=P(), hist=P(), rst=P(),
+        op_reads=P(), hist=P(), rst=P(), steps=P(), spmvs=P(),
+        cycle_len=P(),
     )
     if batched:
         specs = jax.tree.map(lambda p: P(None, *tuple(p)), specs,

@@ -94,6 +94,15 @@ _TINY = 1e-300
 
 @dataclasses.dataclass
 class GmresResult:
+    """One solve's answer and counters.
+
+    ``steps``, ``spmvs`` and ``cycle_lengths`` count what the restart
+    driver ran, not what the algorithm needs: every inner-loop trip of a
+    cycle, masked trips after convergence included, and every operator
+    application.  The block driver (:mod:`repro.solver.block`) leaves them
+    at their defaults.
+    """
+
     x: jax.Array                 # final solution approximation
     rrn: float                   # true relative residual norm at exit
     iterations: int              # total inner iterations executed
@@ -108,6 +117,10 @@ class GmresResult:
                                  # (Arnoldi matvecs + explicit residuals);
                                  # block results carry their 1/p share of
                                  # the batch's shared passes
+    steps: int = 0               # inner-loop trips run, masked ones included
+    spmvs: int = 0               # operator applications run
+    cycle_lengths: np.ndarray = dataclasses.field(  # j_stop of each cycle run
+        default_factory=lambda: np.zeros((0,), np.int32))
 
 
 def _givens(a, b):
@@ -123,13 +136,14 @@ def _cycle(matvec: Callable, acc: BasisAccessor, b_norm, store, w0, beta,
            eta: float, target: float, ortho, precond, dist=LOCAL):
     """One GMRES(m) cycle.  w0 = r0 (unnormalized); beta = ||r0||.
 
-    Returns (store, R, g, rrn_est, extra_rows) where R is the rotated
-    Hessenberg (upper triangular in its leading block), g the rotated rhs,
-    rrn_est the per-inner-iteration implicit residual estimate, and
-    extra_rows the exact count of basis rows swept by extra (conditional)
-    orthogonalization passes: each live iteration j whose orthogonalizer
-    fired contributes its j+1 live rows — folded into the bytes_read
-    accounting.
+    Returns (store, R, g, rrn_est, extra_rows, steps) where R is the
+    rotated Hessenberg (upper triangular in its leading block), g the
+    rotated rhs, rrn_est the per-inner-iteration implicit residual
+    estimate, extra_rows the exact count of basis rows swept by extra
+    (conditional) orthogonalization passes: each live iteration j whose
+    orthogonalizer fired contributes its j+1 live rows — folded into the
+    bytes_read accounting — and steps the inner-loop trips run, one
+    operator application each, masked trips included.
 
     ``dist`` routes vector norms: local (default) or psum-of-local-squares
     when the cycle runs row-partitioned inside ``shard_map``.
@@ -147,7 +161,7 @@ def _cycle(matvec: Callable, acc: BasisAccessor, b_norm, store, w0, beta,
     rows = jnp.arange(m + 1)
 
     def body(j, carry):
-        store, R, g, cs, sn, est, extra_rows, alive = carry
+        store, R, g, cs, sn, est, extra_rows, steps, alive = carry
         v = acc.read_row(store, j)
         w = matvec(precond.apply(v)).astype(ad)
         w_pre = dist.norm(w)
@@ -161,69 +175,71 @@ def _cycle(matvec: Callable, acc: BasisAccessor, b_norm, store, w0, beta,
         vnew = w / hj1_safe
         store = acc.write_row(store, j + 1, vnew)
 
-        # Hessenberg column = [h_{1:j,j}; h_{j+1,j}] then apply rotations
-        col = jnp.where(mask, h, 0.0)
-        col = col.at[j + 1].set(hj1)
+        with jax.named_scope("givens"):
+            # Hessenberg column = [h_{1:j,j}; h_{j+1,j}], then rotations
+            col = jnp.where(mask, h, 0.0)
+            col = col.at[j + 1].set(hj1)
 
-        def rot_body(i, col):
-            a = col[i]
-            bb = col[i + 1]
-            live = i < j
-            c = jnp.where(live, cs[jnp.minimum(i, m - 1)], 1.0)
-            s = jnp.where(live, sn[jnp.minimum(i, m - 1)], 0.0)
-            col = col.at[i].set(c * a + s * bb)
-            col = col.at[i + 1].set(-s * a + c * bb)
-            return col
+            def rot_body(i, col):
+                a = col[i]
+                bb = col[i + 1]
+                live = i < j
+                c = jnp.where(live, cs[jnp.minimum(i, m - 1)], 1.0)
+                s = jnp.where(live, sn[jnp.minimum(i, m - 1)], 0.0)
+                col = col.at[i].set(c * a + s * bb)
+                col = col.at[i + 1].set(-s * a + c * bb)
+                return col
 
-        col = jax.lax.fori_loop(0, j, rot_body, col)
-        c, s = _givens(col[j], col[j + 1])
-        col = col.at[j].set(c * col[j] + s * col[j + 1])
-        col = col.at[j + 1].set(0.0)
-        gj = g[j]
-        g = g.at[j].set(c * gj)
-        g = g.at[j + 1].set(-s * gj)
+            col = jax.lax.fori_loop(0, j, rot_body, col)
+            c, s = _givens(col[j], col[j + 1])
+            col = col.at[j].set(c * col[j] + s * col[j + 1])
+            col = col.at[j + 1].set(0.0)
+            gj = g[j]
+            g = g.at[j].set(c * gj)
+            g = g.at[j + 1].set(-s * gj)
 
-        R = R.at[:, j].set(jnp.where(alive, col, R[:, j]))
-        cs = cs.at[j].set(c)
-        sn = sn.at[j].set(s)
-        resid = jnp.abs(g[j + 1]) / b_norm
-        est = est.at[j].set(jnp.where(alive, resid, est[jnp.maximum(j - 1, 0)]))
+            R = R.at[:, j].set(jnp.where(alive, col, R[:, j]))
+            cs = cs.at[j].set(c)
+            sn = sn.at[j].set(s)
+            resid = jnp.abs(g[j + 1]) / b_norm
+            est = est.at[j].set(
+                jnp.where(alive, resid, est[jnp.maximum(j - 1, 0)]))
         alive_next = alive & (~breakdown) & (resid > target)
-        return store, R, g, cs, sn, est, extra_rows, alive_next
+        return store, R, g, cs, sn, est, extra_rows, steps + 1, alive_next
 
-    store, R, g, cs, sn, est, extra_rows, alive = jax.lax.fori_loop(
+    zero = jnp.asarray(0, jnp.int32)
+    store, R, g, cs, sn, est, extra_rows, steps, alive = jax.lax.fori_loop(
         0, m, body,
-        (store, R0, g0, cs0, sn0, est0, jnp.asarray(0, jnp.int32),
-         jnp.asarray(True))
+        (store, R0, g0, cs0, sn0, est0, zero, zero, jnp.asarray(True))
     )
-    return store, R, g, est, extra_rows
+    return store, R, g, est, extra_rows, steps
 
 
 def _solve_and_update(acc: BasisAccessor, store, R, g, j_stop, x0, precond):
     """y = argmin ||beta e1 - H y|| (truncated at j_stop), x = x0 + M^{-1}V_m y."""
     m = acc.m - 1
     ad = acc.arith_dtype
-    idx = jnp.arange(m)
-    active = idx < j_stop
-    # Back substitution on the leading (j_stop, j_stop) block of R.
-    Rm = jnp.where(active[None, :] & active[:, None], R[:m, :m], 0.0)
-    # anchor the fill literals to the arithmetic dtype: a bare
-    # where(mask, 1.0, 0.0) has no array operand and materializes the
-    # full (m, m) select in weak f64 under x64
-    Rm = Rm + jnp.where(jnp.eye(m, dtype=bool) & ~active[:, None],
-                        jnp.ones((), ad), jnp.zeros((), ad))
-    gm = jnp.where(active, g[:m], 0.0)
+    with jax.named_scope("update"):
+        active = jnp.arange(m) < j_stop
+        # Back substitution on the leading (j_stop, j_stop) block of R.
+        Rm = jnp.where(active[None, :] & active[:, None], R[:m, :m], 0.0)
+        # anchor the fill literals to the arithmetic dtype: a bare
+        # where(mask, 1.0, 0.0) has no array operand and materializes the
+        # full (m, m) select in weak f64 under x64
+        Rm = Rm + jnp.where(jnp.eye(m, dtype=bool) & ~active[:, None],
+                            jnp.ones((), ad), jnp.zeros((), ad))
+        gm = jnp.where(active, g[:m], 0.0)
 
-    def back(i, y):
-        jj = m - 1 - i
-        s = gm[jj] - jnp.dot(Rm[jj], y, precision=HIGHEST)
-        yi = s / Rm[jj, jj]
-        return y.at[jj].set(jnp.where(active[jj], yi, 0.0))
+        def back(i, y):
+            jj = m - 1 - i
+            s = gm[jj] - jnp.dot(Rm[jj], y, precision=HIGHEST)
+            yi = s / Rm[jj, jj]
+            return y.at[jj].set(jnp.where(active[jj], yi, 0.0))
 
-    y = jax.lax.fori_loop(0, m, back, jnp.zeros((m,), ad))
-    ypad = jnp.concatenate([y, jnp.zeros((1,), ad)])
-    dx = precond.apply(acc.combine(store, ypad, jnp.arange(m + 1) < j_stop))
-    return x0 + dx
+        y = jax.lax.fori_loop(0, m, back, jnp.zeros((m,), ad))
+        ypad = jnp.concatenate([y, jnp.zeros((1,), ad)])
+        dx = precond.apply(acc.combine(store, ypad, jnp.arange(m + 1) < j_stop))
+        return x0 + dx
 
 
 def _cycle_row_reads(j_stop, passes: int, extra_rows=0):
@@ -490,6 +506,11 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
     # model the same work); +1 per loop-head residual; +j_stop modelled
     # Arnoldi matvecs and +1 explicit post-update residual per cycle.
     op_reads = 1.0
+    # operator applications, counted as the device driver counts them (its
+    # eager rrn0 included); inner-loop trips; each cycle's j_stop
+    spmvs = 1
+    steps = 0
+    cycle_lengths: list[int] = []
     # rrn is (re)established at each loop head from the explicit restart
     # residual (the seed's extra up-front matvec was redundant); the
     # fallback below only runs for a zero iteration budget, keeping parity
@@ -501,6 +522,7 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
         beta = jnp.linalg.norm(r)
         restart_rrns.append(float(beta / b_norm))
         op_reads += 1.0
+        spmvs += 1
         rrn = restart_rrns[-1]
         if rrn <= target_rrn:
             converged = True
@@ -510,8 +532,8 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
             kernels[lvl] = kernels_for(lvl)
             stores[lvl] = accs[lvl].empty()
         cycle, update = kernels[lvl]
-        stores[lvl], R, g, est, extra_rows = cycle(stores[lvl], r, beta,
-                                                   b_norm)
+        stores[lvl], R, g, est, extra_rows, trips = cycle(stores[lvl], r,
+                                                          beta, b_norm)
         est_np = np.asarray(est)
         # first inner iteration that met the target (1-based count)
         hit = np.nonzero(est_np <= target_rrn)[0]
@@ -520,6 +542,9 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
         x = update(stores[lvl], R, g, jnp.asarray(j_stop), x)
         history.append(est_np[:j_stop])
         total_iters += j_stop
+        steps += int(trips)
+        spmvs += int(trips) + 1          # the trips and the residual below
+        cycle_lengths.append(j_stop)
         bytes_read += _cycle_row_reads(j_stop, ortho.passes,
                                        int(extra_rows)) * (
             accs[lvl].nbytes() / accs[lvl].m)
@@ -553,6 +578,9 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
         bytes_read=bytes_read,
         stagnated=stagnated,
         op_reads=op_reads,
+        steps=steps,
+        spmvs=spmvs,
+        cycle_lengths=np.asarray(cycle_lengths, np.int32),
     )
 
 
@@ -570,6 +598,10 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
     drivers produce identical iteration counts, restart schedules, and
     residual histories (the parity test asserts this).  The returned state
     dict carries fixed-size history buffers; the host wrapper trims them.
+    It also counts what the device ran, each in the loop that runs it:
+    ``steps`` (inner-loop trips, masked ones included), ``spmvs`` (operator
+    applications) and ``cycle_len`` (each cycle's ``j_stop``, indexed by
+    ``cycles``).
 
     Multi-level precision policies carry one pre-built store per level and
     dispatch each cycle with ``lax.switch`` on the policy's level index —
@@ -598,7 +630,8 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
     def solve(b, x0):
         b = b.astype(ad)
         b_norm = dist.norm(b)
-        rrn0 = dist.norm(b - rmv(x0).astype(ad)) / b_norm
+        with jax.named_scope("residual"):
+            rrn0 = dist.norm(b - rmv(x0).astype(ad)) / b_norm
 
         init = dict(
             x=x0,
@@ -614,25 +647,30 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
             op_reads=jnp.asarray(1.0, ad),     # the rrn0 residual above
             hist=jnp.zeros((hist_cap,), ad),
             rst=jnp.zeros((rst_cap,), ad),
+            steps=jnp.asarray(0, jnp.int32),
+            spmvs=jnp.asarray(1, jnp.int32),   # the rrn0 residual above
+            cycle_len=jnp.zeros((rst_cap,), jnp.int32),
         )
 
         def cond(s):
             return (s["total"] < max_iters) & ~s["converged"] & ~s["stagnated"]
 
         def body(s):
-            r = b - rmv(s["x"]).astype(ad)
-            beta = dist.norm(r)
-            rr = beta / b_norm
+            with jax.named_scope("residual"):
+                r = b - rmv(s["x"]).astype(ad)
+                beta = dist.norm(r)
+                rr = beta / b_norm
             rst = s["rst"].at[s["restarts"]].set(rr, mode="drop")
             restarts = s["restarts"] + 1
             op_head = s["op_reads"] + 1.0   # the loop-head residual above
+            spmv_head = s["spmvs"] + 1
             early = rr <= target_rrn        # restart residual already there
             lvl = policy.level(rr, s["cycles"])
 
             def run_cycle_at(k):
                 def run(s):
                     acc = accs[k]
-                    store, R, g, est, extra_rows = _cycle(
+                    store, R, g, est, extra_rows, steps = _cycle(
                         matvec, acc, b_norm, s["stores"][k], r, beta, eta,
                         target_rrn, ortho, precond, dist
                     )
@@ -647,7 +685,8 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
                     hist = s["hist"].at[idx].set(est, mode="drop")
                     total = s["total"] + j_stop
                     cycles = s["cycles"] + 1
-                    rrn = dist.norm(b - rmv(x).astype(ad)) / b_norm
+                    with jax.named_scope("residual"):
+                        rrn = dist.norm(b - rmv(x).astype(ad)) / b_norm
                     conv = rrn <= target_rrn
                     last = est[jnp.maximum(j_stop - 1, 0)]
                     # stagnation guard (host: np.allclose(last, prev, 1e-2))
@@ -670,6 +709,10 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
                         restarts=restarts, converged=conv, stagnated=stag,
                         rrn=rrn, prev_last=last, nbytes=nbytes,
                         op_reads=op_reads, hist=hist, rst=rst,
+                        steps=s["steps"] + steps,
+                        spmvs=spmv_head + steps + 1,   # the trips and rrn
+                        cycle_len=s["cycle_len"].at[s["cycles"]].set(
+                            j_stop, mode="drop"),
                     )
                 return run
 
@@ -682,7 +725,7 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
             def skip_cycle(s):
                 return dict(
                     s, restarts=restarts, converged=jnp.asarray(True),
-                    rrn=rr, rst=rst, op_reads=op_head,
+                    rrn=rr, rst=rst, op_reads=op_head, spmvs=spmv_head,
                 )
 
             return jax.lax.cond(early, skip_cycle, run_cycle, s)
@@ -693,20 +736,29 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
 
 
 def _device_result(state) -> GmresResult:
-    """Trim the device state's fixed buffers into the GmresResult contract."""
-    total = int(state["total"])
-    restarts = int(state["restarts"])
+    """Trim the device state's fixed buffers into the GmresResult contract.
+
+    Everything but ``x`` and the Krylov stores comes to the host in one
+    fetch and is sliced there: a slice on the device by a count would
+    compile a program per count."""
+    host = jax.device_get({k: v for k, v in state.items()
+                           if k not in ("x", "stores")})
+    total = int(host["total"])
+    restarts = int(host["restarts"])
     return GmresResult(
         x=state["x"],
-        rrn=float(state["rrn"]),
+        rrn=float(host["rrn"]),
         iterations=total,
-        converged=bool(state["converged"]),
-        rrn_history=np.asarray(state["hist"][:total]),
-        restart_rrns=np.asarray(state["rst"][:restarts]),
+        converged=bool(host["converged"]),
+        rrn_history=np.asarray(host["hist"][:total]),
+        restart_rrns=np.asarray(host["rst"][:restarts]),
         restarts=restarts,
-        bytes_read=float(state["nbytes"]),
-        stagnated=bool(state["stagnated"]),
-        op_reads=float(state["op_reads"]),
+        bytes_read=float(host["nbytes"]),
+        stagnated=bool(host["stagnated"]),
+        op_reads=float(host["op_reads"]),
+        steps=int(host["steps"]),
+        spmvs=int(host["spmvs"]),
+        cycle_lengths=np.asarray(host["cycle_len"][:int(host["cycles"])]),
     )
 
 
@@ -891,38 +943,43 @@ def gmres(
     operator; ``"rcm"`` forces the permutation (the solve runs in
     permuted coordinates; ``b``/``x0`` are mapped in and ``x`` back out
     transparently); ``"none"`` disables it.
-    """
-    user_matvec = matvec
-    if shard is not None:
-        if driver != "device":
-            raise ValueError("shard= requires the device driver")
-        from repro.solver.sharded import sharded_gmres
 
-        return sharded_gmres(
-            A, b, x0=x0, storage=storage, policy=policy, precond=precond,
-            ortho=ortho, m=m, max_iters=max_iters, target_rrn=target_rrn,
-            arith_dtype=arith_dtype, eta=eta, matvec=matvec, shard=shard,
-            transport=shard_transport, partition_mode=shard_matvec,
-            reorder=reorder, pgrid=shard_grid)
-    if driver == "device":
-        solve, args, plan = solve_program(
-            A, b, x0=x0, storage=storage, policy=policy, precond=precond,
-            ortho=ortho, m=m, max_iters=max_iters, target_rrn=target_rrn,
-            arith_dtype=arith_dtype, eta=eta, matvec=matvec, reorder=reorder)
-        res = _device_result(solve(*args))
-    elif driver == "host":
-        plan, A, b, x0, accs, policy, _, matvec, precond, ortho = _prepare(
-            A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
-            matvec, target_rrn, reorder)
-        op_key, pins = _operator_key(A, user_matvec, plan)
-        res = _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn,
-                          eta, ortho, precond, x0=x0, op_key=op_key,
-                          pins=pins + (precond,))
-    else:
-        raise ValueError(f"unknown driver {driver!r}")
-    if plan is not None:
-        res.x = plan.unpermute(res.x)
-    return res
+    The solve runs inside a ``gmres.solve`` profiler span
+    (``jax.profiler.TraceAnnotation``), on the clock of the device's ops.
+    """
+    with jax.profiler.TraceAnnotation("gmres.solve"):
+        user_matvec = matvec
+        if shard is not None:
+            if driver != "device":
+                raise ValueError("shard= requires the device driver")
+            from repro.solver.sharded import sharded_gmres
+
+            return sharded_gmres(
+                A, b, x0=x0, storage=storage, policy=policy, precond=precond,
+                ortho=ortho, m=m, max_iters=max_iters, target_rrn=target_rrn,
+                arith_dtype=arith_dtype, eta=eta, matvec=matvec, shard=shard,
+                transport=shard_transport, partition_mode=shard_matvec,
+                reorder=reorder, pgrid=shard_grid)
+        if driver == "device":
+            solve, args, plan = solve_program(
+                A, b, x0=x0, storage=storage, policy=policy, precond=precond,
+                ortho=ortho, m=m, max_iters=max_iters, target_rrn=target_rrn,
+                arith_dtype=arith_dtype, eta=eta, matvec=matvec,
+                reorder=reorder)
+            res = _device_result(solve(*args))
+        elif driver == "host":
+            plan, A, b, x0, accs, policy, _, matvec, precond, ortho = _prepare(
+                A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
+                matvec, target_rrn, reorder)
+            op_key, pins = _operator_key(A, user_matvec, plan)
+            res = _gmres_host(matvec, accs, policy, b, m, max_iters,
+                              target_rrn, eta, ortho, precond, x0=x0,
+                              op_key=op_key, pins=pins + (precond,))
+        else:
+            raise ValueError(f"unknown driver {driver!r}")
+        if plan is not None:
+            res.x = plan.unpermute(res.x)
+        return res
 
 
 def _prepare(A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
@@ -954,14 +1011,22 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
     arguments ``gmres`` calls it with, and the reordering plan (``None``
     without one).  ``solve.lower(*args).compile()`` is the executable
     ``gmres`` runs, for reading its memory analysis and HLO.
+
+    Host spans: ``gmres.solve_program`` around it all, ``gmres.plan`` around
+    the plan, the pipeline and the operator's ``row_ids``, and
+    ``gmres.lookup`` around the fingerprint and the compiled-solve cache.
     """
-    plan, A, b, x0, accs, policy, arith_dtype, mv, precond, ortho = _prepare(
-        A, b, x0, storage, policy, precond, ortho, m, arith_dtype, matvec,
-        target_rrn, reorder)
-    x0 = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
-    solve = _cached_solve(A, matvec, False, mv, accs, policy, m, max_iters,
-                          eta, target_rrn, ortho, precond, plan)
-    return solve, (b, x0), plan
+    with jax.profiler.TraceAnnotation("gmres.solve_program"):
+        with jax.profiler.TraceAnnotation("gmres.plan"):
+            (plan, A, b, x0, accs, policy, arith_dtype, mv, precond,
+             ortho) = _prepare(A, b, x0, storage, policy, precond, ortho, m,
+                               arith_dtype, matvec, target_rrn, reorder)
+            x0 = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
+        with jax.profiler.TraceAnnotation("gmres.lookup"):
+            solve = _cached_solve(A, matvec, False, mv, accs, policy, m,
+                                  max_iters, eta, target_rrn, ortho, precond,
+                                  plan)
+        return solve, (b, x0), plan
 
 
 def gmres_batched(

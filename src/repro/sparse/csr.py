@@ -56,10 +56,11 @@ class CSR:
         )
 
     def matvec(self, x: jax.Array, row_ids: jax.Array | None = None) -> jax.Array:
-        if row_ids is None:
-            row_ids = self.row_ids()
-        prod = self.data * x[self.indices].astype(self.data.dtype)
-        return jax.ops.segment_sum(prod, row_ids, num_segments=self.shape[0])
+        with jax.named_scope("spmv"):
+            if row_ids is None:
+                row_ids = self.row_ids()
+            prod = self.data * x[self.indices].astype(self.data.dtype)
+            return jax.ops.segment_sum(prod, row_ids, num_segments=self.shape[0])
 
     def diag(self) -> jax.Array:
         """(n,) main diagonal (zeros where a row has no diagonal entry)."""
@@ -152,9 +153,10 @@ class ELL:
         ``BlockCompressed`` operand, decompressed first."""
         from repro.core import frsz2 as F
 
-        if isinstance(x, F.BlockCompressed):
-            x = F.decompress(x)
-        return (self.vals * x[self.cols].astype(self.vals.dtype)).sum(axis=1)
+        with jax.named_scope("spmv"):
+            if isinstance(x, F.BlockCompressed):
+                x = F.decompress(x)
+            return (self.vals * x[self.cols].astype(self.vals.dtype)).sum(axis=1)
 
     def diag(self) -> jax.Array:
         """(n,) main diagonal (padding slots carry val 0, so they drop out)."""

@@ -48,6 +48,10 @@ def test_device_driver_parity(fmt):
     assert rh.rrn_history.shape == rd.rrn_history.shape
     np.testing.assert_allclose(rh.rrn_history, rd.rrn_history,
                                rtol=1e-10, atol=1e-15)
+    # what the device ran: trips, operator applications, cycle lengths
+    assert rh.steps == rd.steps > 0, fmt
+    assert rh.spmvs == rd.spmvs, fmt
+    np.testing.assert_array_equal(rh.cycle_lengths, rd.cycle_lengths)
 
 
 def test_device_driver_stagnation_parity():
@@ -89,7 +93,8 @@ def test_stagnated_flag_reported_by_both_drivers(monkeypatch):
         # decreasing est that first meets the target at the last position
         # (interior multipliers strictly > 1, final strictly < 1)
         est = jnp.asarray(target * np.linspace(2.0, 0.9, m), ad)
-        return store, R, g, est, jnp.asarray(0, jnp.int32)
+        zero = jnp.asarray(0, jnp.int32)
+        return store, R, g, est, zero, zero + m
 
     monkeypatch.setattr(gmres_mod, "_cycle", fake_cycle)
     # fresh solve cache: the device program compiled from the fake cycle
@@ -141,6 +146,52 @@ def test_device_driver_trivial_rhs_converges_immediately():
     assert res.converged
     assert res.iterations == 0
     assert res.restarts == 1
+    # the initial residual and the skipped cycle's head, and no trip
+    assert (res.steps, res.spmvs, res.cycle_lengths.size) == (0, 2, 0)
+
+
+@pytest.mark.parametrize("fmt,m,target", [("float64", 10, None),
+                                          ("frsz2_16", 40, 1e-6)])
+def test_counters_count_what_the_device_ran(fmt, m, target):
+    """GMRES(10) restarts after m trips; the 16-bit basis restarts early,
+    when the implicit estimate meets the target and the explicit residual
+    does not.  Either way every cycle runs all m trips (the tail after
+    j_stop is masked) and three residuals frame the trips."""
+    A, b, _, rrn = _problem()
+    res = gmres(A, b, storage=fmt, m=m, max_iters=4000,
+                target_rrn=rrn if target is None else target)
+    cycles = res.cycle_lengths.size
+    assert res.converged and cycles >= 2
+    assert res.steps == m * cycles
+    skipped_heads = res.restarts - cycles
+    assert res.spmvs == 1 + cycles * (m + 2) + skipped_heads
+    assert res.cycle_lengths.sum() == res.iterations
+    assert (res.cycle_lengths[:-1] < m).any() == (fmt == "frsz2_16")
+
+
+def test_gmres_compiles_nothing_for_a_new_iteration_count():
+    """The result is trimmed on the host: a solve of the same operator
+    that stops after a different number of iterations runs the cached
+    program and compiles nothing."""
+    from jax._src import dispatch
+
+    A, b, _, rrn = _problem(256)
+    b2 = jnp.asarray(np.random.default_rng(0).standard_normal(b.shape[0]))
+    kw = dict(storage="float64", m=20, max_iters=2000, target_rrn=rrn)
+    first = gmres(A, b, **kw)
+    compiles = []
+
+    def on_compile(event, _secs, **_kw):
+        if event == dispatch.BACKEND_COMPILE_EVENT:
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        second = gmres(A, b2, **kw)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert second.iterations != first.iterations
+    assert compiles == []
 
 
 # ---------------------------------------------------------------------------
