@@ -351,12 +351,11 @@ def audit_transfer_guard() -> list[Finding]:
     G._SOLVE_CACHE.clear()
     kw = dict(storage="float64", m=8, max_iters=240, target_rrn=1e-8)
     G.gmres(A, b, **kw)                                    # warm + compile
-    solve = next(iter(G._SOLVE_CACHE.values()))[0]
-    bd = jax.device_put(b)
-    x0d = jax.device_put(jnp.zeros_like(b))
+    solve, args, _ = G.solve_program(A, b, **kw)
+    args = jax.device_put(args)
     try:
         with jax.transfer_guard("disallow"):
-            jax.block_until_ready(solve(bd, x0d))
+            jax.block_until_ready(solve(*args))
     except Exception as e:                                  # noqa: BLE001
         findings.append(_trace_finding(
             "device-transfer", "transfer",

@@ -63,7 +63,6 @@ through the fused path.
 """
 from __future__ import annotations
 
-from functools import partial
 from collections.abc import Callable
 from typing import Any
 
@@ -94,6 +93,7 @@ from repro.solver.pipeline import (
     resolve_policy,
     resolve_preconditioner,
 )
+from repro.sparse.csr import operator_matvec
 
 __all__ = ["gmres_block"]
 
@@ -488,9 +488,7 @@ def _resolve_block(A, B, storage, policy, m, arith_dtype, matvec, precond,
     if arith_dtype is None:
         arith_dtype = B.dtype
     if matvec is None:
-        row_ids = A.row_ids() if hasattr(A, "row_ids") else None
-        matvec = (partial(A.matvec, row_ids=row_ids)
-                  if row_ids is not None else A.matvec)
+        matvec = operator_matvec(A)
     policy = resolve_policy(policy, storage, arith_dtype, target_rrn, m)
     p, n = B.shape
     accs = tuple(

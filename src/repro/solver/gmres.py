@@ -71,7 +71,6 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
-from functools import partial
 from collections.abc import Callable
 from typing import Any
 
@@ -86,6 +85,7 @@ from repro.solver.pipeline import (
     resolve_policy,
     resolve_preconditioner,
 )
+from repro.sparse.csr import operator_matvec
 
 __all__ = ["GmresResult", "gmres", "gmres_batched", "cb_gmres", "solve_program"]
 
@@ -397,9 +397,7 @@ def _resolve(A, b, storage, policy, m, arith_dtype, matvec, precond, ortho,
     if arith_dtype is None:
         arith_dtype = b.dtype
     if matvec is None:
-        row_ids = A.row_ids() if hasattr(A, "row_ids") else None
-        matvec = (partial(A.matvec, row_ids=row_ids)
-                  if row_ids is not None else A.matvec)
+        matvec = operator_matvec(A)
     policy = resolve_policy(policy, storage, arith_dtype, target_rrn, m)
     n = b.shape[0]
     accs = tuple(
@@ -849,7 +847,10 @@ def _lru_cached(cache: OrderedDict, maxsize: int, make_key, build):
 
 def _cached_solve(A, user_matvec, batched, matvec, accs, policy, m,
                   max_iters, eta, target, ortho, precond, plan=None):
+    """The compiled solve ``(b, x0, operand) -> state`` of this problem
+    (cached), and the ``operand`` to call it with (:func:`_operand`)."""
     pins: tuple = ()
+    operand = _operand(user_matvec, matvec)
 
     def make_key():
         nonlocal pins
@@ -859,12 +860,33 @@ def _cached_solve(A, user_matvec, batched, matvec, accs, policy, m,
                 accs[0].m, accs[0].n, jnp.dtype(accs[0].arith_dtype).name,
                 m, max_iters, float(eta), float(target))
 
-    def build():
-        solve = _device_solve_fn(matvec, accs, policy, m, max_iters, eta,
-                                 target, ortho, precond)
-        return jax.jit(jax.vmap(solve) if batched else solve), pins
+    closed = operand is None
 
-    return _lru_cached(_SOLVE_CACHE, _SOLVE_CACHE_SIZE, make_key, build)[0]
+    def build():
+        def solve(b, x0, op):
+            return _device_solve_fn(matvec if closed else op, accs, policy,
+                                    m, max_iters, eta, target, ortho,
+                                    precond)(b, x0)
+
+        return jax.jit(jax.vmap(solve, in_axes=(0, 0, None)) if batched
+                       else solve), pins
+
+    solve = _lru_cached(_SOLVE_CACHE, _SOLVE_CACHE_SIZE, make_key, build)[0]
+    return solve, operand
+
+
+def _operand(user_matvec, matvec):
+    """The operator argument of a compiled solve ``(b, x0, operand)``:
+    ``operator_matvec``'s pytree where its leaves are arrays, so that XLA
+    cannot specialise the program to the operator's values (as constants,
+    a diagonal of equal values folds to a scalar, and the SpMV then reads
+    fewer bytes than the operator holds); ``None`` where the program
+    closes over ``matvec``: a user's callable, or an operator that is not
+    a pytree of arrays."""
+    if user_matvec is None and all(isinstance(leaf, jax.Array)
+                                   for leaf in jax.tree.leaves(matvec)):
+        return matvec
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -1008,12 +1030,14 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
     """The one-device program that ``gmres(A, b, ...)`` runs.
 
     Returns ``(solve, args, plan)``: the cached jitted solve, the
-    arguments ``gmres`` calls it with, and the reordering plan (``None``
+    arguments ``gmres`` calls it with (``b``, ``x0`` and the operator's
+    arrays, see :func:`_operand`), and the reordering plan (``None``
     without one).  ``solve.lower(*args).compile()`` is the executable
     ``gmres`` runs, for reading its memory analysis and HLO.
 
     Host spans: ``gmres.solve_program`` around it all, ``gmres.plan`` around
-    the plan, the pipeline and the operator's ``row_ids``, and
+    the plan, the pipeline and the operator's SpMV
+    (:func:`~repro.sparse.csr.operator_matvec`), and
     ``gmres.lookup`` around the fingerprint and the compiled-solve cache.
     """
     with jax.profiler.TraceAnnotation("gmres.solve_program"):
@@ -1023,10 +1047,10 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
                                arith_dtype, matvec, target_rrn, reorder)
             x0 = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
         with jax.profiler.TraceAnnotation("gmres.lookup"):
-            solve = _cached_solve(A, matvec, False, mv, accs, policy, m,
-                                  max_iters, eta, target_rrn, ortho, precond,
-                                  plan)
-        return solve, (b, x0), plan
+            solve, operand = _cached_solve(A, matvec, False, mv, accs,
+                                           policy, m, max_iters, eta,
+                                           target_rrn, ortho, precond, plan)
+        return solve, (b, x0, operand), plan
 
 
 def gmres_batched(
@@ -1131,10 +1155,10 @@ def gmres_batched(
     B = B.astype(arith_dtype)
     X0 = jnp.zeros_like(B) if X0 is None else X0.astype(arith_dtype)
 
-    solve = _cached_solve(A, user_matvec, True, matvec, accs, policy,
-                          m, max_iters, eta, target_rrn, ortho, precond,
-                          plan)
-    states = solve(B, X0)
+    solve, operand = _cached_solve(A, user_matvec, True, matvec, accs,
+                                   policy, m, max_iters, eta, target_rrn,
+                                   ortho, precond, plan)
+    states = solve(B, X0, operand)
     k = B.shape[0]
     results = [
         _device_result(jax.tree.map(lambda a: a[i], states)) for i in range(k)

@@ -1,7 +1,7 @@
-"""Sparse formats (CSR/ELL), operator planning (reordering, padding,
-halo probing, 3-D block partitioning), row-partitioned SpMV, and the
-synthetic CFD problem suite."""
-from repro.sparse.csr import CSR, ELL, csr_from_coo
+"""Sparse formats (CSR/DIA/ELL) and the solvers' SpMV, operator planning
+(reordering, padding, halo probing, 3-D block partitioning),
+row-partitioned SpMV, and the synthetic CFD problem suite."""
+from repro.sparse.csr import CSR, DIA, ELL, csr_from_coo, operator_matvec
 from repro.sparse.halo_probe import (
     BlockPartition,
     HaloProbe,
@@ -16,7 +16,7 @@ from repro.sparse.reorder import permute_csr, rcm_permutation
 from repro.sparse.shard import partition_matvec
 
 __all__ = [
-    "CSR", "ELL", "csr_from_coo",
+    "CSR", "DIA", "ELL", "csr_from_coo", "operator_matvec",
     "BlockPartition", "HaloProbe", "block_partition", "factor_pgrid",
     "grid_of", "halo_probe",
     "OperatorPlan", "plan_operator",

@@ -1,25 +1,37 @@
 """Sparse matrix formats and SpMV in pure JAX.
 
-Two formats:
+Three formats:
 
-* :class:`CSR` — the assembly/IO format; SpMV via ``segment_sum`` (CPU-friendly,
-  used by the f64 paper-faithful solver runs).
-* :class:`ELL` — fixed row width, SpMV via gather + dense reduce.  This is the
-  TPU-friendly layout (regular access, no data-dependent control flow) that
-  the distributed solver shards row-wise.
+* :class:`CSR` — the assembly/IO format, and the operator the solvers take;
+  its own SpMV gathers ``x`` per nonzero and sums rows with a
+  ``segment_sum`` scatter-add.  Any sparsity works, but a TPU is slowest at
+  exactly those two steps.
+* :class:`DIA` — banded operators stored by diagonal; SpMV is a sum of
+  products with statically shifted slices of ``x``: no gather, no scatter.
+  :meth:`CSR.to_dia` converts where the structure suits, and
+  :func:`operator_matvec` picks this path whenever it does.
+* :class:`ELL` — fixed row width, SpMV via gather + dense reduce; the
+  layout the sharded driver partitions row-wise.
 
-Both are registered pytrees so they pass through jit / shard_map.
+All are registered pytrees so they pass through jit / shard_map.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
-__all__ = ["CSR", "ELL", "csr_from_coo"]
+__all__ = ["CSR", "DIA", "ELL", "csr_from_coo", "operator_matvec"]
+
+#: a CSR converts to DIA when it has at most this many distinct diagonals
+DIA_MAX_OFFSETS = 64
+#: ... and storing them whole costs at most this many values per nonzero
+DIA_MAX_FILL = 1.25
 
 
 @jax.tree_util.register_pytree_node_class
@@ -125,6 +137,110 @@ class CSR:
     def to_dense(self) -> jax.Array:
         d = jnp.zeros(self.shape, self.data.dtype)
         return d.at[self.row_ids(), self.indices].add(self.data)
+
+    def to_dia(self) -> DIA | None:
+        """This operator stored by diagonal (host-side, cached), or ``None``
+        where its structure does not suit: more than
+        :data:`DIA_MAX_OFFSETS` distinct diagonals, or more than
+        :data:`DIA_MAX_FILL` stored values per nonzero."""
+        if not hasattr(self, "_dia"):
+            self._dia = _dia_from_csr(self)
+        return self._dia
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class DIA:
+    """Diagonal storage of a square operator.
+
+    ``offsets`` is a static tuple of ``K`` distinct column-minus-row
+    offsets; ``vals`` holds one ``(n,)`` array per offset, with
+    ``vals[k][i] = A[i, i + offsets[k]]``, 0 where row ``i`` stores nothing
+    on that diagonal.  A tuple of rows and not one ``(K, n)`` array: a TPU
+    tiles a 2-D array by (8, 128), so a row of it is strided and XLA copies
+    it out before every use, where a 1-D array is read in place.
+    """
+
+    offsets: tuple
+    vals: tuple
+    shape: tuple
+
+    def tree_flatten(self):
+        return (self.vals,), (self.offsets, self.shape)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(aux[0], tuple(children[0]), aux[1])
+
+    def matvec(self, x: jax.Array) -> jax.Array:
+        """``y = A @ x`` as ``sum_k vals[k] * x[i + offsets[k]]``, with ``x``
+        padded by zeros so that every shifted slice is static.  Jitted, so
+        that a call outside a solve rounds as the same SpMV inside one."""
+        return _dia_program(self.offsets, self.shape[0])(self.vals, x)
+
+
+@functools.lru_cache(maxsize=64)
+def _dia_program(offsets: tuple, n: int):
+    """The jitted SpMV of a DIA structure, one per ``(offsets, n)``."""
+    lo = max(0, -min(offsets))
+    hi = max(0, max(offsets))
+
+    @jax.jit
+    def apply(vals, x):
+        with jax.named_scope("spmv"), jax.named_scope("dia"):
+            xp = jnp.pad(x.astype(vals[0].dtype), (lo, hi))
+            y = vals[0] * xp[lo + offsets[0]:lo + offsets[0] + n]
+            for k in range(1, len(offsets)):
+                off = lo + offsets[k]
+                y = y + vals[k] * xp[off:off + n]
+            return y
+
+    return apply
+
+
+def _dia_from_csr(A: CSR) -> DIA | None:
+    """:meth:`CSR.to_dia`, uncached: O(nnz + K n) numpy, no loop over rows."""
+    n = A.shape[0]
+    indptr = np.asarray(A.indptr)
+    nnz = int(indptr[-1])
+    if A.shape[1] != n or nnz == 0:
+        return None
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+    off = np.asarray(A.indices)[:nnz].astype(np.int64) - rows
+    present = np.bincount(off + (n - 1), minlength=2 * n - 1)
+    offsets = np.flatnonzero(present) - (n - 1)
+    K = offsets.size
+    if K > DIA_MAX_OFFSETS or K * n > DIA_MAX_FILL * nnz:
+        return None
+    slot = np.zeros(2 * n - 1, np.int64)
+    slot[offsets + (n - 1)] = np.arange(K)
+    flat = slot[off + (n - 1)] * n + rows
+    seen = np.zeros(K * n, bool)
+    seen[flat] = True
+    if np.count_nonzero(seen) < nnz:
+        return None              # duplicate entries: CSR sums them
+    data = np.asarray(A.data)[:nnz]
+    vals = np.zeros(K * n, data.dtype)
+    vals[flat] = data
+    return DIA(tuple(int(o) for o in offsets),
+               tuple(jnp.asarray(v) for v in vals.reshape(K, n)), (n, n))
+
+
+def operator_matvec(A):
+    """The SpMV the solvers run for operator ``A``: the DIA path where
+    :meth:`CSR.to_dia` converts ``A``, else ``A``'s own matvec (a CSR's
+    with its ``row_ids`` computed once).  A pytree, so a sharded solve can
+    pass it into ``shard_map`` as an operand."""
+    if isinstance(A, CSR):
+        dia = A.to_dia()
+        if dia is not None:
+            return Partial(DIA.matvec, dia)
+        return Partial(CSR.matvec, A, row_ids=A.row_ids())
+    return Partial(_own_matvec, A)
+
+
+def _own_matvec(A, x):
+    return A.matvec(x)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -79,6 +79,7 @@ from repro.dist.collectives import (
     halo_exchange,
     halo_exchange_3d,
 )
+from repro.sparse.csr import operator_matvec
 
 # probing/partition geometry grew into its own module; the canonical home
 # is repro.sparse.halo_probe — re-exported here for existing importers
@@ -237,16 +238,13 @@ def partition_matvec(A=None, n_shards: int | None = None,
             return (vals_l * x[cols_l].astype(vals_l.dtype)).sum(axis=1)
 
     else:  # replicated
-        row_ids = A.row_ids() if hasattr(A, "row_ids") else None
-        operand = (A, row_ids)
+        operand = operator_matvec(A)
         in_specs = jax.tree.map(lambda _: P(), operand)
         pad = n_pad - n
 
-        def local_matvec(op, x_local):
-            A_full, rid = op
+        def local_matvec(mv, x_local):
             x = gather_operand(x_local, axis_name)
-            y = (A_full.matvec(x[:n], row_ids=rid) if rid is not None
-                 else A_full.matvec(x[:n]))
+            y = mv(x[:n])
             if pad:
                 y = jnp.pad(y, (0, pad))
             i = jax.lax.axis_index(axis_name)

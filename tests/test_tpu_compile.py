@@ -1,9 +1,10 @@
 """Ahead-of-time compiles for a described TPU v5e (no chip attached).
 
 The solver's Pallas kernels at the full single-chip size (n = 1,259,712
-rows, an m + 1 = 101 row basis, float32, l in {8, 16}) and one whole
-float32 frsz2_16 device solve at 48^3 rows must pass the TPU compiler:
-interpret-mode tests cannot see its tiling and lowering refusals.
+rows, an m + 1 = 101 row basis, float32, l in {8, 16}), the DIA SpMV of
+both benchmark stencils at full size, and one whole float32 frsz2_16
+device solve at 48^3 rows must pass the TPU compiler: interpret-mode tests
+cannot see its tiling and lowering refusals.
 
 The topology is described inside a module fixture (never at import), so
 under pytest-xdist only the worker that runs this file loads the TPU
@@ -142,3 +143,20 @@ def test_frsz2_16_device_solve_compiles(tpu_compile):
     hlo = tpu_compile(solve, (b.shape, jnp.float32), (b.shape, jnp.float32))
     assert "tpu_custom_call" in hlo
     assert "f64[" not in hlo
+
+
+@pytest.mark.parametrize("s, offsets", [
+    (108, (-108 * 108, -108, -1, 0, 1, 108, 108 * 108)),
+    (104, tuple(104 * 104 * i + 104 * j + k
+                for i in (-1, 0, 1) for j in (-1, 0, 1) for k in (-1, 0, 1))),
+])
+def test_dia_matvec_compiles(tpu_compile, s, offsets):
+    """The 7-point (108^3) and 27-point (104^3) DIA SpMVs: shifted slices
+    and a multiply-add, with no gather and no scatter."""
+    from repro.sparse.csr import DIA
+
+    n = s ** 3
+    hlo = tpu_compile(lambda x, *v: DIA(offsets, v, (n, n)).matvec(x),
+                      *[((n,), jnp.float32)] * (1 + len(offsets)))
+    assert "spmv/dia" in hlo
+    assert " gather(" not in hlo and " scatter(" not in hlo
