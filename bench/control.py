@@ -82,7 +82,7 @@ def main(argv=None) -> int:
     peak = run.roofline.peak(dev.device_kind)
     for seed in (int(s) for s in args.seeds.split(",")):
         out = run.run_cell(cell, seed, 0.0, False, t0=time.perf_counter(),
-                           peak=peak, memory_stats=dev.memory_stats)
+                           peak=peak, devices=jax.devices()[:cell.chips])
         print(json.dumps(dict(workload=cell.name, seed=seed,
                               control=bool(args.control),
                               correct=out["correct"],

@@ -13,6 +13,17 @@ the sum of the durations of the operations that :mod:`hlo` assigns to the
 layer, from the HLO text of the executable that ran.  Operations of other
 programs (eager ops dispatched around the solve) count as busy and are
 reported as ``program:<module>``.
+
+A trace of several chips has one device plane each, and the reduction reads
+them so that every reading is one chip's:
+
+* ``busy_s`` is the mean over the devices that ran any operation;
+* ``layer_s``, ``op_s`` and ``scope_s`` are chip-seconds, summed over the
+  devices, so a per-iteration time in ms is chip-ms;
+* a roofline share divides the algorithm's least bytes, all chips' together,
+  by chip-seconds times one chip's bandwidth: four chips that each move a
+  quarter of the bytes at peak read 100%, as one chip that moves them all;
+* ``chips`` counts the devices that ran operations of the solve program.
 """
 from __future__ import annotations
 
@@ -57,8 +68,10 @@ def xplane_file(trace_dir: Path) -> Path:
 class Reduction:
     layer_s: dict       # layer -> summed device seconds
     op_s: dict          # (layer, op) -> summed device seconds
+    scope_s: dict       # (layer, innermost named scope or "") -> seconds
     busy_s: float
     window_s: float
+    chips: int          # device planes that ran the solve program's ops
     gaps: list          # [(host span label, seconds)] of idle gaps
 
     def top_ops(self, k: int) -> list:
@@ -133,26 +146,31 @@ def reduce_profile(profile, module: hlo.Module, classes: dict) -> Reduction:
     spans = [s for s in spans if s[2] != WINDOW_SPAN]
     layer_s = collections.Counter()
     op_s = collections.Counter()
-    busy, gaps = [], []
+    scope_s = collections.Counter()
+    busy, gaps, chips = [], [], 0
     for events in device_ops(profile).values():
         inside = [(max(a, w0), min(b, w1), op, mod)
                   for a, b, op, mod in events if b > w0 and a < w1]
         merged = _union((a, b) for a, b, _, _ in inside)
         busy.append(sum(b - a for a, b in merged))
+        chips += any(mod == module.name for _, _, _, mod in inside)
         for a, b, op, mod in inside:
-            layer = (classes.get(op, "unknown") if mod == module.name
-                     else f"program:{mod}")
+            ours = mod == module.name
+            layer = classes.get(op, "unknown") if ours else f"program:{mod}"
             if layer == "control":
                 continue
+            ins = module.instrs.get(op) if ours else None
             layer_s[layer] += (b - a) * 1e-9
             op_s[(layer, op)] += (b - a) * 1e-9
+            scope_s[(layer, hlo.scope(ins) if ins else "")] += (b - a) * 1e-9
         edges = [w0] + [x for ab in merged for x in ab] + [w1]
         for g0, g1 in zip(edges[::2], edges[1::2]):
             if g1 > g0:
                 gaps.append((_label(spans, g0, g1), (g1 - g0) * 1e-9))
     busy_s = sum(busy) / len(busy) * 1e-9 if busy else 0.0
-    return Reduction(layer_s=dict(layer_s), op_s=dict(op_s), busy_s=busy_s,
-                     window_s=(w1 - w0) * 1e-9, gaps=gaps)
+    return Reduction(layer_s=dict(layer_s), op_s=dict(op_s),
+                     scope_s=dict(scope_s), busy_s=busy_s,
+                     window_s=(w1 - w0) * 1e-9, chips=chips, gaps=gaps)
 
 
 def _label(spans: list, g0: float, g1: float) -> str:

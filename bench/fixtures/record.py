@@ -33,7 +33,8 @@ def main(argv) -> int:
     cell = run.cells.load_cell(name)
     out = run.run_cell(cell, 20260, 0.0, True, t0=time.perf_counter(),
                        peak=run.roofline.peak(dev.device_kind),
-                       memory_stats=dev.memory_stats, grid=(side,) * 3)
+                       devices=jax.devices()[:cell.chips],
+                       grid=(side,) * 3)
     src = run.OUT / "trace" / name
     dst = (Path(argv[2]) if len(argv) > 2
            else Path(__file__).resolve().parent / name)

@@ -14,7 +14,11 @@ Rules, first match wins:
    out of every layer's sum.
 2. ``spmv``: the op or an op fused into it comes from ``repro/sparse/``, or
    sits in a named scope ``spmv``, or reads or writes an array of ``nnz``
-   elements (the operator's values or indices).
+   elements (the operator's values or indices), or the op is a
+   ``collective-permute``: the solver sends point to point only the halo
+   of a sharded SpMV (``dist/collectives.py`` ``halo_exchange``,
+   ``halo_exchange_3d``), and XLA gives some of those exchanges the source
+   line of the loop around them.
 3. ``basis``: the op or an op fused into it comes from ``repro/core/`` or
    ``repro/kernels/`` (accessor, codec, Pallas kernels), or sits in a named
    scope ``dots``/``combine``/``compress``/``store``/``basis``, or reads or
@@ -23,6 +27,13 @@ Rules, first match wins:
 4. by the innermost source file of the op: ``solver/gmres.py`` ->
    ``driver``, ``solver/pipeline.py`` -> ``orthogonalizer``, ``dist/`` ->
    ``reductions``; anything else -> ``other``.
+
+Rules 2 and 3 read the whole source stack, callers included: the
+all-reduce of the basis dot products, in ``dist/collectives.py`` called
+from ``core/accessor.py``, is basis work.
+
+:func:`scope` gives an op's innermost named scope of the program
+(``jax.named_scope``), which the trace reduction sums time by.
 """
 from __future__ import annotations
 
@@ -53,6 +64,10 @@ _FRAME_ID = re.compile(r"stack_frame_id=(\d+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _TABLE_ROW = re.compile(r"^(\d+)\s+(.*)$")
 _KV = re.compile(r"(\w+)=(\d+)")
+#: ``op_name`` components that JAX writes for its own structure, not scopes
+_STRUCTURE = re.compile(
+    r"^(?:while|body|cond|branch_\d+_fun|closed_call|core_call|remat|"
+    r"checkpoint|shard_map|scan|pjit|custom_jvp_call|custom_vjp_call)$")
 
 
 @dataclasses.dataclass
@@ -153,7 +168,9 @@ def _parse_tables(lines: list) -> dict:
             seen.add(cur)
             loc = locs.get(raw[cur].get("file_location_id"), {})
             chain.append(files.get(loc.get("file_name_id"), ""))
-            cur = raw[cur].get("parent_frame_id")
+            # the table prints a parent as its row plus one; the outermost
+            # frame's parent reads 1, which is no row
+            cur = raw[cur].get("parent_frame_id", 0) - 1
         frames[fid] = tuple(chain)
     return frames
 
@@ -179,6 +196,18 @@ def parse(text: str) -> Module:
             comps[comp].append(ins.name)
     return Module(name=name, instrs=instrs, computations=comps,
                   frames=_parse_tables(lines))
+
+
+def scope(ins: Instr) -> str:
+    """The innermost named scope of the program around ``ins``, from its
+    ``op_name`` (``jit(solve)/while/body/spmv/dia/mul`` -> ``dia``); ``""``
+    where it sits under none.  The last component names the primitive, and
+    JAX's own components (transformations, loop and branch bodies, merged
+    names) are no scopes."""
+    parts = ins.op_name.split("/")[:-1]
+    return next((c for c in reversed(parts)
+                 if c and not _STRUCTURE.match(c)
+                 and not any(ch in c for ch in "();")), "")
 
 
 def _fused(module: Module, ins: Instr) -> list:
@@ -224,7 +253,8 @@ def classify(module: Module, n: int, nnz: int, m: int) -> dict:
                 if o in module.instrs:
                     arrays.extend(module.instrs[o].arrays)
         sizes = {_elements(d) for _, d in arrays}
-        if (scopes & SPMV_SCOPES or nnz in sizes
+        if (ins.opcode.startswith("collective-permute")
+                or scopes & SPMV_SCOPES or nnz in sizes
                 or any("repro/sparse/" in f for f in files)):
             out[ins.name] = "spmv"
         elif (scopes & BASIS_SCOPES

@@ -20,11 +20,12 @@ program's operator must hash to the configuration's digest.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
-``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``, with
-``--trace 1`` a ``breakdown``, and last ``checks``: each number compared,
-beside its limit.  Without a TPU, or with fewer chips than the cell asks
-for, or on a chip missing from ``bench/peaks.json``, it prints no result and
-exits with code 2.
+``--trace 0``, its per-layer metrics with ``--trace 1``), ``device`` (its
+``memory_peak_bytes`` and ``hbm_gb`` are the fullest of the cell's chips),
+with ``--trace 1`` a ``breakdown``, and last ``checks``: each number
+compared, beside its limit.  Without a TPU, or with fewer chips than the
+cell asks for, or on a chip missing from ``bench/peaks.json``, it prints no
+result and exits with code 2.
 """
 from __future__ import annotations
 
@@ -94,14 +95,19 @@ def _program():
 
 
 def per_layer(cell: cells.Cell, red, peak: dict, *, iterations, cycles,
-              n: int, nnz: int) -> dict:
-    """The cell's per-layer metrics that its readers find in ``red``;
-    ``cycles`` holds each solve's restart-cycle lengths."""
+              n: int, nnz: int, steps=None, spmvs=None) -> dict:
+    """The cell's per-layer metrics that its readers find in ``red``.
+
+    Per solve of the window: ``iterations``, ``cycles`` (its restart-cycle
+    lengths), and the restart driver's counters ``steps`` (inner-loop trips
+    run) and ``spmvs`` (operator applications run), ``None`` where the
+    program does not count them.  A reader whose input is missing returns
+    ``None`` and its metric is left out."""
     ctx = types.SimpleNamespace(
-        iterations=iterations, cycles=cycles, config=cell.config,
-        traffic=cell.traffic,
-        peak=peak, n=n, nnz=nnz, layer_s=red.layer_s, busy_s=red.busy_s,
-        window_s=red.window_s)
+        iterations=iterations, cycles=cycles, steps=steps, spmvs=spmvs,
+        config=cell.config, traffic=cell.traffic,
+        peak=peak, n=n, nnz=nnz, layer_s=red.layer_s, scope_s=red.scope_s,
+        chips=red.chips, busy_s=red.busy_s, window_s=red.window_s)
     metrics = {}
     for spec in cell.per_layer:
         value = cells.metric_reader(spec["name"])(ctx)
@@ -111,10 +117,10 @@ def per_layer(cell: cells.Cell, red, peak: dict, *, iterations, cycles,
 
 
 def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
-             t0: float, peak: dict, memory_stats, grid=None) -> dict:
+             t0: float, peak: dict, devices, grid=None) -> dict:
     """Set up, run the window, check it against the reference, and return
     the result object.  ``grid`` cuts the operator (CPU tests only);
-    ``memory_stats`` reads the device's memory counters."""
+    ``devices`` are the cell's chips, whose memory counters are read."""
     phases = {"start": time.perf_counter() - t0}
     lap = time.perf_counter()
 
@@ -188,12 +194,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
     if window_compiles:
         raise SystemExit(f"{window_compiles} compile(s) inside the window")
 
-    stats = memory_stats() or {}
-    missing = [k for k in ("peak_bytes_in_use", "peak_bytes_reserved")
-               if k not in stats]
-    if missing:
-        raise SystemExit(f"device memory counters missing: {missing}")
-    hbm_bytes = stats["peak_bytes_in_use"] + stats["peak_bytes_reserved"]
+    hbm_bytes = max(_peak_bytes(d) for d in devices)
     del b, args, solve, compiled
 
     results = [prog.result(dict(s, x=np.asarray(s["x"]))) for s in done]
@@ -211,6 +212,9 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
     its = [res.iterations for res in results]
     cycles = [roofline.cycle_lengths(res.rrn_history, cfg["target_rrn"], m)
               for res in results]
+    counters = {k: [getattr(res, k, None) for res in results]
+                for k in ("steps", "spmvs")}
+    counters = {k: (None if None in v else v) for k, v in counters.items()}
     _log(f"# iterations {its} cycles {cycles} rrn {rrns}")
 
     device = dict(platform=jax.devices()[0].platform,
@@ -220,7 +224,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         # the trace directory holds all that the readers need, to re-read
         (trace_dir / "hlo.txt").write_text(hlo_text)
-        inputs = dict(iterations=its, cycles=cycles, n=n, nnz=nnz)
+        inputs = dict(iterations=its, cycles=cycles, n=n, nnz=nnz,
+                      **counters)
         (trace_dir / "inputs.json").write_text(json.dumps(inputs))
         red = devtrace.reduce(trace_dir, hlo_text, n=n, nnz=nnz, m=m)
         out["metrics"] = per_layer(cell, red, peak, **inputs)
@@ -242,6 +247,17 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
         _log(f"check {name} {c['value']} limit {c['limit']}")
     out["checks"] = checks
     return out
+
+
+def _peak_bytes(device) -> int:
+    """Peak device memory: ``peak_bytes_in_use`` + ``peak_bytes_reserved``
+    (the reservation carries the program's scratch)."""
+    stats = device.memory_stats() or {}
+    missing = [k for k in ("peak_bytes_in_use", "peak_bytes_reserved")
+               if k not in stats]
+    if missing:
+        raise SystemExit(f"device memory counters missing: {missing}")
+    return stats["peak_bytes_in_use"] + stats["peak_bytes_reserved"]
 
 
 def use_checkout_cache() -> None:
@@ -282,7 +298,7 @@ def main(argv=None) -> int:
         return 2
     use_checkout_cache()
     out = run_cell(cell, args.seed, args.seconds, bool(args.trace), t0=T0,
-                   peak=peak, memory_stats=devices[0].memory_stats)
+                   peak=peak, devices=devices[:cell.chips])
     print(json.dumps(out), flush=True)
     return 0
 

@@ -3,6 +3,8 @@ whole run with the timed path as it is, with each fault that a cell can
 have planted underneath it, and with the control in the program's place."""
 from __future__ import annotations
 
+import dataclasses
+import json
 import sys
 import time
 import types
@@ -25,15 +27,17 @@ SEED = 2 ** 31 + 12345
 CELLS = ["atmos7_108.float32", "atmos7_108.frsz2_16"]
 
 
-def _memory():
-    return {"peak_bytes_in_use": 3_000_000, "peak_bytes_reserved": 1_000_000}
+def _chip(in_use=3_000_000, reserved=1_000_000):
+    """A device whose memory counters read as given."""
+    stats = {"peak_bytes_in_use": in_use, "peak_bytes_reserved": reserved}
+    return types.SimpleNamespace(memory_stats=lambda: stats)
 
 
-def _run(cell, trace=False, seed=SEED):
+def _run(cell, trace=False, seed=SEED, devices=(_chip(),)):
     return run.run_cell(cells.load_cell(cell), seed, 0.0, trace,
                         t0=time.perf_counter(),
                         peak=roofline.peak("TPU v5 lite"),
-                        memory_stats=_memory, grid=GRID)
+                        devices=devices, grid=GRID)
 
 
 def test_main_refuses_without_a_tpu(capsys):
@@ -43,6 +47,30 @@ def test_main_refuses_without_a_tpu(capsys):
     assert rc == 2
     assert out.out == ""
     assert "needs a TPU" in out.err
+
+
+def test_main_refuses_fewer_chips_than_the_cell_asks_for(monkeypatch,
+                                                         capsys):
+    one = cells.load_cell("atmos7_108.float32")
+    monkeypatch.setattr(cells, "load_cell",
+                        lambda name: dataclasses.replace(one, chips=4))
+    tpu = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(run.jax, "devices", lambda: [tpu])
+    rc = run.main(["--workload", "atmos7_108.float32", "--seed", "1",
+                   "--seconds", "1", "--trace", "0"])
+    out = capsys.readouterr()
+    assert rc == 2
+    assert out.out == ""
+    assert "needs 4 chips, JAX found 1" in out.err
+
+
+def test_hbm_gb_is_the_fullest_chip():
+    chips = (_chip(3_000_000, 1_000_000), _chip(5_000_000, 2_500_000),
+             _chip(6_000_000, 0), _chip(1_000_000, 1_000_000))
+    out = _run("atmos7_108.float32", devices=chips)
+    assert out["correct"] is True
+    assert out["metrics"]["hbm_gb"]["value"] == 7_500_000 / 1e9
+    assert out["device"]["memory_peak_bytes"] == 7_500_000
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -60,6 +88,23 @@ def test_a_tiny_run_is_correct_and_reports_its_metrics(cell, capsys):
     err = capsys.readouterr().err.strip().splitlines()
     assert err[-2].startswith("check rrn_max ")
     assert err[-1] == "check operator_mismatch 0 limit 0"
+
+
+def test_a_traced_run_hands_the_drivers_counters_to_the_readers():
+    """``--trace 1`` keeps each solve's ``steps`` and ``spmvs`` beside its
+    iterations in ``inputs.json`` and passes them to the readers; on the
+    CPU the trace has no device plane, so only the counters' readers
+    find something to read."""
+    out = _run("atmos7_108.float32", trace=True)
+    inputs = json.loads((run.OUT / "trace" / "atmos7_108.float32" /
+                         "inputs.json").read_text())
+    its, steps = inputs["iterations"], inputs["steps"]
+    assert out["correct"] is True and len(its) == len(steps) == 1
+    assert steps == [100] and 0 < its[0] < 100
+    assert inputs["spmvs"][0] > its[0]
+    assert set(out["metrics"]) == {"iterations", "useful_step_pct"}
+    assert out["metrics"]["useful_step_pct"]["value"] == 100.0 * its[0] / 100
+    assert out["device"]["busy_s"] == 0.0
 
 
 def _planted(fault):

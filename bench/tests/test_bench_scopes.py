@@ -174,9 +174,31 @@ def test_the_harness_reads_the_scoped_program_as_recorded(recorded):
     cell = cells.load_cell("atmos7_108.float32")
     metrics = run.per_layer(cell, red, roofline.peak("TPU v5 lite"),
                             **inputs)
-    assert metrics == result["metrics"]
+    # what the fixture recorded reads the same; of the readers added since,
+    # only store_copy_ms finds its input (the fixture has no counters)
+    assert set(metrics) == set(result["metrics"]) | {"store_copy_ms"}
+    assert {k: metrics[k] for k in result["metrics"]} == result["metrics"]
     assert {"device_ops": red.top_ops(10),
             "idle_gaps": red.top_gaps(10)} == result["breakdown"]
+
+
+def test_device_time_by_layer_and_innermost_scope(recorded):
+    """Each layer's time splits by the innermost named scope of its ops;
+    the store copies that XLA adds sit under none, and are what
+    ``store_copy_ms`` reads."""
+    inputs, _, _, red, _ = recorded
+    by_layer: dict = {}
+    for (layer, _), s in red.scope_s.items():
+        by_layer[layer] = by_layer.get(layer, 0.0) + s
+    assert by_layer == pytest.approx(red.layer_s)
+    assert {("spmv", "spmv"), ("basis", "dots"), ("basis", "combine"),
+            ("basis", "store"), ("driver", "givens"),
+            ("basis", "")} <= set(red.scope_s)
+    assert {scope for _, scope in red.scope_s} <= set(SCOPES) | {""}
+    copy = run.per_layer(cells.load_cell("atmos7_108.float32"), red,
+                         roofline.peak("TPU v5 lite"),
+                         **inputs)["store_copy_ms"]["value"]
+    assert copy == 1e3 * red.scope_s[("basis", "")]
 
 
 def test_the_chip_program_carries_its_scopes_and_they_move_no_op(recorded):

@@ -244,12 +244,32 @@ def test_benchmark_json_keeps_the_contract_shape():
 @pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
 def test_every_cell_resolves_with_its_metrics(workload):
     cell = cells.load_cell(workload)
-    assert cell.chips == 1
+    assert cell.chips in (1, 4)
     assert cell.config["operator_sha256"]
     assert {"setup_s", "solve_s"} <= {m["name"] for m in cell.end_to_end}
     assert cell.per_layer
     for m in cell.per_layer:
         assert callable(cells.metric_reader(m["name"]))
+
+
+def _four_chip_cells_allowed(workloads) -> bool:
+    """The contract's rule: at most half the cells, rounded down, ask for
+    four chips, and one always may."""
+    four = sum(w["chips"] == 4 for w in workloads)
+    return four <= max(1, len(workloads) // 2)
+
+
+def test_at_most_half_the_cells_take_four_chips():
+    assert {w["chips"] for w in SPEC["workloads"]} <= {1, 4}
+    assert _four_chip_cells_allowed(SPEC["workloads"])
+
+
+@pytest.mark.parametrize("four,cells_,allowed", [
+    (0, 3, True), (1, 1, True), (1, 3, True), (2, 3, False), (4, 8, True),
+    (5, 8, False), (12, 24, True), (13, 24, False)])
+def test_the_four_chip_rule_counts_half_rounded_down(four, cells_, allowed):
+    workloads = [{"chips": 4}] * four + [{"chips": 1}] * (cells_ - four)
+    assert _four_chip_cells_allowed(workloads) is allowed
 
 
 def _digests(root):
@@ -282,5 +302,44 @@ def test_added_workload_is_picked_up_without_editing_a_file(tmp_path):
     assert "solves" in [m["name"] for m in cell.per_layer]
     read = cells.metric_reader("solves", root=root)
     assert read(type("C", (), {"iterations": [3, 4]})) == 2.0
+    after = _digests(root / "bench")
+    assert {p: d for p, d in after.items() if p in before} == before
+
+
+def test_added_four_chip_workload_is_picked_up_without_editing_a_file(
+        tmp_path):
+    """A later change adds a cell on four chips with a configuration, a
+    traffic file and entries alone: it resolves with its metrics, keeps to
+    the four-chip rule, and no file of the benchmark changes."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    before = _digests(root / "bench")
+    cfg = _config("atmos7_108")
+    cfg.update(name="atmos7_336", n=336 ** 3)
+    cfg["stencil"] = dict(cfg["stencil"], grid=[336, 336, 336])
+    (root / "bench" / "configs" / "atmos7_336.json").write_text(
+        json.dumps(cfg))
+    (root / "bench" / "traffic" / "float32_shard4.json").write_text(
+        json.dumps({"storage": "float32", "kernels": False}))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    name = "atmos7_336.float32_shard4"
+    spec["configs"].append({"name": "atmos7_336", "source": "arXiv",
+                            "file": "bench/configs/atmos7_336.json",
+                            "reduced": [], "why": "one system on 4 chips"})
+    spec["workloads"].append({"name": name, "config": "atmos7_336",
+                              "traffic": "float32_shard4", "chips": 4,
+                              "why": "halo exchange and all-reduces"})
+    for m in spec["per_layer"]:
+        m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    cell = cells.load_cell(name, root=root)
+    assert cell.chips == 4 and cell.config["n"] == 336 ** 3
+    assert _four_chip_cells_allowed(spec["workloads"])
+    assert {m["name"] for m in cell.per_layer} == \
+        {m["name"] for m in SPEC["per_layer"]}
+    for m in cell.per_layer:
+        assert callable(cells.metric_reader(m["name"], root=root))
     after = _digests(root / "bench")
     assert {p: d for p, d in after.items() if p in before} == before
