@@ -67,9 +67,11 @@ def psum(x, axis_name: str):
     ``raw-collective`` rule rejects direct ``lax.psum`` elsewhere): a
     reduction routed here is priced by :func:`reduce_bytes` with
     ``compressed=False``, so the wire accounting the benchmarks gate on
-    stays complete by construction.
+    stays complete by construction.  Named scope ``allreduce``, as every
+    reduction of this module.
     """
-    return jax.lax.psum(x, axis_name)
+    with jax.named_scope("allreduce"):
+        return jax.lax.psum(x, axis_name)
 
 
 def gather_operand(x_local, axis_name: str):
@@ -107,7 +109,8 @@ def compressed_pmean(tree, axis_name: str):
         mean = jnp.mean(_gathered_shards(x, axis_name), axis=0)
         return mean[: x.size].reshape(x.shape).astype(x.dtype)
 
-    return jax.tree.map(leaf_pmean, tree)
+    with jax.named_scope("allreduce"):
+        return jax.tree.map(leaf_pmean, tree)
 
 
 def compressed_psum(tree, axis_name: str):
@@ -123,7 +126,8 @@ def compressed_psum(tree, axis_name: str):
         total = jnp.sum(_gathered_shards(x, axis_name), axis=0)
         return total[: x.size].reshape(x.shape).astype(x.dtype)
 
-    return jax.tree.map(leaf_psum, tree)
+    with jax.named_scope("allreduce"):
+        return jax.tree.map(leaf_psum, tree)
 
 
 # ---------------------------------------------------------------------------
@@ -232,21 +236,24 @@ def halo_exchange(x_local, strips, n_shards: int, axis_name: str, *,
 
     Runs inside ``shard_map`` with ``axis_name`` bound.  ``compressed``
     ships the strips as FRSZ2 codes (:func:`halo_wire_spec` — half the
-    operand width).
+    operand width).  Named scope ``halo``.
     """
     n_local = x_local.shape[0]
     left, right = [], []
-    for k, s in enumerate(strips, start=1):
-        if not 0 < s <= n_local:
-            raise ValueError(f"strip {k} of {strips} not in (0, {n_local}]")
-        # left halo: the trailing s values of the k-hop left neighbor
-        left.append(_pshift(x_local[n_local - s:], +k, n_shards, axis_name,
-                            compressed))
-        # right halo: the leading s values of the k-hop right neighbor
-        right.append(_pshift(x_local[:s], -k, n_shards, axis_name,
-                             compressed))
-    # farthest-first on the left, nearest-first on the right: global order
-    return jnp.concatenate(left[::-1] + [x_local] + right)
+    with jax.named_scope("halo"):
+        for k, s in enumerate(strips, start=1):
+            if not 0 < s <= n_local:
+                raise ValueError(
+                    f"strip {k} of {strips} not in (0, {n_local}]")
+            # left halo: the trailing s values of the k-hop left neighbor
+            left.append(_pshift(x_local[n_local - s:], +k, n_shards,
+                                axis_name, compressed))
+            # right halo: the leading s values of the k-hop right neighbor
+            right.append(_pshift(x_local[:s], -k, n_shards, axis_name,
+                                 compressed))
+        # farthest-first on the left, nearest-first on the right: global
+        # order
+        return jnp.concatenate(left[::-1] + [x_local] + right)
 
 
 def halo_exchange_3d(x_local, send_idx, rounds, axis_name: str, *,
@@ -267,17 +274,19 @@ def halo_exchange_3d(x_local, send_idx, rounds, axis_name: str, *,
     ships each round's buffer as FRSZ2 codes (:func:`halo_wire_spec`).
     Runs inside ``shard_map`` with ``axis_name`` bound; under ``jax.vmap``
     the gathers/ppermutes batch, so one exchange serves a whole RHS block.
+    Named scope ``halo``.
     """
     defect = rounds_defect(rounds)
     if defect is not None:
         raise ValueError(f"malformed exchange rounds: {defect}")
-    bufs = [
-        _ppermute(x_local[..., idx], axis_name, list(pairs), compressed)
-        for idx, pairs in zip(send_idx, rounds)
-    ]
-    if not bufs:
-        return x_local
-    return jnp.concatenate([x_local, *bufs], axis=-1)
+    with jax.named_scope("halo"):
+        bufs = [
+            _ppermute(x_local[..., idx], axis_name, list(pairs), compressed)
+            for idx, pairs in zip(send_idx, rounds)
+        ]
+        if not bufs:
+            return x_local
+        return jnp.concatenate([x_local, *bufs], axis=-1)
 
 
 def exchange_bytes(sizes, *, compressed: bool = False,

@@ -1027,7 +1027,7 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
                   target_rrn: float = 1e-14, arith_dtype=None,
                   eta: float = 0.7071067811865475, matvec=None,
                   reorder: str = "auto"):
-    """The one-device program that ``gmres(A, b, ...)`` runs.
+    """The program that ``gmres(A, b, ...)`` runs.
 
     Returns ``(solve, args, plan)``: the cached jitted solve, the
     arguments ``gmres`` calls it with (``b``, ``x0`` and the operator's
@@ -1035,12 +1035,31 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
     without one).  ``solve.lower(*args).compile()`` is the executable
     ``gmres`` runs, for reading its memory analysis and HLO.
 
-    Host spans: ``gmres.solve_program`` around it all, ``gmres.plan`` around
-    the plan, the pipeline and the operator's SpMV
-    (:func:`~repro.sparse.csr.operator_matvec`), and
-    ``gmres.lookup`` around the fingerprint and the compiled-solve cache.
+    The layout follows from the input (:func:`repro.solver.layout.
+    solve_chips`): a solve that fits one chip's memory is the one-device
+    program; one that does not runs on every local chip
+    (:func:`repro.solver.sharded.sharded_program`), behind the same
+    contract: ``plan`` is ``None``, the state's ``x`` is in the operator's
+    order and its Krylov stores stay on the chips.
+
+    Host spans: ``gmres.solve_program`` around it all, ``gmres.layout``
+    around the choice of chips (and on several, the placement of ``b``),
+    ``gmres.plan`` around the plan, the pipeline and the operator's SpMV
+    (:func:`~repro.sparse.csr.operator_matvec`), and ``gmres.lookup``
+    around the fingerprint and the compiled-solve cache.
     """
     with jax.profiler.TraceAnnotation("gmres.solve_program"):
+        with jax.profiler.TraceAnnotation("gmres.layout"):
+            chips = _layout(A, b, storage, policy, m, arith_dtype, matvec,
+                            target_rrn, reorder)
+        if chips > 1:
+            from repro.solver.sharded import sharded_program
+
+            solve, args = sharded_program(
+                A, b, chips, x0=x0, storage=storage, policy=policy,
+                precond=precond, ortho=ortho, m=m, max_iters=max_iters,
+                target_rrn=target_rrn, arith_dtype=arith_dtype, eta=eta)
+            return solve, args, None
         with jax.profiler.TraceAnnotation("gmres.plan"):
             (plan, A, b, x0, accs, policy, arith_dtype, mv, precond,
              ortho) = _prepare(A, b, x0, storage, policy, precond, ortho, m,
@@ -1051,6 +1070,23 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
                                            policy, m, max_iters, eta,
                                            target_rrn, ortho, precond, plan)
         return solve, (b, x0, operand), plan
+
+
+def _layout(A, b, storage, policy, m, arith_dtype, matvec, target_rrn,
+            reorder) -> int:
+    """The chips a ``solve_program`` solve runs on: one, unless the
+    operator can be split by rows (a CSR, no user ``matvec``, no forced
+    reordering) and the one-device solve would not fit a chip."""
+    from repro.solver.layout import solve_chips
+    from repro.sparse.csr import CSR
+
+    if matvec is not None or reorder == "rcm" or not isinstance(A, CSR):
+        return 1
+    if arith_dtype is None:
+        arith_dtype = b.dtype
+    formats = resolve_policy(policy, storage, arith_dtype, target_rrn,
+                             m).formats()
+    return solve_chips(A, A.shape[0], m, formats, arith_dtype)
 
 
 def gmres_batched(

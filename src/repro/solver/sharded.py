@@ -46,7 +46,7 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.accessor import BasisAccessor, BlockBasisAccessor, ShardedFormat
 from repro.dist.context import DistContext
@@ -71,10 +71,11 @@ from repro.solver.pipeline import (
     resolve_policy,
     resolve_preconditioner,
 )
+from repro.sparse.csr import CSR
 from repro.sparse.plan import plan_operator
 from repro.sparse.shard import partition_matvec
 
-__all__ = ["sharded_gmres"]
+__all__ = ["sharded_gmres", "sharded_program"]
 
 _TRANSPORTS = ("plain", "compressed", "compressed+norms")
 
@@ -226,6 +227,76 @@ def sharded_gmres(A, b, *, batched: bool = False, x0=None, storage=None,
     ]
 
 
+def sharded_program(A, b, n_shards: int, *, x0=None, storage=None,
+                    policy=None, precond=None, ortho="mgs", m: int = 100,
+                    max_iters: int = 20000, target_rrn: float = 1e-14,
+                    arith_dtype=None, eta: float = 0.7071067811865475,
+                    axis_name: str = "basis"):
+    """The program :func:`repro.solver.gmres.solve_program` returns for a
+    solve that does not fit one chip: ``(solve, args)`` with
+    ``solve(*args)`` the state of ``gmres``'s device driver, as on one
+    chip.
+
+    The operator is planned as ``n_shards`` contiguous slabs of rows in
+    its own order (no reordering, no 3-D blocks; rows zero-padded to a
+    multiple of ``n_shards``), with the halo SpMV
+    (:func:`repro.sparse.shard.partition_matvec`).  ``args`` are ``(b,
+    x0, operand)``: ``b`` and ``x0`` padded and placed by slab, the
+    operand the cached slabs of the operator, placed from the host; the
+    CSR's own arrays then move to the host
+    (:meth:`~repro.sparse.csr.CSR.to_host`), so that no chip holds the
+    whole operator.  The state's ``x`` is in the operator's order, trimmed
+    to ``n`` inside the program; the Krylov stores stay sharded on the
+    chips.
+
+    Host spans (inside ``gmres.solve_program``): ``gmres.layout`` around
+    the placement of ``b`` and ``x0``, ``gmres.plan`` around the plan and
+    the pipeline, ``gmres.lookup`` around the compiled-solve cache.
+    """
+    with jax.profiler.TraceAnnotation("gmres.plan"):
+        plan, precond = _plan_and_precond(A, n_shards, "none", "halo",
+                                          precond)
+        if arith_dtype is None:
+            arith_dtype = b.dtype
+        policy, accs, ortho_obj, precond_obj, dist = _slab_pipeline(
+            plan.operator, plan.n_local, plan.n_pad, storage, policy,
+            precond, ortho, m, target_rrn, arith_dtype, axis_name)
+    with jax.profiler.TraceAnnotation("gmres.lookup"):
+        solve, operand = _cached_sharded_solve(
+            plan, False, accs, policy, m, max_iters, eta, target_rrn,
+            ortho_obj, precond_obj, dist, axis_name, False, "vmap",
+            program=True)
+        if isinstance(A, CSR):
+            A.to_host()          # each chip holds its slab: free the rest
+    with jax.profiler.TraceAnnotation("gmres.layout"):
+        mesh = Mesh(np.asarray(jax.devices()[:n_shards]), (axis_name,))
+        slabs = NamedSharding(mesh, vector_partition_spec(axis_name))
+        b = jax.device_put(plan.embed(b).astype(arith_dtype), slabs)
+        if x0 is None:
+            x0 = jnp.zeros(plan.n_pad, arith_dtype, device=slabs)
+        else:
+            x0 = jax.device_put(plan.embed(x0).astype(arith_dtype), slabs)
+    return solve, (b, x0, operand)
+
+
+def _slab_pipeline(operator, n_local, n_pad, storage, policy, precond,
+                   ortho, m, target_rrn, arith_dtype, axis_name):
+    """The pipeline of :func:`sharded_program` over slabs of ``n_local``
+    rows: the policy's formats sharded (plain transport), their accessors,
+    the orthogonalizer, the preconditioner's local part and the norms'
+    context."""
+    policy = _wrap_policy(
+        resolve_policy(policy, storage, arith_dtype, target_rrn, m),
+        axis_name, False)
+    accs = tuple(BasisAccessor(fmt=f, m=m + 1, n=n_local,
+                               arith_dtype=arith_dtype)
+                 for f in policy.formats())
+    precond = resolve_preconditioner(precond, operator).shard_local(
+        axis_name, n_local, n_pad)
+    return (policy, accs, orthogonalizer_by_name(ortho), precond,
+            DistContext(axis_name=axis_name))
+
+
 def _plan_and_precond(A, p_dev, reorder, partition_mode, precond,
                       pgrid=None):
     """Plan the operator and carry the preconditioner through the plan's
@@ -267,7 +338,19 @@ def _build_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
     # matvecs; the explicit residual recomputations always ride an exact
     # exchange, else the codec error floors the attainable rrn (same split
     # as lossy basis storage vs exact arithmetic in CB-GMRES itself)
-    local_rmv = local_mv.exact
+    sm = _sharded_fn(mesh, op_specs, local_mv, local_mv.exact, batched,
+                     accs, policy, m, max_iters, eta, target_rrn, ortho,
+                     precond, dist, axis_name, method)
+    return jax.jit(sm), operand
+
+
+def _sharded_fn(mesh, op_specs, local_mv, local_rmv, batched, accs, policy,
+                m, max_iters, eta, target_rrn, ortho, precond, dist,
+                axis_name, method):
+    """The restart driver under ``shard_map`` over ``mesh``:
+    ``(operand, b, x0) -> state``, each device contracting its rows with
+    ``local_mv`` (``local_rmv`` for the explicit residuals).  Needs no
+    data, so a described topology can compile it."""
 
     if method == "block":
         # the block driver batches the matvec itself (jax.vmap inside the
@@ -303,16 +386,18 @@ def _build_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
         vec_spec = vector_partition_spec(axis_name, batched=batched)
         state_specs = driver_partition_specs(accs, axis_name,
                                              batched=batched)
-    sm = jax.shard_map(run, mesh=mesh,
-                       in_specs=(op_specs, vec_spec, vec_spec),
-                       out_specs=state_specs, axis_names={axis_name},
-                       check_vma=False)
-    return jax.jit(sm), operand
+    return jax.shard_map(run, mesh=mesh,
+                         in_specs=(op_specs, vec_spec, vec_spec),
+                         out_specs=state_specs, axis_names={axis_name},
+                         check_vma=False)
 
 
 def _cached_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
                           target_rrn, ortho, precond, dist, axis_name,
-                          compressed_halo, method):
+                          compressed_halo, method, program: bool = False):
+    """The compiled sharded solve and its operand (cached): ``solve(operand,
+    b, x0)``, or with ``program`` ``solve(b, x0, operand)`` with ``x``
+    trimmed to ``n`` inside, as :func:`sharded_program` returns it."""
     pins: tuple = ()
 
     def make_key():
@@ -327,12 +412,20 @@ def _cached_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
                 dist.spec(), accs[0].m, accs[0].n,
                 jnp.dtype(accs[0].arith_dtype).name, m, max_iters,
                 float(eta), float(target_rrn), plan.n_shards, axis_name,
-                compressed_halo)
+                compressed_halo, program)
 
     def build():
         solve, operand = _build_sharded_solve(
             plan, batched, accs, policy, m, max_iters, eta, target_rrn,
             ortho, precond, dist, axis_name, compressed_halo, method)
+        if program:
+            run, n = solve, plan.n
+
+            def solve(b, x0, op):
+                state = run(op, b, x0)
+                return dict(state, x=state["x"][:n])
+
+            solve = jax.jit(solve)
         return solve, operand, pins
 
     ent = _lru_cached(_SHARDED_CACHE, _SHARDED_CACHE_SIZE, make_key, build)

@@ -93,13 +93,27 @@ class CSR:
             fp = self._fingerprint = h.hexdigest()
         return fp
 
+    def to_host(self) -> None:
+        """Move this operator's arrays to the host (numpy), freeing their
+        device copies and its cached DIA form's.  A solve across chips
+        calls it once each chip holds its slab, so that no chip holds the
+        whole operator; every method works on host arrays."""
+        self.indptr, self.indices, self.data = (
+            np.asarray(a) for a in (self.indptr, self.indices, self.data))
+        self.__dict__.pop("_dia", None)
+
     def bandwidth(self) -> int:
         """max |col - row| over nonzero entries (host-side, cached).
 
         Explicitly-stored zeros are excluded: they contribute nothing to a
-        matvec, so the halo partitioner may ignore their columns.
+        matvec, so the halo partitioner may ignore their columns.  Read
+        off the diagonals where :meth:`dia_arrays` has them.
         """
         bw = getattr(self, "_bandwidth", None)
+        if bw is None and getattr(self, "_dia_arrays", None) is not None:
+            offsets, vals = self._dia_arrays
+            live = [abs(o) for o, v in zip(offsets, vals) if v.any()]
+            bw = self._bandwidth = max(live, default=0)
         if bw is None:
             indptr = np.asarray(self.indptr)
             rows = np.repeat(np.arange(self.shape[0]), np.diff(indptr))
@@ -138,13 +152,23 @@ class CSR:
         d = jnp.zeros(self.shape, self.data.dtype)
         return d.at[self.row_ids(), self.indices].add(self.data)
 
-    def to_dia(self) -> DIA | None:
-        """This operator stored by diagonal (host-side, cached), or ``None``
-        where its structure does not suit: more than
+    def dia_arrays(self):
+        """``(offsets, vals)`` of this operator stored by diagonal, on the
+        host (cached): a tuple of ``K`` offsets and a ``(K, n)`` numpy
+        array, or ``None`` where its structure does not suit: more than
         :data:`DIA_MAX_OFFSETS` distinct diagonals, or more than
         :data:`DIA_MAX_FILL` stored values per nonzero."""
+        if not hasattr(self, "_dia_arrays"):
+            self._dia_arrays = _dia_from_csr(self)
+        return self._dia_arrays
+
+    def to_dia(self) -> DIA | None:
+        """This operator stored by diagonal on the default device (cached),
+        or ``None`` where :meth:`dia_arrays` declines it."""
         if not hasattr(self, "_dia"):
-            self._dia = _dia_from_csr(self)
+            host = self.dia_arrays()
+            self._dia = None if host is None else DIA(
+                host[0], tuple(jnp.asarray(v) for v in host[1]), self.shape)
         return self._dia
 
 
@@ -181,7 +205,8 @@ class DIA:
 
 @functools.lru_cache(maxsize=64)
 def _dia_program(offsets: tuple, n: int):
-    """The jitted SpMV of a DIA structure, one per ``(offsets, n)``."""
+    """The jitted SpMV of a DIA structure, one per ``(offsets, n)``: the
+    shifted-slice sum over ``x`` padded by zeros."""
     lo = max(0, -min(offsets))
     hi = max(0, max(offsets))
 
@@ -189,17 +214,28 @@ def _dia_program(offsets: tuple, n: int):
     def apply(vals, x):
         with jax.named_scope("spmv"), jax.named_scope("dia"):
             xp = jnp.pad(x.astype(vals[0].dtype), (lo, hi))
-            y = vals[0] * xp[lo + offsets[0]:lo + offsets[0] + n]
-            for k in range(1, len(offsets)):
-                off = lo + offsets[k]
-                y = y + vals[k] * xp[off:off + n]
-            return y
+            return dia_sum(offsets, vals, xp, lo, n)
 
     return apply
 
 
-def _dia_from_csr(A: CSR) -> DIA | None:
-    """:meth:`CSR.to_dia`, uncached: O(nnz + K n) numpy, no loop over rows."""
+def dia_sum(offsets: tuple, vals, xp, lo: int, n: int):
+    """``y[i] = sum_k vals[k][i] * xp[lo + i + offsets[k]]`` for ``i < n``,
+    with static slices of ``xp``: ``x`` extended by at least ``lo`` values
+    on the left and ``max(offsets)`` on the right.  The DIA SpMV pads
+    ``x`` with zeros; the sharded halo SpMV
+    (:func:`repro.sparse.shard.partition_matvec`) extends a slab with its
+    neighbours' values."""
+    y = vals[0] * xp[lo + offsets[0]:lo + offsets[0] + n]
+    for k in range(1, len(offsets)):
+        off = lo + offsets[k]
+        y = y + vals[k] * xp[off:off + n]
+    return y
+
+
+def _dia_from_csr(A: CSR):
+    """:meth:`CSR.dia_arrays`, uncached: O(nnz + K n) numpy, no loop over
+    rows."""
     n = A.shape[0]
     indptr = np.asarray(A.indptr)
     nnz = int(indptr[-1])
@@ -222,8 +258,7 @@ def _dia_from_csr(A: CSR) -> DIA | None:
     data = np.asarray(A.data)[:nnz]
     vals = np.zeros(K * n, data.dtype)
     vals[flat] = data
-    return DIA(tuple(int(o) for o in offsets),
-               tuple(jnp.asarray(v) for v in vals.reshape(K, n)), (n, n))
+    return tuple(int(o) for o in offsets), vals.reshape(K, n)
 
 
 def operator_matvec(A):
@@ -319,9 +354,15 @@ def csr_from_coo(rows, cols, vals, shape) -> CSR:
     rows = np.asarray(rows)
     order = np.argsort(rows, kind="stable")
     rows, cols, vals = rows[order], np.asarray(cols)[order], np.asarray(vals)[order]
+    counts = np.bincount(rows, minlength=shape[0])
+    return csr_from_counts(counts, cols, vals, shape)
+
+
+def csr_from_counts(counts, cols, vals, shape) -> CSR:
+    """Build CSR from entries in row order, ``counts[i]`` of them in row
+    ``i``."""
     indptr = np.zeros(shape[0] + 1, np.int32)
-    np.add.at(indptr, rows + 1, 1)
-    indptr = np.cumsum(indptr)
+    np.cumsum(counts, out=indptr[1:])
     return CSR(
         indptr=jnp.asarray(indptr, jnp.int32),
         indices=jnp.asarray(cols, jnp.int32),

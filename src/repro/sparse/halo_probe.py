@@ -87,10 +87,10 @@ def _ell_arrays(A):
     return None
 
 
-def _bandwidth_of(A, ell) -> int:
+def _bandwidth_of(A, ell=None) -> int:
     if hasattr(A, "bandwidth"):
         return A.bandwidth()
-    cols, vals = ell
+    cols, vals = _ell_arrays(A) if ell is None else ell
     live = np.asarray(vals) != 0
     rows = np.arange(np.asarray(cols).shape[0])[:, None]
     off = np.abs(np.asarray(cols) - rows)[live]
@@ -108,11 +108,11 @@ def halo_probe(A, n_shards: int, *,
     n = A.shape[0]
     n_pad = -(-n // n_shards) * n_shards
     n_local = n_pad // n_shards
-    ell = _ell_arrays(A)
-    if ell is None:
+    if not (hasattr(A, "to_ell") or (hasattr(A, "cols")
+                                     and hasattr(A, "vals"))):
         return HaloProbe(n=n, n_pad=n_pad, n_local=n_local, bandwidth=0,
                          hops=0, strips=(), mode="replicated")
-    bw = _bandwidth_of(A, ell)
+    bw = _bandwidth_of(A)
     hops = -(-bw // n_local) if bw else 0
     strips = tuple(
         min(n_local, bw - (k - 1) * n_local) for k in range(1, hops + 1)

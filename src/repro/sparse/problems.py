@@ -36,52 +36,51 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.sparse.csr import CSR, csr_from_coo
+from repro.sparse.csr import CSR, csr_from_coo, csr_from_counts
 
 __all__ = ["make_problem", "rhs_for", "PROBLEMS", "problem_suite"]
 
 
-def _stencil3d(nx, ny, nz, wind=(0.4, 0.2, 0.1), diff=1.0, dtype=np.float64):
-    """7-point convection-diffusion stencil on an nx×ny×nz grid.
+def _stencil3d(nx, ny, nz, wind=(0.4, 0.2, 0.1), diff=1.0,
+               dtype=np.float64) -> CSR:
+    """7-point convection-diffusion stencil on an nx×ny×nz grid, as a CSR.
 
     Central differences for diffusion + upwind for convection gives a
-    nonsymmetric M-matrix — the atmosmod family character.
+    nonsymmetric M-matrix — the atmosmod family character.  Each row holds
+    its diagonal, then its ±x, ±y, ±z neighbours that exist, in that
+    order; built row by row in one pass (no sort), which a 38M-row grid
+    needs.
     """
     n = nx * ny * nz
-    idx = np.arange(n).reshape(nx, ny, nz)
-    rows, cols, vals = [], [], []
-
-    def add(r, c, v):
-        rows.append(r.ravel())
-        cols.append(c.ravel())
-        vals.append(np.full(r.size, v, dtype))
-
+    shape = (nx, ny, nz)
     diag = 6.0 * diff + sum(abs(w) for w in wind)
-    add(idx, idx, diag)
+    terms = [((0, 0, 0), diag)]
     # ± x/y/z neighbours with upwind-biased convection
     for axis, w in zip(range(3), wind):
         for sgn in (+1, -1):
-            src = [slice(None)] * 3
-            dst = [slice(None)] * 3
-            if sgn > 0:
-                src[axis], dst[axis] = slice(0, -1), slice(1, None)
-            else:
-                src[axis], dst[axis] = slice(1, None), slice(0, -1)
-            r = idx[tuple(src)]
-            c = idx[tuple(dst)]
+            d = [0, 0, 0]
+            d[axis] = sgn
             off = -diff + (-w if sgn > 0 else 0.0) + (w if sgn < 0 else 0.0)
             # upwind: the coefficient against the wind is strengthened
-            add(r, c, off - 0.05 * sgn * w)
-    rows = np.concatenate(rows)
-    cols = np.concatenate(cols)
-    vals = np.concatenate(vals)
-    return rows, cols, vals, n
+            terms.append((tuple(d), off - 0.05 * sgn * w))
+    idx = np.arange(n, dtype=np.int64).reshape(shape)
+    cols = np.empty(shape + (len(terms),), np.int64)
+    live = np.zeros(shape + (len(terms),), bool)
+    for t, (d, _) in enumerate(terms):
+        has = tuple(slice(max(0, -dk), s - max(0, dk))
+                    for dk, s in zip(d, shape))
+        live[has + (t,)] = True
+        cols[..., t] = idx + (d[0] * ny + d[1]) * nz + d[2]
+    del idx
+    live = live.reshape(n, len(terms))
+    vals = np.array([v for _, v in terms], dtype)
+    return csr_from_counts(live.sum(axis=1), cols.reshape(n, -1)[live],
+                           np.broadcast_to(vals, live.shape)[live], (n, n))
 
 
 def _problem_atmosmod(n_target: int, dtype=np.float64) -> CSR:
     s = max(4, round(n_target ** (1 / 3)))
-    rows, cols, vals, n = _stencil3d(s, s, s, dtype=dtype)
-    A = csr_from_coo(rows, cols, vals, (n, n))
+    A = _stencil3d(s, s, s, dtype=dtype)
     # cell geometry for the 3-D block partitioner (a plain attribute:
     # dropped by pytree round-trips and permute_csr, which is correct —
     # a permuted operator has lost its lexicographic meaning)
@@ -271,9 +270,7 @@ def _problem_unstructured(n_target: int, dtype=np.float64) -> CSR:
 
 def _problem_stretched(n_target: int, dtype=np.float64) -> CSR:
     s = max(4, round(n_target ** (1 / 3)))
-    rows, cols, vals, n = _stencil3d(s, s, s, wind=(1.5, 0.0, 0.0), diff=0.3,
-                                     dtype=dtype)
-    A = csr_from_coo(rows, cols, vals, (n, n))
+    A = _stencil3d(s, s, s, wind=(1.5, 0.0, 0.0), diff=0.3, dtype=dtype)
     A.grid = (s, s, s)
     return A
 

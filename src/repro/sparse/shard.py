@@ -7,13 +7,18 @@ provided, selected by :func:`partition_matvec`:
 
 * ``"halo"`` (default for banded CSR/ELL) — **row-partitioned,
   neighbor-exchange halo**: a host-side probe (:func:`halo_probe`) measures
-  the column bandwidth of the operator and precomputes per-shard halo index
-  maps; at solve time each device ``ppermute``s only its boundary strips to
-  the left/right neighbors (multi-hop when the bandwidth spans several
-  chunks, :func:`repro.dist.collectives.halo_exchange`) and contracts its
-  rows against ``[left halo | local chunk | right halo]``.  Wire cost per
-  matvec: ``O(bandwidth)`` values instead of the ``O(n)`` a gathered
-  operand moves (:func:`~repro.dist.collectives.halo_bytes` vs
+  the column bandwidth of the operator; at solve time each device
+  ``ppermute``s only its boundary strips to the left/right neighbors
+  (multi-hop when the bandwidth spans several chunks,
+  :func:`repro.dist.collectives.halo_exchange`) and contracts its rows
+  against ``[left halo | local chunk | right halo]``.  Where the operator
+  stores by diagonal (:meth:`~repro.sparse.csr.CSR.dia_arrays`) each
+  device holds its slab of every diagonal and the contraction is the DIA
+  SpMV's shifted-slice sum (:func:`~repro.sparse.csr.dia_sum`), the halo
+  in place of its zero padding: no gather.  Otherwise it gathers through
+  per-shard halo-localized ELL index maps.  Wire cost per matvec:
+  ``O(bandwidth)`` values instead of the ``O(n)`` a gathered operand
+  moves (:func:`~repro.dist.collectives.halo_bytes` vs
   :func:`~repro.dist.collectives.gather_bytes`).
 
 * ``"rows"`` — **row-partitioned, gathered-halo**: the operator is
@@ -48,8 +53,9 @@ next multiple (padded rows carry val 0, padded operand entries are zeros,
 so the padded SpMV embeds the original exactly); callers pad their vectors
 to ``probe.n_pad`` and trim the result.
 
-All modes return the same triple, ready to splice into a ``shard_map``
-call::
+With a ``mesh`` the operand is placed on it, each device receiving only
+its rows from the host arrays.  All modes return the same triple, ready
+to splice into a ``shard_map`` call::
 
     operand, in_specs, local_mv = partition_matvec(A, n_shards=P)
     # shard_map(f, in_specs=(in_specs, ...)); inside f:
@@ -72,14 +78,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.collectives import (
     gather_operand,
     halo_exchange,
     halo_exchange_3d,
 )
-from repro.sparse.csr import operator_matvec
+from repro.sparse.csr import CSR, dia_sum, operator_matvec
 
 # probing/partition geometry grew into its own module; the canonical home
 # is repro.sparse.halo_probe — re-exported here for existing importers
@@ -93,8 +100,8 @@ from repro.sparse.halo_probe import (  # noqa: F401
     halo_probe,
 )
 
-__all__ = ["BlockPartition", "HaloProbe", "block_partition", "halo_probe",
-           "partition_matvec"]
+__all__ = ["BlockPartition", "HaloProbe", "block_partition", "dia_halo_matvec",
+           "halo_probe", "partition_matvec"]
 
 
 def _validate_mesh(mesh, axis_name: str, n_shards: int):
@@ -177,18 +184,30 @@ def partition_matvec(A=None, n_shards: int | None = None,
     n = plan.n
 
     exact_matvec = None
-    if mode == "halo":
+    dia = _halo_dia(A, probe.bandwidth) if mode == "halo" else None
+    if dia is not None:
+        offsets, vals = dia
+        pad = n_pad - n
+        operand = tuple(np.pad(v, (0, pad)) if pad else v for v in vals)
+        in_specs = tuple(P(axis_name) for _ in vals)
+        _halo_matvec = dia_halo_matvec(offsets, probe.strips, n_local,
+                                       n_shards, axis_name)
+
+    elif mode == "halo":
         lcols, vals = plan.ell_halo_localized()
-        operand = (jnp.asarray(lcols, jnp.int32), jnp.asarray(vals))
+        operand = (np.asarray(lcols, np.int32), vals)
         in_specs = (P(axis_name, None), P(axis_name, None))
         strips = probe.strips
 
         def _halo_matvec(op, x_local, compressed):
             lcols_l, vals_l = op                      # (n_local, w) each
-            x_ext = halo_exchange(x_local, strips, n_shards, axis_name,
-                                  compressed=compressed)
-            return (vals_l * x_ext[lcols_l].astype(vals_l.dtype)).sum(axis=1)
+            with jax.named_scope("spmv"):
+                x_ext = halo_exchange(x_local, strips, n_shards, axis_name,
+                                      compressed=compressed)
+                return (vals_l * x_ext[lcols_l].astype(vals_l.dtype)).sum(
+                    axis=1)
 
+    if mode == "halo":
         def local_matvec(op, x_local):
             return _halo_matvec(op, x_local, compressed_halo)
 
@@ -198,9 +217,9 @@ def partition_matvec(A=None, n_shards: int | None = None,
 
     elif mode == "block3d":
         blk = plan.block
-        operand = (jnp.asarray(blk.lcols, jnp.int32),
-                   jnp.asarray(blk.vals),
-                   tuple(jnp.asarray(ix, jnp.int32) for ix in blk.send_idx))
+        operand = (np.asarray(blk.lcols, np.int32),
+                   np.asarray(blk.vals),
+                   tuple(np.asarray(ix, np.int32) for ix in blk.send_idx))
         in_specs = (P(axis_name, None), P(axis_name, None),
                     tuple(P(axis_name, None) for _ in blk.send_idx))
         rounds = blk.rounds
@@ -211,14 +230,15 @@ def partition_matvec(A=None, n_shards: int | None = None,
             # issue the face ppermutes first, then contract the interior
             # rows (purely local by layout) so XLA can overlap them with
             # the in-flight exchange; only boundary rows read x_ext
-            x_ext = halo_exchange_3d(x_local, tuple(ix[0] for ix in send),
-                                     rounds, axis_name,
-                                     compressed=compressed)
-            y_int = (vals_l[:ni]
-                     * x_local[lcols_l[:ni]].astype(vals_l.dtype)).sum(axis=1)
-            y_bnd = (vals_l[ni:]
-                     * x_ext[lcols_l[ni:]].astype(vals_l.dtype)).sum(axis=1)
-            return jnp.concatenate([y_int, y_bnd])
+            with jax.named_scope("spmv"):
+                x_ext = halo_exchange_3d(x_local, tuple(ix[0] for ix in send),
+                                         rounds, axis_name,
+                                         compressed=compressed)
+                y_int = (vals_l[:ni] * x_local[lcols_l[:ni]].astype(
+                    vals_l.dtype)).sum(axis=1)
+                y_bnd = (vals_l[ni:] * x_ext[lcols_l[ni:]].astype(
+                    vals_l.dtype)).sum(axis=1)
+                return jnp.concatenate([y_int, y_bnd])
 
         def local_matvec(op, x_local):
             return _block3d_matvec(op, x_local, compressed_halo)
@@ -229,7 +249,7 @@ def partition_matvec(A=None, n_shards: int | None = None,
 
     elif mode == "rows":
         cols, vals = plan.ell_padded()
-        operand = (jnp.asarray(cols, jnp.int32), jnp.asarray(vals))
+        operand = (np.asarray(cols, np.int32), vals)
         in_specs = (P(axis_name, None), P(axis_name, None))
 
         def local_matvec(op, x_local):
@@ -250,6 +270,7 @@ def partition_matvec(A=None, n_shards: int | None = None,
             i = jax.lax.axis_index(axis_name)
             return jax.lax.dynamic_slice_in_dim(y, i * n_local, n_local)
 
+    operand = _place(operand, in_specs, mesh)
     local_matvec.mode = mode
     local_matvec.probe = probe
     local_matvec.plan = plan
@@ -258,3 +279,53 @@ def partition_matvec(A=None, n_shards: int | None = None,
     # explicit residual recomputations ride this one.
     local_matvec.exact = exact_matvec or local_matvec
     return operand, in_specs, local_matvec
+
+
+def dia_halo_matvec(offsets: tuple, strips: tuple, n_local: int,
+                    n_shards: int, axis_name: str):
+    """The halo SpMV of a slab of diagonals:
+    ``local_matvec(op, x_local, compressed)`` with ``op`` this device's
+    ``(n_local,)`` slab of each diagonal of ``offsets``.  The halo exchange
+    (``strips`` per hop, their sum the bandwidth, at least
+    ``max |offsets|``) extends the slab's ``x``, and the DIA SpMV's
+    shifted-slice sum contracts it.  Named scopes ``spmv`` around it all,
+    ``halo`` (the exchange, :mod:`repro.dist.collectives`) and ``dia``."""
+    bw = sum(strips)
+    if bw < max(abs(o) for o in offsets):
+        raise ValueError(f"a halo of {bw} values cannot cover the "
+                         f"offsets {offsets}")
+
+    def local_matvec(op, x_local, compressed=False):
+        with jax.named_scope("spmv"):
+            x_ext = halo_exchange(x_local, strips, n_shards, axis_name,
+                                  compressed=compressed)
+            with jax.named_scope("dia"):
+                return dia_sum(offsets, op, x_ext.astype(op[0].dtype), bw,
+                               n_local)
+
+    return local_matvec
+
+
+def _halo_dia(A, bandwidth: int):
+    """The diagonals the halo SpMV sums, ``(offsets, vals)`` on the host,
+    or ``None`` where ``A`` does not store by diagonal.  A diagonal past
+    the probed ``bandwidth`` holds stored zeros only and is left out, so
+    the halo of ``bandwidth`` values covers every offset summed."""
+    dia = A.dia_arrays() if isinstance(A, CSR) else None
+    if dia is None:
+        return None
+    keep = [k for k, o in enumerate(dia[0]) if abs(o) <= bandwidth]
+    if not keep:
+        return None
+    return tuple(dia[0][k] for k in keep), tuple(dia[1][k] for k in keep)
+
+
+def _place(operand, in_specs, mesh):
+    """The operand's arrays on the device: with a ``mesh``, sharded by
+    ``in_specs`` straight from the host, so that no device holds rows of
+    another; without one, on the default device."""
+    if mesh is None:
+        return jax.tree.map(jnp.asarray, operand)
+    return jax.tree.map(
+        lambda spec, a: jax.device_put(a, NamedSharding(mesh, spec)),
+        in_specs, operand, is_leaf=lambda x: isinstance(x, P))

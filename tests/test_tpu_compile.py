@@ -160,3 +160,101 @@ def test_dia_matvec_compiles(tpu_compile, s, offsets):
                       *[((n,), jnp.float32)] * (1 + len(offsets)))
     assert "spmv/dia" in hlo
     assert " gather(" not in hlo and " scatter(" not in hlo
+
+
+# -- a system no one chip holds: 336^3 on the four chips of one v5e host ----
+
+S336 = 336
+N336 = S336 ** 3        # 37,933,056 rows: the atmos7_336 configuration
+HBM = 16e9              # one v5e chip's memory
+OFFSETS_7PT = (-S336 * S336, -S336, -1, 0, 1, S336, S336 * S336)
+
+
+@pytest.fixture(scope="module")
+def four_chips(tpu_compile):
+    """A mesh over the four chips of the described ``v5e:2x2``."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.asarray(topo.devices), ("basis",))
+
+
+def _chip_bytes(compiled) -> int:
+    """Bytes one chip holds for the executable: arguments, outputs and
+    scratch, less the outputs that alias an argument."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def _float32_pipeline(n_local):
+    from repro.solver.sharded import _slab_pipeline
+
+    return _slab_pipeline(None, n_local, n_local * 4, "float32", None,
+                          None, "mgs", 100, 1.15e-6, jnp.float32, "basis")
+
+
+def test_four_chip_program_at_336_cubed_fits_each_chip(four_chips,
+                                                       tpu_compile):
+    """The solve program ``solve_program`` returns for the 336^3 system:
+    the restart driver under ``shard_map`` on four slabs of 84 x-planes,
+    with the DIA halo SpMV, compiles for the 2x2 host and holds under one
+    chip's memory on each."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.solver.sharded import _sharded_fn
+    from repro.sparse.shard import dia_halo_matvec
+
+    n_local = N336 // 4
+    with jax.enable_x64(False):
+        policy, accs, ortho, precond, dist = _float32_pipeline(n_local)
+        mv = dia_halo_matvec(OFFSETS_7PT, (S336 * S336,), n_local, 4,
+                             "basis")
+        specs = tuple(PartitionSpec("basis") for _ in OFFSETS_7PT)
+        sm = _sharded_fn(four_chips, specs, mv, mv, False, accs, policy,
+                         100, 4000, 0.7071067811865475, 1.15e-6, ortho,
+                         precond, dist, "basis", "vmap")
+        slab = jax.ShapeDtypeStruct(
+            (N336,), jnp.float32,
+            sharding=NamedSharding(four_chips, PartitionSpec("basis")))
+        compiled = jax.jit(sm).lower(
+            (slab,) * len(OFFSETS_7PT), slab, slab).compile()
+    hlo = compiled.as_text()
+    assert "collective-permute" in hlo and " gather(" not in hlo
+    assert _chip_bytes(compiled) < HBM
+
+
+def test_one_device_program_at_336_cubed_exceeds_one_chip(one_chip,
+                                                          tpu_compile):
+    """The same system's one-device program needs more than one chip's
+    memory, or the compiler refuses it for memory: the reason the layout
+    takes four chips."""
+    from repro.core.accessor import BasisAccessor, format_by_name
+    from repro.solver.gmres import _device_solve_fn
+    from repro.solver.pipeline import (
+        StaticPolicy,
+        orthogonalizer_by_name,
+        resolve_preconditioner,
+    )
+    from repro.sparse.csr import DIA
+
+    with jax.enable_x64(False):
+        fmt = format_by_name("float32", arith_dtype=jnp.float32)
+        acc = BasisAccessor(fmt=fmt, m=101, n=N336, arith_dtype=jnp.float32)
+
+        def solve(b, x0, vals):
+            mv = DIA(OFFSETS_7PT, vals, (N336, N336)).matvec
+            return _device_solve_fn(
+                mv, (acc,), StaticPolicy(fmt), 100, 4000,
+                0.7071067811865475, 1.15e-6, orthogonalizer_by_name("mgs"),
+                resolve_preconditioner(None, None))(b, x0)
+
+        vec = jax.ShapeDtypeStruct((N336,), jnp.float32, sharding=one_chip)
+        lowered = jax.jit(solve).lower(vec, vec, (vec,) * len(OFFSETS_7PT))
+        try:
+            compiled = lowered.compile()
+        except Exception as e:  # noqa: BLE001 - the refusal is the reading
+            assert "memory" in str(e).lower(), e
+            return
+    assert _chip_bytes(compiled) > HBM
