@@ -386,13 +386,12 @@ def run_local_checks() -> list[Finding]:
         A, b, "float64", None, 6, None, None, None, "mgs", 1e-8)
     acc = accs[0]
 
-    def cycle(store, w0, beta, b_norm):
-        return G._cycle(matvec, acc, b_norm, store, w0, beta,
-                        0.7071067811865475, 1e-8, ortho, precond)
+    def cycle(w0, beta, b_norm):
+        return G._cycle(matvec, acc, b_norm, w0, beta, 0.7071067811865475,
+                        1e-8, ortho, precond)
 
     scalar = jax.ShapeDtypeStruct((), b.dtype)
-    store = jax.eval_shape(acc.empty)
-    _, f = check_jaxpr(jax.make_jaxpr(cycle)(store, vec, scalar, scalar),
+    _, f = check_jaxpr(jax.make_jaxpr(cycle)(vec, scalar, scalar),
                        label="host-cycle")
     findings += f
     return findings

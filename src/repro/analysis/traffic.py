@@ -191,7 +191,8 @@ def run_local_traffic() -> list[Finding]:
     conditional re-orth) and ``max_iters = k*m`` the solve runs exactly
     ``k`` full ``m``-iteration cycles.  Every factor of the expected
     accounting then comes from the program, not the model: row bytes from
-    the store avals, the trip count from the cycle scan's ``length``.
+    the avals of the store each cycle allocates (``acc.empty()``), the
+    trip count from the cycle scan's ``length``.
     """
     from repro.analysis.traceaudit import _pin_environment, _problem
     from repro.solver.gmres import _cycle_row_reads, build_device_solve
@@ -208,10 +209,9 @@ def run_local_traffic() -> list[Finding]:
         acc = accs[0]
         vec = jax.ShapeDtypeStruct(b.shape, b.dtype)
 
-        shapes = jax.eval_shape(solve, vec, vec)
         aval_bytes = sum(
             int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree.leaves(shapes["stores"]))
+            for leaf in jax.tree.leaves(jax.eval_shape(acc.empty)))
         row_bytes = aval_bytes / acc.m
         model_row = acc.nbytes() / acc.m
         if row_bytes != model_row:

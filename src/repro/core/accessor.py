@@ -240,6 +240,11 @@ class FrszFormat(StorageFormat):
     ``use_kernels`` routes ``dots``/``combine`` through the fused Pallas
     decompress-dot kernels (interpret-mode on CPU); otherwise the pure-jnp
     codec is used.  Semantics are identical (tests assert this).
+
+    The store keeps each row's codes flat, ``(m, nb * width)``, the code
+    matrix the kernels read: a ``(m, nb, bs)`` store would be laid out
+    otherwise on a TPU, and every pass over it would copy the whole store
+    into the kernels' layout first.
     """
 
     spec: F.FrszSpec = F.FRSZ2_32
@@ -261,12 +266,16 @@ class FrszFormat(StorageFormat):
     def _nb(self, n: int) -> int:
         return -(-n // self.spec.bs)
 
+    def _width(self) -> int:
+        """Code words per block: ``bs`` codes, or the packed uint32s."""
+        spec = self.spec
+        return spec.bs if spec.aligned else spec.words_per_block
+
     def empty(self, m: int, n: int):
         spec = self.spec
         nb = self._nb(n)
-        codes = (jnp.zeros((m, nb, spec.bs), F._code_dtype(spec.l))
-                 if spec.aligned
-                 else jnp.zeros((m, nb, spec.words_per_block), jnp.uint32))
+        dtype = F._code_dtype(spec.l) if spec.aligned else jnp.uint32
+        codes = jnp.zeros((m, nb * self._width()), dtype)
         exps = jnp.zeros((m, nb), spec.exp_dtype)
         return {"codes": codes, "exps": exps}
 
@@ -277,22 +286,18 @@ class FrszFormat(StorageFormat):
         with jax.named_scope("compress"):
             bc = F.compress(v.astype(self.spec.dtype), self.spec)
         return {
-            "codes": store["codes"].at[j].set(bc.codes),
+            "codes": store["codes"].at[j].set(bc.codes.reshape(-1)),
             "exps": store["exps"].at[j].set(bc.exps),
         }
 
     def _as_bc(self, store, n: int) -> F.BlockCompressed:
-        return F.BlockCompressed(
-            codes=store["codes"], exps=store["exps"], n=n, spec=self.spec
-        )
+        exps = store["exps"]
+        codes = store["codes"].reshape(*exps.shape, self._width())
+        return F.BlockCompressed(codes=codes, exps=exps, n=n, spec=self.spec)
 
     def read_row(self, store, j, arith_dtype, n: int):
-        spec = self.spec
-        bc = F.BlockCompressed(
-            codes=store["codes"][j][None], exps=store["exps"][j][None],
-            n=n, spec=spec,
-        )
-        return F.decompress(bc)[0].astype(arith_dtype)
+        row = {"codes": store["codes"][j][None], "exps": store["exps"][j][None]}
+        return F.decompress(self._as_bc(row, n))[0].astype(arith_dtype)
 
     def read_all(self, store, arith_dtype, n: int):
         return F.decompress(self._as_bc(store, n)).astype(arith_dtype)

@@ -179,7 +179,7 @@ def basis_partition_specs(store, axis: str = "basis"):
 
     Every storage format keeps the row axis first and the (possibly
     blocked) vector axis second — native ``(m, n)``, FRSZ2 codes
-    ``(m, nb, bs)``, FRSZ2 exps ``(m, nb)`` — so sharding dim 1 of every
+    ``(m, nb * bs)``, FRSZ2 exps ``(m, nb)`` — so sharding dim 1 of every
     ``ndim >= 2`` leaf splits each basis vector across devices while
     keeping compressed blocks intact (``n`` must split on block
     boundaries, i.e. ``n_local`` a multiple of the block size).  Used with
@@ -207,7 +207,7 @@ def vector_partition_spec(axis: str = "basis", batched: bool = False) -> P:
     return P(None, axis) if batched else P(axis)
 
 
-def driver_partition_specs(accs, axis: str = "basis", batched: bool = False):
+def driver_partition_specs(axis: str = "basis", batched: bool = False):
     """PartitionSpec tree for the device driver's *full* state dict.
 
     The device-resident GMRES driver's ``lax.while_loop`` state (see
@@ -220,26 +220,20 @@ def driver_partition_specs(accs, axis: str = "basis", batched: bool = False):
         with the 3-D block layout's padded-space permutation for
         ``matvec_mode="block3d"``), so a contiguous ``P(axis)`` split
         lands each device exactly on its plan chunk;
-      * ``stores`` — one Krylov store per policy level, each sharded along
-        the vector dim per :func:`basis_partition_specs`;
       * ``hist`` / ``rst`` / ``cycle_len`` and every scalar (``total``,
         ``cycles``, ``restarts``, ``converged``, ``stagnated``, ``rrn``,
         ``prev_last``, ``nbytes``, ``op_reads``, ``steps``, ``spmvs``) —
         device-invariant, replicated.
 
-    ``accs`` is the driver's tuple of ``BasisAccessor``s (anything with an
-    ``empty()`` store builder works — only shapes are inspected, via
-    ``jax.eval_shape``).  ``batched=True`` prepends an unsharded batch dim
-    to every spec, matching a ``vmap`` applied *inside* the ``shard_map``
-    (the multi-device multi-RHS composition).
+    The Krylov store is no part of it: each restart cycle allocates its
+    own, inside ``shard_map``, so each device holds the local slab of
+    every Krylov vector and nothing leaves the program.  ``batched=True``
+    prepends an unsharded batch dim to every spec, matching a ``vmap``
+    applied *inside* the ``shard_map`` (the multi-device multi-RHS
+    composition).
     """
-    store_specs = tuple(
-        basis_partition_specs(jax.eval_shape(acc.empty), axis)
-        for acc in accs
-    )
     specs = dict(
         x=P(axis),
-        stores=store_specs,
         total=P(), cycles=P(), restarts=P(), converged=P(),
         stagnated=P(), rrn=P(), prev_last=P(), nbytes=P(),
         op_reads=P(), hist=P(), rst=P(), steps=P(), spmvs=P(),

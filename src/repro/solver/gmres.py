@@ -31,10 +31,10 @@ The cycle is assembled from three pluggable stages (see
     both drivers: ``"jacobi"``, a callable hook, or any object with
     ``apply``;
   * ``policy`` — :class:`~repro.solver.pipeline.PrecisionPolicy`: the
-    storage format per restart cycle.  The device driver pre-builds one
-    store per policy level and dispatches the cycle through ``lax.switch``
-    on the restart residual, so an adaptive ``float64 -> frsz2_32 ->
-    frsz2_16`` schedule still runs as a single XLA program.
+    storage format per restart cycle.  The device driver dispatches the
+    cycle through ``lax.switch`` on the restart residual, and each cycle
+    allocates the store of its level, so an adaptive ``float64 -> frsz2_32
+    -> frsz2_16`` schedule still runs as a single XLA program.
 
 Every result carries ``bytes_read`` — the modelled basis read traffic
 (rows touched by read_row/dots/combine/update times the active format's
@@ -65,7 +65,10 @@ finished systems are masked).
 
 The inner cycle is a single ``lax.fori_loop`` over a fixed-capacity basis
 buffer with row masking, so the solver traces once per
-(problem-size, m, pipeline) combination.
+(problem-size, m, pipeline) combination.  The buffer lives inside one
+cycle: the cycle writes each row before any trip reads it, so nothing in
+the store outlives the cycle, and no restart loop carries it (carried,
+XLA copies the whole store on every inner trip).
 """
 from __future__ import annotations
 
@@ -132,11 +135,13 @@ def _givens(a, b):
     return c, s
 
 
-def _cycle(matvec: Callable, acc: BasisAccessor, b_norm, store, w0, beta,
+def _cycle(matvec: Callable, acc: BasisAccessor, b_norm, w0, beta,
            eta: float, target: float, ortho, precond, dist=LOCAL):
     """One GMRES(m) cycle.  w0 = r0 (unnormalized); beta = ||r0||.
 
-    Returns (store, R, g, rrn_est, extra_rows, steps) where R is the
+    The cycle allocates its own zero-filled store (``acc.empty()``) and
+    writes row ``j + 1`` before any trip reads it.  Returns (store, R, g,
+    rrn_est, extra_rows, steps) where store is that Krylov basis, R the
     rotated Hessenberg (upper triangular in its leading block), g the
     rotated rhs, rrn_est the per-inner-iteration implicit residual
     estimate, extra_rows the exact count of basis rows swept by extra
@@ -151,7 +156,7 @@ def _cycle(matvec: Callable, acc: BasisAccessor, b_norm, store, w0, beta,
     m = acc.m - 1
     ad = acc.arith_dtype
 
-    store = acc.write_row(store, 0, w0 / jnp.maximum(beta, _TINY))
+    store = acc.write_row(acc.empty(), 0, w0 / jnp.maximum(beta, _TINY))
 
     R0 = jnp.zeros((m + 1, m), ad)
     g0 = jnp.zeros((m + 1,), ad).at[0].set(beta)
@@ -467,9 +472,9 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
     # gates on).
     def make_cycle(acc):
         return jax.jit(
-            lambda store, w0, beta, b_norm_: _cycle(
-                matvec, acc, b_norm_, store, w0, beta, eta, target_rrn,
-                ortho, precond
+            lambda w0, beta, b_norm_: _cycle(
+                matvec, acc, b_norm_, w0, beta, eta, target_rrn, ortho,
+                precond
             )
         )
 
@@ -489,9 +494,8 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
             op_key, pins, tail,
             lambda: (make_cycle(acc), make_update(acc)))
 
-    # per-policy-level jitted kernels + stores, built on first use
+    # per-policy-level jitted kernels, built on first use
     kernels: dict[int, tuple] = {}
-    stores: dict[int, Any] = {}
 
     history: list[np.ndarray] = []
     restart_rrns: list[float] = []
@@ -528,16 +532,14 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
         lvl = int(policy.level(restart_rrns[-1], len(restart_rrns) - 1))
         if lvl not in kernels:
             kernels[lvl] = kernels_for(lvl)
-            stores[lvl] = accs[lvl].empty()
         cycle, update = kernels[lvl]
-        stores[lvl], R, g, est, extra_rows, trips = cycle(stores[lvl], r,
-                                                          beta, b_norm)
+        store, R, g, est, extra_rows, trips = cycle(r, beta, b_norm)
         est_np = np.asarray(est)
         # first inner iteration that met the target (1-based count)
         hit = np.nonzero(est_np <= target_rrn)[0]
         j_stop = int(hit[0]) + 1 if hit.size else m
         # breakdown shows up as a frozen tail in est; detect via argmin
-        x = update(stores[lvl], R, g, jnp.asarray(j_stop), x)
+        x = update(store, R, g, jnp.asarray(j_stop), x)
         history.append(est_np[:j_stop])
         total_iters += j_stop
         steps += int(trips)
@@ -601,9 +603,11 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
     applications) and ``cycle_len`` (each cycle's ``j_stop``, indexed by
     ``cycles``).
 
-    Multi-level precision policies carry one pre-built store per level and
-    dispatch each cycle with ``lax.switch`` on the policy's level index —
-    the whole adaptive solve remains a single XLA program.
+    The state carries no Krylov store: each cycle allocates its own
+    (:func:`_cycle`), so no loop copies it.  Multi-level precision policies
+    dispatch each cycle with ``lax.switch`` on the policy's level index,
+    each branch with the store of its level — the whole adaptive solve
+    remains a single XLA program.
 
     ``dist`` distributes the solve: with an axis name bound, ``b``/``x0``
     are the device-local chunks of row-partitioned vectors, ``matvec`` must
@@ -633,7 +637,6 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
 
         init = dict(
             x=x0,
-            stores=tuple(acc.empty() for acc in accs),
             total=jnp.asarray(0, jnp.int32),
             cycles=jnp.asarray(0, jnp.int32),
             restarts=jnp.asarray(0, jnp.int32),
@@ -669,8 +672,8 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
                 def run(s):
                     acc = accs[k]
                     store, R, g, est, extra_rows, steps = _cycle(
-                        matvec, acc, b_norm, s["stores"][k], r, beta, eta,
-                        target_rrn, ortho, precond, dist
+                        matvec, acc, b_norm, r, beta, eta, target_rrn,
+                        ortho, precond, dist
                     )
                     hit = est <= target_rrn
                     hit_any = jnp.any(hit)
@@ -697,13 +700,9 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
                         _cycle_row_reads(j_stop, ortho.passes,
                                          extra_rows).astype(ad)
                         * row_bytes[k])
-                    stores = tuple(
-                        store if i == k else s["stores"][i]
-                        for i in range(n_levels)
-                    )
                     op_reads = op_head + j_stop.astype(ad) + 1.0
                     return dict(
-                        x=x, stores=stores, total=total, cycles=cycles,
+                        x=x, total=total, cycles=cycles,
                         restarts=restarts, converged=conv, stagnated=stag,
                         rrn=rrn, prev_last=last, nbytes=nbytes,
                         op_reads=op_reads, hist=hist, rst=rst,
@@ -736,11 +735,10 @@ def _device_solve_fn(matvec, accs, policy, m: int, max_iters: int,
 def _device_result(state) -> GmresResult:
     """Trim the device state's fixed buffers into the GmresResult contract.
 
-    Everything but ``x`` and the Krylov stores comes to the host in one
-    fetch and is sliced there: a slice on the device by a count would
-    compile a program per count."""
-    host = jax.device_get({k: v for k, v in state.items()
-                           if k not in ("x", "stores")})
+    Everything but ``x`` comes to the host in one fetch and is sliced
+    there: a slice on the device by a count would compile a program per
+    count."""
+    host = jax.device_get({k: v for k, v in state.items() if k != "x"})
     total = int(host["total"])
     restarts = int(host["restarts"])
     return GmresResult(
@@ -1040,7 +1038,7 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
     program; one that does not runs on every local chip
     (:func:`repro.solver.sharded.sharded_program`), behind the same
     contract: ``plan`` is ``None``, the state's ``x`` is in the operator's
-    order and its Krylov stores stay on the chips.
+    order.
 
     Host spans: ``gmres.solve_program`` around it all, ``gmres.layout``
     around the choice of chips (and on several, the placement of ``b``),

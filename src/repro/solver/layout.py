@@ -9,10 +9,10 @@ every local chip, row-partitioned (:mod:`repro.solver.sharded`).
 The bytes of a one-chip solve (:func:`solve_bytes`):
 
 * the Krylov store, ``m + 1`` rows of ``n`` values at each storage
-  format's bits, once per level of the precision policy, and a copy of it:
-  XLA copies the store around the restart loop (a ``f32[101, n]`` copy
-  twice a cycle on the benchmark's float32 cells), so the store is held
-  twice at the peak;
+  format's bits, once per level of the precision policy, counted twice:
+  room for one whole copy of it, which the program makes nowhere since
+  each restart cycle allocates its own store
+  (``tests/test_store_copies.py``), so the rule errs toward more chips;
 * the operator as the SpMV reads it: its diagonals where it stores by
   diagonal, else the CSR and its row ids;
 * :data:`VECTORS` vectors of ``n`` values at the arithmetic width.
@@ -33,8 +33,8 @@ VECTORS = 8
 def solve_bytes(n: int, m: int, formats, operator_bytes: int,
                 value_bytes: int) -> int:
     """Device bytes a one-chip solve of ``n`` rows holds: the store of
-    ``m + 1`` rows in each of ``formats`` and its copy, the operator, and
-    :data:`VECTORS` vectors of ``value_bytes`` values."""
+    ``m + 1`` rows in each of ``formats`` and room for a copy, the
+    operator, and :data:`VECTORS` vectors of ``value_bytes`` values."""
     store = sum(f.nbytes(m + 1, n) for f in formats)
     return 2 * store + int(operator_bytes) + VECTORS * n * value_bytes
 

@@ -23,8 +23,9 @@ basis rows, the residual) is row-partitioned along the vector dim over a
     multiple (padded operator rows are masked, so the padded solve embeds
     the original exactly); the returned ``x`` is trimmed back;
   * the while_loop state's partition specs come from
-    :func:`repro.dist.sharding.driver_partition_specs` — ``x`` and the
-    stores sharded, history buffers and scalars replicated.
+    :func:`repro.dist.sharding.driver_partition_specs` — ``x`` sharded,
+    history buffers and scalars replicated; each restart cycle allocates
+    its Krylov store inside the ``shard_map``, one local slab a device.
 
 Because every reduced quantity (norms, Hessenberg entries, residual
 estimates) is device-invariant after its psum, all devices take identical
@@ -246,8 +247,8 @@ def sharded_program(A, b, n_shards: int, *, x0=None, storage=None,
     CSR's own arrays then move to the host
     (:meth:`~repro.sparse.csr.CSR.to_host`), so that no chip holds the
     whole operator.  The state's ``x`` is in the operator's order, trimmed
-    to ``n`` inside the program; the Krylov stores stay sharded on the
-    chips.
+    to ``n`` inside the program; each chip allocates its slab of the
+    Krylov store inside the program.
 
     Host spans (inside ``gmres.solve_program``): ``gmres.layout`` around
     the placement of ``b`` and ``x0``, ``gmres.plan`` around the plan and
@@ -384,8 +385,7 @@ def _sharded_fn(mesh, op_specs, local_mv, local_rmv, batched, accs, policy,
             run = solve_local
 
         vec_spec = vector_partition_spec(axis_name, batched=batched)
-        state_specs = driver_partition_specs(accs, axis_name,
-                                             batched=batched)
+        state_specs = driver_partition_specs(axis_name, batched=batched)
     return jax.shard_map(run, mesh=mesh,
                          in_specs=(op_specs, vec_spec, vec_spec),
                          out_specs=state_specs, axis_names={axis_name},

@@ -374,8 +374,8 @@ def test_seeded_spec_mismatch_reports_readable_path():
         driver_partition_specs,
     )
 
-    def broken(accs, axis, **kw):
-        specs = dict(driver_partition_specs(accs, axis, **kw))
+    def broken(axis, **kw):
+        specs = dict(driver_partition_specs(axis, **kw))
         del specs["stagnated"]          # the PR 3 bug, seeded on purpose
         specs["bogus_extra"] = specs["converged"]
         return specs

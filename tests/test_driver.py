@@ -85,8 +85,8 @@ def test_stagnated_flag_reported_by_both_drivers(monkeypatch):
     gmres_mod = importlib.import_module("repro.solver.gmres")
     m, target = 4, 1e-8
 
-    def fake_cycle(matvec, acc, b_norm, store, w0, beta, eta, tgt, ortho,
-                   precond, dist=None):
+    def fake_cycle(matvec, acc, b_norm, w0, beta, eta, tgt, ortho, precond,
+                   dist=None):
         ad = acc.arith_dtype
         R = jnp.eye(m + 1, m, dtype=ad)          # benign back-substitution
         g = jnp.zeros((m + 1,), ad)              # y == 0 => x unchanged
@@ -94,7 +94,7 @@ def test_stagnated_flag_reported_by_both_drivers(monkeypatch):
         # (interior multipliers strictly > 1, final strictly < 1)
         est = jnp.asarray(target * np.linspace(2.0, 0.9, m), ad)
         zero = jnp.asarray(0, jnp.int32)
-        return store, R, g, est, zero, zero + m
+        return acc.empty(), R, g, est, zero, zero + m
 
     monkeypatch.setattr(gmres_mod, "_cycle", fake_cycle)
     # fresh solve cache: the device program compiled from the fake cycle

@@ -148,6 +148,7 @@ for s in (16, 15):
     b = jnp.asarray(reference.matvec64(*op, rng.standard_normal(A.shape[0])))
     layout.bytes_limit = lambda device: 1000
     solve, args, plan = solve_program(A, b, **kw)
+    hlo = solve.lower(*args).compile().as_text()
     state = solve(*args)
     res = _device_result(state)
     host_csr = all(isinstance(a, np.ndarray)
@@ -159,7 +160,10 @@ for s in (16, 15):
     out["program"].append(dict(
         n=A.shape[0], plan=plan is None, x_len=int(res.x.shape[0]),
         devices=len(args[0].sharding.device_set),
-        stores=len(state["stores"][0].sharding.device_set),
+        # the devices the Krylov store is split over: the program holds
+        # the store of m + 1 = 31 rows only as its one-device slab
+        stores=[d for d in (1, 2, 4, 8)
+                if f"f64[31,{args[0].shape[0] // d}]" in hlo],
         rrn=reference.true_rrn(op, b, np.asarray(res.x)),
         rrn_gmres=reference.true_rrn(op, b, np.asarray(via_gmres.x)),
         it=res.iterations, it_one=one.iterations,
@@ -201,7 +205,7 @@ def test_an_operator_dia_refuses_takes_the_ell_halo(multidevice):
 def test_solve_program_runs_on_every_device_past_the_limit(multidevice):
     for case in multidevice["program"]:
         assert case["plan"], case                  # plan is None
-        assert case["devices"] == 8 and case["stores"] == 8, case
+        assert case["devices"] == 8 and case["stores"] == [8], case
         assert case["x_len"] == case["n"], case    # trimmed, operator order
         assert case["host_csr"], case              # no whole-operator copy
         assert case["rrn"] < 1e-10 and case["rrn_gmres"] < 1e-10, case

@@ -127,8 +127,8 @@ def test_decompress_compiles(tpu_compile, l):
     assert "tpu_custom_call" in hlo
 
 
-def test_frsz2_16_device_solve_compiles(tpu_compile):
-    """A whole float32 GMRES(100) solve over a fused frsz2_16 basis."""
+def _device_solve(storage, use_kernels):
+    """A float32 GMRES(100) device solve at 48^3 and its accessor."""
     from repro.core.accessor import format_by_name
     from repro.solver.gmres import build_device_solve
     from repro.sparse import make_problem, rhs_for
@@ -136,13 +136,31 @@ def test_frsz2_16_device_solve_compiles(tpu_compile):
     with jax.enable_x64(False):
         A, _ = make_problem("synth:atmosmod", 48 ** 3, dtype=np.float32)
         b, _ = rhs_for(A)
-        fmt = format_by_name("frsz2_16", use_kernels=True,
+        fmt = format_by_name(storage, use_kernels=use_kernels,
                              arith_dtype=jnp.float32)
-        solve, _ = build_device_solve(A, b, storage=fmt, m=100,
-                                      max_iters=400, target_rrn=1e-6)
-    hlo = tpu_compile(solve, (b.shape, jnp.float32), (b.shape, jnp.float32))
+        solve, accs = build_device_solve(A, b, storage=fmt, m=100,
+                                         max_iters=400, target_rrn=1e-6)
+    return solve, accs[0], b.shape
+
+
+def test_frsz2_16_device_solve_compiles(tpu_compile, whole_store_copies):
+    """A whole float32 GMRES(100) solve over a fused frsz2_16 basis; the
+    kernels read the store in its own layout, so no pass copies it."""
+    solve, acc, shape = _device_solve("frsz2_16", True)
+    hlo = tpu_compile(solve, (shape, jnp.float32), (shape, jnp.float32))
     assert "tpu_custom_call" in hlo
     assert "f64[" not in hlo
+    assert whole_store_copies(hlo, jax.eval_shape(acc.empty)) == []
+
+
+def test_float32_device_solve_copies_no_whole_store(tpu_compile,
+                                                    whole_store_copies):
+    """The float32 basis of the same solve: no loop carries the store, so
+    the chip's compiler copies it nowhere."""
+    solve, acc, shape = _device_solve("float32", False)
+    hlo = tpu_compile(solve, (shape, jnp.float32), (shape, jnp.float32))
+    assert "dynamic-update-slice" in hlo
+    assert whole_store_copies(hlo, jax.eval_shape(acc.empty)) == []
 
 
 @pytest.mark.parametrize("s, offsets", [
@@ -197,11 +215,12 @@ def _float32_pipeline(n_local):
 
 
 def test_four_chip_program_at_336_cubed_fits_each_chip(four_chips,
-                                                       tpu_compile):
+                                                       tpu_compile,
+                                                       whole_store_copies):
     """The solve program ``solve_program`` returns for the 336^3 system:
     the restart driver under ``shard_map`` on four slabs of 84 x-planes,
-    with the DIA halo SpMV, compiles for the 2x2 host and holds under one
-    chip's memory on each."""
+    with the DIA halo SpMV, compiles for the 2x2 host, holds under one
+    chip's memory on each and copies no chip's slab of the store."""
     from jax.sharding import NamedSharding, PartitionSpec
     from repro.solver.sharded import _sharded_fn
     from repro.sparse.shard import dia_halo_matvec
@@ -223,6 +242,7 @@ def test_four_chip_program_at_336_cubed_fits_each_chip(four_chips,
     hlo = compiled.as_text()
     assert "collective-permute" in hlo and " gather(" not in hlo
     assert _chip_bytes(compiled) < HBM
+    assert whole_store_copies(hlo, jax.eval_shape(accs[0].empty)) == []
 
 
 def test_one_device_program_at_336_cubed_exceeds_one_chip(one_chip,
