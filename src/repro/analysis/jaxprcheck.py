@@ -382,13 +382,12 @@ def run_local_checks() -> list[Finding]:
     findings += f
 
     # the host driver's unit of compilation is the cycle kernel
-    accs, _policy, _ad, matvec, precond, ortho = G._resolve(
-        A, b, "float64", None, 6, None, None, None, "mgs", 1e-8)
-    acc = accs[0]
+    pipe = G._resolve(A, b, "float64", None, 6, None, None, None, "mgs",
+                      1e-8)
 
     def cycle(w0, beta, b_norm):
-        return G._cycle(matvec, acc, b_norm, w0, beta, 0.7071067811865475,
-                        1e-8, ortho, precond)
+        return G._cycle(pipe.matvec, pipe.accs[0], b_norm, w0, beta,
+                        0.7071067811865475, 1e-8, pipe.ortho, pipe.precond)
 
     scalar = jax.ShapeDtypeStruct((), b.dtype)
     _, f = check_jaxpr(jax.make_jaxpr(cycle)(vec, scalar, scalar),

@@ -402,29 +402,17 @@ def _audit_matvec(plan, mode_label: str, compressed: bool,
 
 
 def _sharded_solve_jaxpr(plan, m: int):
+    G = importlib.import_module("repro.solver.gmres")
     S = importlib.import_module("repro.solver.sharded")
-    from repro.core.accessor import BasisAccessor
-    from repro.dist.context import DistContext
-    from repro.solver.pipeline import (
-        orthogonalizer_by_name,
-        resolve_policy,
-        resolve_preconditioner,
-    )
 
-    ad = jnp.float64
-    policy = S._wrap_policy(resolve_policy(None, "float64", ad, 1e-8, m),
-                            _AXIS, False)
-    accs = (BasisAccessor(fmt=policy.formats()[0], m=m + 1, n=plan.n_local,
-                          arith_dtype=ad),)
-    ortho = orthogonalizer_by_name("cgs2")
-    precond = resolve_preconditioner(None, plan.operator).shard_local(
-        _AXIS, plan.n_local, plan.n_pad)
-    dist = DistContext(axis_name=_AXIS)
+    vec = jax.ShapeDtypeStruct((plan.n_pad,), jnp.float64)
+    pipe = G._resolve(plan.operator, vec, "float64", None, m, None, None,
+                      None, "cgs2", 1e-8, axis_name=_AXIS,
+                      rows=(plan.n_local, plan.n_pad))
     solve, operand = S._build_sharded_solve(
-        plan, False, accs, policy, m, 4 * m, 0.7071067811865475, 1e-8,
-        ortho, precond, dist, _AXIS, False, "vmap")
-    vec = jax.ShapeDtypeStruct((plan.n_pad,), ad)
-    return jax.make_jaxpr(solve)(operand, vec, vec)
+        plan, pipe, False, "vmap", m, 4 * m, 0.7071067811865475, 1e-8,
+        _AXIS, False)
+    return jax.make_jaxpr(solve)(vec, vec, operand)
 
 
 def _audit_census(plan, m: int, findings: list[Finding]):

@@ -70,9 +70,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.accessor import BlockBasisAccessor
 from repro.dist.context import LOCAL
 from repro.solver.gmres import (
+    _HOST_KERNEL_CACHE,
+    _HOST_KERNEL_CACHE_SIZE,
     _SOLVE_CACHE,
     _SOLVE_CACHE_SIZE,
     _TINY,
@@ -80,20 +81,12 @@ from repro.solver.gmres import (
     _block_apply_prior,
     _block_solve_and_update,
     _block_triangularize,
-    _cached_host_kernels,
+    _cached,
     _cycle_row_reads,
-    _lru_cached,
-    _operator_key,
-    _permuted_precond,
-    _plan_unsharded,
+    _prepare,
+    _resolve,
 )
-from repro.solver.pipeline import (
-    block_orthogonalizer_by_name,
-    block_qr,
-    resolve_policy,
-    resolve_preconditioner,
-)
-from repro.sparse.csr import operator_matvec
+from repro.solver.pipeline import block_qr
 
 __all__ = ["gmres_block"]
 
@@ -357,18 +350,18 @@ def _block_results(state) -> list[GmresResult]:
 # ---------------------------------------------------------------------------
 
 
-def _gmres_block_host(matvec, accs, policy, B, m, max_iters, target_rrn,
-                      eta, ortho, precond, X0=None, op_key=None,
-                      pins=()) -> list[GmresResult]:
+def _gmres_block_host(pipe, B, X, m, max_iters, target_rrn, eta,
+                      op) -> list[GmresResult]:
     """Python restart loop mirroring ``_block_device_solve_fn``
-    decision-for-decision (same jitted cycle, numpy restart logic)."""
+    decision-for-decision (same jitted cycle, numpy restart logic).
+    ``B`` and ``X`` are in the arithmetic dtype; ``op`` is the operator's
+    ``(A, user_matvec, plan)``, the key of the cached kernels."""
+    accs, policy, ortho, precond, _, matvec = pipe
     ad = accs[0].arith_dtype
     p = accs[0].p
-    B = B.astype(ad)
     bmv = jax.vmap(lambda v: matvec(precond.apply(v)))
     bmv_r = jax.vmap(matvec)
     bn_safe = jnp.maximum(jnp.linalg.norm(B, axis=1), _TINY)
-    X = jnp.zeros_like(B) if X0 is None else X0.astype(ad)
 
     # ``bn_safe`` is a jit argument, not a closure constant — see
     # _gmres_host: a closed-over per-solve array would recompile the cycle
@@ -383,13 +376,9 @@ def _gmres_block_host(matvec, accs, policy, B, m, max_iters, target_rrn,
 
     def kernels_for(lvl):
         acc = accs[lvl]
-        tail = ("block", lvl, acc.p, policy.spec(), ortho.spec(),
-                precond.spec(), acc.m, acc.n,
-                jnp.dtype(acc.arith_dtype).name, float(eta),
-                float(target_rrn))
-        return _cached_host_kernels(
-            op_key, pins, tail,
-            lambda: (make_cycle(acc), make_update(acc)))
+        return _cached(_HOST_KERNEL_CACHE, _HOST_KERNEL_CACHE_SIZE, op, pipe,
+                       ("host", lvl, float(eta), float(target_rrn)),
+                       lambda: ((make_cycle(acc), make_update(acc)),))[0]
 
     kernels: dict[int, tuple] = {}
     stores: dict[int, Any] = {}
@@ -479,47 +468,8 @@ def _gmres_block_host(matvec, accs, policy, B, m, max_iters, target_rrn,
 
 
 # ---------------------------------------------------------------------------
-# Resolution + compiled-solve cache + public API
+# Public API
 # ---------------------------------------------------------------------------
-
-
-def _resolve_block(A, B, storage, policy, m, arith_dtype, matvec, precond,
-                   ortho, target_rrn):
-    if arith_dtype is None:
-        arith_dtype = B.dtype
-    if matvec is None:
-        matvec = operator_matvec(A)
-    policy = resolve_policy(policy, storage, arith_dtype, target_rrn, m)
-    p, n = B.shape
-    accs = tuple(
-        BlockBasisAccessor(fmt=f, m=m + 1, p=p, n=n, arith_dtype=arith_dtype)
-        for f in policy.formats()
-    )
-    precond = resolve_preconditioner(precond, A)
-    ortho = block_orthogonalizer_by_name(ortho)
-    return accs, policy, arith_dtype, matvec, precond, ortho
-
-
-def _cached_block_solve(A, user_matvec, matvec, accs, policy, m, max_iters,
-                        eta, target, ortho, precond, plan=None):
-    pins: tuple = ()
-
-    def make_key():
-        nonlocal pins
-        op_key, pins = _operator_key(A, user_matvec, plan)
-        pins = pins + (precond,)
-        acc = accs[0]
-        return (op_key, "block", acc.p, policy.spec(), ortho.spec(),
-                precond.spec(), acc.m, acc.n,
-                jnp.dtype(acc.arith_dtype).name,
-                m, max_iters, float(eta), float(target))
-
-    def build():
-        solve = _block_device_solve_fn(matvec, accs, policy, m, max_iters,
-                                       eta, target, ortho, precond)
-        return jax.jit(solve), pins
-
-    return _lru_cached(_SOLVE_CACHE, _SOLVE_CACHE_SIZE, make_key, build)[0]
 
 
 def gmres_block(
@@ -550,32 +500,23 @@ def gmres_block(
     """
     if B.ndim != 2:
         raise ValueError(f"B must be (batch, n), got {B.shape}")
-    user_matvec = matvec
-    plan = _plan_unsharded(A, reorder, user_matvec)
-    if plan is not None:
-        precond = _permuted_precond(precond, plan)
-        A = plan.operator
-        B = plan.permute(B)
-        if X0 is not None:
-            X0 = plan.permute(X0)
-    accs, policy, arith_dtype, matvec, precond, ortho = _resolve_block(
-        A, B, storage, policy, m, arith_dtype, matvec, precond, ortho,
-        target_rrn)
-    B = B.astype(arith_dtype)
-
-    if driver == "host":
-        op_key, pins = _operator_key(A, user_matvec, plan)
-        results = _gmres_block_host(matvec, accs, policy, B, m, max_iters,
-                                    target_rrn, eta, ortho, precond, X0=X0,
-                                    op_key=op_key, pins=pins + (precond,))
-    elif driver != "device":
+    if driver not in ("device", "host"):
         raise ValueError(f"unknown driver {driver!r}; "
                          f"expected one of ('device', 'host')")
+    plan, A, B, X0, pipe = _prepare(A, B, X0, storage, policy, precond,
+                                    ortho, m, arith_dtype, matvec,
+                                    target_rrn, reorder, block=True)
+    op = (A, matvec, plan)
+    if driver == "host":
+        results = _gmres_block_host(pipe, B, X0, m, max_iters, target_rrn,
+                                    eta, op)
     else:
-        X0 = jnp.zeros_like(B) if X0 is None else X0.astype(arith_dtype)
-        solve = _cached_block_solve(A, user_matvec, matvec, accs, policy,
-                                    m, max_iters, eta, target_rrn, ortho,
-                                    precond, plan)
+        solve = _cached(
+            _SOLVE_CACHE, _SOLVE_CACHE_SIZE, op, pipe,
+            ("block", m, max_iters, float(eta), float(target_rrn)),
+            lambda: (jax.jit(_block_device_solve_fn(
+                pipe.matvec, pipe.accs, pipe.policy, m, max_iters, eta,
+                target_rrn, pipe.ortho, pipe.precond)),))[0]
         results = _block_results(solve(B, X0))
     if plan is not None:
         for r in results:
@@ -594,9 +535,9 @@ def build_block_solve(A, B, *, storage=None, policy=None, precond=None,
     surface the trace audit checks
     :func:`repro.dist.sharding.block_driver_partition_specs` against.
     """
-    accs, policy, _, matvec, precond, ortho = _resolve_block(
-        A, B, storage, policy, m, arith_dtype, matvec, precond, ortho,
-        target_rrn)
-    solve = _block_device_solve_fn(matvec, accs, policy, m, max_iters, eta,
-                                   target_rrn, ortho, precond)
-    return solve, accs
+    pipe = _resolve(A, B, storage, policy, m, arith_dtype, matvec, precond,
+                    ortho, target_rrn, block=True)
+    solve = _block_device_solve_fn(pipe.matvec, pipe.accs, pipe.policy, m,
+                                   max_iters, eta, target_rrn, pipe.ortho,
+                                   pipe.precond)
+    return solve, pipe.accs

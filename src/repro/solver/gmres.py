@@ -53,7 +53,7 @@ Two drivers share the same jitted cycle/update kernels:
     host exactly once at the end.  This is what the paper's premise
     requires: CB-GMRES is bandwidth-bound, so per-cycle host syncs
     (``np.asarray``/`float()` on the residual estimate) must not dominate
-    wall time.  ``benchmarks/driver_overhead.py`` measures the win.
+    wall time.
   * ``driver="host"`` — the seed host-looped driver (one device sync per
     restart cycle), kept as the parity oracle; ``tests/test_solver.py``
     asserts both produce identical iteration counts and final RRN.
@@ -62,6 +62,19 @@ Two drivers share the same jitted cycle/update kernels:
 right-hand sides: one XLA program advances all systems, each with its own
 restart schedule (the while_loop runs until the *last* system converges;
 finished systems are masked).
+
+Set-up
+------
+
+Every entry point (``gmres`` on either driver, ``gmres_batched``,
+``repro.solver.block.gmres_block``, ``solve_program`` on one chip or
+many, ``repro.solver.sharded.sharded_gmres`` and the ``build_*_solve``
+introspection functions) reaches its compiled program by one path: the
+unsharded entries plan and permute in :func:`_prepare`, the sharded ones
+in ``repro.solver.sharded._sharded_setup``; both resolve the pipeline in
+:func:`_resolve` and look the program up under the one key
+:func:`_solve_key` (:func:`_cached`).  A pipeline field is therefore
+resolved, and keyed, in one place.
 
 The inner cycle is a single ``lax.fori_loop`` over a fixed-capacity basis
 buffer with row masking, so the solver traces once per
@@ -75,15 +88,23 @@ from __future__ import annotations
 import dataclasses
 from collections import OrderedDict
 from collections.abc import Callable
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.accessor import HIGHEST, BasisAccessor
-from repro.dist.context import LOCAL
+from repro.core.accessor import (
+    HIGHEST,
+    BasisAccessor,
+    BlockBasisAccessor,
+    ShardedFormat,
+)
+from repro.dist.context import LOCAL, DistContext
 from repro.solver.pipeline import (
+    AdaptivePolicy,
+    StaticPolicy,
+    block_orthogonalizer_by_name,
     orthogonalizer_by_name,
     resolve_policy,
     resolve_preconditioner,
@@ -397,21 +418,82 @@ def _block_solve_and_update(acc, store, R, G, j_stop, X0, precond):
 # ---------------------------------------------------------------------------
 
 
+class _Pipeline(NamedTuple):
+    """A solve's resolved pipeline (:func:`_resolve`)."""
+
+    accs: tuple          # one accessor per policy level
+    policy: Any
+    ortho: Any
+    precond: Any
+    dist: Any            # DistContext: LOCAL, or the mesh axis's reductions
+    matvec: Any          # the SpMV; None on the sharded path, whose
+                         # builder partitions the operator
+
+
 def _resolve(A, b, storage, policy, m, arith_dtype, matvec, precond, ortho,
-             target_rrn=None):
+             target_rrn, *, block=False, axis_name=None, rows=None,
+             transport="plain") -> _Pipeline:
+    """The one pipeline resolver of every solve entry point.
+
+    ``b`` (an array, or its shape and dtype) gives the default arithmetic
+    dtype, the row count and, with ``block``, the block width ``p =
+    b.shape[0]`` (block accessors and a block orthogonalizer).  With
+    ``axis_name`` the solve runs row-partitioned inside ``shard_map``:
+    ``rows = (n_local, n_pad)``, every policy level is wrapped in
+    :class:`~repro.core.accessor.ShardedFormat` on ``transport``, the
+    preconditioner keeps its local part and the norms reduce over the
+    axis.
+    """
     if arith_dtype is None:
         arith_dtype = b.dtype
-    if matvec is None:
-        matvec = operator_matvec(A)
     policy = resolve_policy(policy, storage, arith_dtype, target_rrn, m)
-    n = b.shape[0]
-    accs = tuple(
-        BasisAccessor(fmt=f, m=m + 1, n=n, arith_dtype=arith_dtype)
-        for f in policy.formats()
-    )
     precond = resolve_preconditioner(precond, A)
-    ortho = orthogonalizer_by_name(ortho)
-    return accs, policy, arith_dtype, matvec, precond, ortho
+    n, dist = b.shape[-1], LOCAL
+    if axis_name is not None:
+        n, n_pad = rows
+        policy = _wrap_policy(policy, axis_name, transport != "plain")
+        precond = precond.shard_local(axis_name, n, n_pad)
+        dist = DistContext(axis_name=axis_name,
+                           compressed_norms=transport == "compressed+norms")
+    elif matvec is None:
+        matvec = operator_matvec(A)
+    if block:
+        accs = tuple(BlockBasisAccessor(fmt=f, m=m + 1, p=b.shape[0], n=n,
+                                        arith_dtype=arith_dtype)
+                     for f in policy.formats())
+        ortho = block_orthogonalizer_by_name(ortho)
+    else:
+        accs = tuple(BasisAccessor(fmt=f, m=m + 1, n=n,
+                                   arith_dtype=arith_dtype)
+                     for f in policy.formats())
+        ortho = orthogonalizer_by_name(ortho)
+    return _Pipeline(accs, policy, ortho, precond, dist, matvec)
+
+
+def _wrap_policy(policy, axis_name: str, compressed_dots: bool):
+    """Wrap every policy level in ShardedFormat.
+
+    The solve's ``shard_transport`` argument is the single authority on
+    the collective wire format: formats that arrive already sharded (e.g.
+    ``storage="sharded:frsz2_32"``, whose builder defaults to compressed
+    transport) are rebuilt onto the requested transport and axis, so
+    ``transport="plain"`` always means the documented exact-psum parity.
+    """
+
+    def wrap(fmt):
+        if isinstance(fmt, ShardedFormat):
+            fmt = fmt.inner
+        return ShardedFormat(inner=fmt, axis_name=axis_name,
+                             compressed_transport=compressed_dots)
+
+    fmts = tuple(wrap(f) for f in policy.formats())
+    if isinstance(policy, StaticPolicy):
+        return StaticPolicy(fmts[0])
+    if isinstance(policy, AdaptivePolicy):
+        return AdaptivePolicy(levels=fmts, thresholds=policy.thresholds)
+    raise ValueError(
+        f"cannot shard custom policy {type(policy).__name__}: give it "
+        "ShardedFormat levels explicitly")
 
 
 def _plan_unsharded(A, reorder: str, user_matvec):
@@ -459,12 +541,14 @@ def _permuted_precond(precond, plan):
 # ---------------------------------------------------------------------------
 
 
-def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
-                ortho, precond, x0=None, op_key=None, pins=()) -> GmresResult:
+def _gmres_host(pipe, b, x, m, max_iters, target_rrn, eta,
+                op) -> GmresResult:
+    """The host-looped solve of ``b`` from ``x`` (both in the arithmetic
+    dtype); ``op`` is the operator's ``(A, user_matvec, plan)``, the key
+    of the cached kernels."""
+    accs, policy, ortho, precond, _, matvec = pipe
     arith_dtype = accs[0].arith_dtype
-    b = b.astype(arith_dtype)
     b_norm = jnp.linalg.norm(b)
-    x = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
 
     # ``b_norm`` rides as a jit *argument*: closing over it would bake the
     # per-solve array into the trace as a constant, recompiling the cycle
@@ -487,12 +571,9 @@ def _gmres_host(matvec, accs, policy, b, m, max_iters, target_rrn, eta,
 
     def kernels_for(lvl):
         acc = accs[lvl]
-        tail = (lvl, policy.spec(), ortho.name, precond.spec(), acc.m,
-                acc.n, jnp.dtype(acc.arith_dtype).name, float(eta),
-                float(target_rrn))
-        return _cached_host_kernels(
-            op_key, pins, tail,
-            lambda: (make_cycle(acc), make_update(acc)))
+        return _cached(_HOST_KERNEL_CACHE, _HOST_KERNEL_CACHE_SIZE, op, pipe,
+                       ("host", lvl, float(eta), float(target_rrn)),
+                       lambda: ((make_cycle(acc), make_update(acc)),))[0]
 
     # per-policy-level jitted kernels, built on first use
     kernels: dict[int, tuple] = {}
@@ -780,27 +861,6 @@ _HOST_KERNEL_CACHE: OrderedDict = OrderedDict()
 _HOST_KERNEL_CACHE_SIZE = 32
 
 
-def _cached_host_kernels(op_key, pins, key_tail, build):
-    """Memoize one policy level's host-driver kernels.
-
-    ``op_key`` is the operator's content key from :func:`_operator_key`
-    (``None`` disables caching — the kernels are built per call, the seed
-    behaviour); ``key_tail`` carries the pipeline identity; ``pins`` keeps
-    id()-keyed objects alive for as long as the entry lives.
-    """
-
-    def make_key():
-        if op_key is None:
-            raise TypeError("uncacheable operator")
-        return ("host", op_key) + tuple(key_tail)
-
-    def build_entry():
-        return build(), pins
-
-    return _lru_cached(_HOST_KERNEL_CACHE, _HOST_KERNEL_CACHE_SIZE,
-                       make_key, build_entry)[0]
-
-
 def _operator_key(A, user_matvec, plan=None):
     """Content-based key for the operator, plus any objects to pin.
 
@@ -820,44 +880,54 @@ def _operator_key(A, user_matvec, plan=None):
     return ("obj", id(A)), (A,)
 
 
-def _lru_cached(cache: OrderedDict, maxsize: int, make_key, build):
-    """Bounded-LRU memoization shared by the solve caches.
+def _solve_key(op, pipe: _Pipeline, fields: tuple):
+    """The one compiled-solve cache key, and the objects its entry pins.
 
-    ``make_key()`` returns the cache key (raise/return something unhashable
-    and the result is built uncached); ``build()`` returns the cached
-    entry — a tuple whose trailing elements may pin objects (preconditioner
-    hooks, callables) whose ``id()`` participates in the key.
+    Every value a cached build closes over is in it: the operator's key
+    (:func:`_operator_key` of ``op = (A, user_matvec, plan)``), the
+    resolved pipeline, and ``fields``, what differs per program (driver,
+    batching, iteration limits, shards, transport, axis).  The pins keep
+    alive what is keyed by ``id()``: a user's callable, the
+    preconditioner (whose ``spec()`` may key on ``id(fn)``).
     """
+    op_key, pins = _operator_key(*op)
+    acc = pipe.accs[0]
+    key = (op_key, pipe.policy.spec(), pipe.ortho.spec(), pipe.precond.spec(),
+           pipe.dist.spec(), acc.m, acc.n, getattr(acc, "p", 0),
+           jnp.dtype(acc.arith_dtype).name) + fields
+    return key, pins + (pipe.precond,)
+
+
+def _cached(cache: OrderedDict, maxsize: int, op, pipe: _Pipeline,
+            fields: tuple, build):
+    """Bounded-LRU memoization shared by the solve caches, keyed by
+    :func:`_solve_key`.
+
+    ``build()`` returns the entry's payload, a tuple; the entry is that
+    payload and the key's pins.  An unhashable key (a custom policy or
+    preconditioner spec) builds the entry uncached.
+    """
+    key, pins = _solve_key(op, pipe, fields)
     try:
-        key = make_key()
         hash(key)
     except TypeError:
-        return build()
+        return build() + (pins,)
     ent = cache.get(key)
     if ent is not None:
         cache.move_to_end(key)
         return ent
-    ent = cache[key] = build()
+    ent = cache[key] = build() + (pins,)
     while len(cache) > maxsize:
         cache.popitem(last=False)
     return ent
 
 
-def _cached_solve(A, user_matvec, batched, matvec, accs, policy, m,
-                  max_iters, eta, target, ortho, precond, plan=None):
+def _cached_solve(op, pipe: _Pipeline, batched: bool, m, max_iters, eta,
+                  target):
     """The compiled solve ``(b, x0, operand) -> state`` of this problem
     (cached), and the ``operand`` to call it with (:func:`_operand`)."""
-    pins: tuple = ()
-    operand = _operand(user_matvec, matvec)
-
-    def make_key():
-        nonlocal pins
-        op_key, pins = _operator_key(A, user_matvec, plan)
-        pins = pins + (precond,)     # spec() may key on id(fn): keep it alive
-        return (op_key, batched, policy.spec(), ortho.name, precond.spec(),
-                accs[0].m, accs[0].n, jnp.dtype(accs[0].arith_dtype).name,
-                m, max_iters, float(eta), float(target))
-
+    accs, policy, ortho, precond, _, matvec = pipe
+    operand = _operand(op[1], matvec)
     closed = operand is None
 
     def build():
@@ -866,10 +936,12 @@ def _cached_solve(A, user_matvec, batched, matvec, accs, policy, m,
                                     m, max_iters, eta, target, ortho,
                                     precond)(b, x0)
 
-        return jax.jit(jax.vmap(solve, in_axes=(0, 0, None)) if batched
-                       else solve), pins
+        return (jax.jit(jax.vmap(solve, in_axes=(0, 0, None)) if batched
+                        else solve),)
 
-    solve = _lru_cached(_SOLVE_CACHE, _SOLVE_CACHE_SIZE, make_key, build)[0]
+    solve = _cached(_SOLVE_CACHE, _SOLVE_CACHE_SIZE, op, pipe,
+                    ("device", batched, m, max_iters, float(eta),
+                     float(target)), build)[0]
     return solve, operand
 
 
@@ -968,7 +1040,6 @@ def gmres(
     (``jax.profiler.TraceAnnotation``), on the clock of the device's ops.
     """
     with jax.profiler.TraceAnnotation("gmres.solve"):
-        user_matvec = matvec
         if shard is not None:
             if driver != "device":
                 raise ValueError("shard= requires the device driver")
@@ -988,13 +1059,11 @@ def gmres(
                 reorder=reorder)
             res = _device_result(solve(*args))
         elif driver == "host":
-            plan, A, b, x0, accs, policy, _, matvec, precond, ortho = _prepare(
+            plan, A, b, x0, pipe = _prepare(
                 A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
                 matvec, target_rrn, reorder)
-            op_key, pins = _operator_key(A, user_matvec, plan)
-            res = _gmres_host(matvec, accs, policy, b, m, max_iters,
-                              target_rrn, eta, ortho, precond, x0=x0,
-                              op_key=op_key, pins=pins + (precond,))
+            res = _gmres_host(pipe, b, x0, m, max_iters, target_rrn, eta,
+                              (A, matvec, plan))
         else:
             raise ValueError(f"unknown driver {driver!r}")
         if plan is not None:
@@ -1003,9 +1072,12 @@ def gmres(
 
 
 def _prepare(A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
-             matvec, target_rrn, reorder):
-    """Plan (and permute into) the operator's coordinates, then resolve the
-    pipeline, for one unsharded solve."""
+             matvec, target_rrn, reorder, block=False):
+    """The one plan step of the unsharded entry points: plan (and permute
+    ``b``, ``x0`` and the preconditioner into) the operator's coordinates,
+    resolve the pipeline, and cast ``b`` and ``x0`` (zeros by default) to
+    the arithmetic dtype.  Returns ``(plan, A, b, x0, pipe)``, ``A`` the
+    operator the solve runs on."""
     plan = _plan_unsharded(A, reorder, matvec)
     if plan is not None:
         precond = _permuted_precond(precond, plan)
@@ -1013,11 +1085,11 @@ def _prepare(A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
         b = plan.permute(b)
         if x0 is not None:
             x0 = plan.permute(x0)
-    accs, policy, arith_dtype, mv, precond, ortho = _resolve(
-        A, b, storage, policy, m, arith_dtype, matvec, precond, ortho,
-        target_rrn)
-    return (plan, A, b.astype(arith_dtype), x0, accs, policy, arith_dtype,
-            mv, precond, ortho)
+    pipe = _resolve(A, b, storage, policy, m, arith_dtype, matvec, precond,
+                    ortho, target_rrn, block=block)
+    b = b.astype(pipe.accs[0].arith_dtype)
+    x0 = jnp.zeros_like(b) if x0 is None else x0.astype(b.dtype)
+    return plan, A, b, x0, pipe
 
 
 def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
@@ -1059,14 +1131,12 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
                 target_rrn=target_rrn, arith_dtype=arith_dtype, eta=eta)
             return solve, args, None
         with jax.profiler.TraceAnnotation("gmres.plan"):
-            (plan, A, b, x0, accs, policy, arith_dtype, mv, precond,
-             ortho) = _prepare(A, b, x0, storage, policy, precond, ortho, m,
-                               arith_dtype, matvec, target_rrn, reorder)
-            x0 = jnp.zeros_like(b) if x0 is None else x0.astype(arith_dtype)
+            plan, A, b, x0, pipe = _prepare(
+                A, b, x0, storage, policy, precond, ortho, m, arith_dtype,
+                matvec, target_rrn, reorder)
         with jax.profiler.TraceAnnotation("gmres.lookup"):
-            solve, operand = _cached_solve(A, matvec, False, mv, accs,
-                                           policy, m, max_iters, eta,
-                                           target_rrn, ortho, precond, plan)
+            solve, operand = _cached_solve((A, matvec, plan), pipe, False, m,
+                                           max_iters, eta, target_rrn)
         return solve, (b, x0, operand), plan
 
 
@@ -1175,23 +1245,11 @@ def gmres_batched(
                   matvec=matvec, driver="host", reorder=reorder)
             for i in range(B.shape[0])
         ]
-    user_matvec = matvec
-    plan = _plan_unsharded(A, reorder, user_matvec)
-    if plan is not None:
-        precond = _permuted_precond(precond, plan)
-        A = plan.operator
-        B = plan.permute(B)
-        if X0 is not None:
-            X0 = plan.permute(X0)
-    accs, policy, arith_dtype, matvec, precond, ortho = _resolve(
-        A, B[0], storage, policy, m, arith_dtype, matvec, precond, ortho,
-        target_rrn)
-    B = B.astype(arith_dtype)
-    X0 = jnp.zeros_like(B) if X0 is None else X0.astype(arith_dtype)
-
-    solve, operand = _cached_solve(A, user_matvec, True, matvec, accs,
-                                   policy, m, max_iters, eta, target_rrn,
-                                   ortho, precond, plan)
+    plan, A, B, X0, pipe = _prepare(A, B, X0, storage, policy, precond,
+                                    ortho, m, arith_dtype, matvec,
+                                    target_rrn, reorder)
+    solve, operand = _cached_solve((A, matvec, plan), pipe, True, m,
+                                   max_iters, eta, target_rrn)
     states = solve(B, X0, operand)
     k = B.shape[0]
     results = [
@@ -1224,9 +1282,9 @@ def build_device_solve(A, b, *, storage=None, policy=None, precond=None,
     identical to ``gmres(..., driver="device")`` minus jit, caching, and
     result trimming.
     """
-    accs, policy, _, matvec, precond, ortho = _resolve(
-        A, b, storage, policy, m, arith_dtype, matvec, precond, ortho,
-        target_rrn)
-    solve = _device_solve_fn(matvec, accs, policy, m, max_iters, eta,
-                             target_rrn, ortho, precond)
-    return solve, accs
+    pipe = _resolve(A, b, storage, policy, m, arith_dtype, matvec, precond,
+                    ortho, target_rrn)
+    solve = _device_solve_fn(pipe.matvec, pipe.accs, pipe.policy, m,
+                             max_iters, eta, target_rrn, pipe.ortho,
+                             pipe.precond)
+    return solve, pipe.accs

@@ -21,7 +21,7 @@ basis rows, the residual) is row-partitioned along the vector dim over a
     :func:`repro.sparse.shard.partition_matvec` consumes;
   * vector dims that do not divide the mesh are zero-padded to the next
     multiple (padded operator rows are masked, so the padded solve embeds
-    the original exactly); the returned ``x`` is trimmed back;
+    the original exactly); the program trims the returned ``x`` back;
   * the while_loop state's partition specs come from
     :func:`repro.dist.sharding.driver_partition_specs` — ``x`` sharded,
     history buffers and scalars replicated; each restart cycle allocates
@@ -39,6 +39,16 @@ same compressed transport the dots already use.
 ``gmres_batched(..., shard=...)`` composes the two scaling axes: the
 ``vmap`` over right-hand sides runs *inside* the ``shard_map``, so one XLA
 program advances ``k`` systems over ``P`` devices.
+
+One path builds every sharded solve: ``gmres(..., shard=P)``
+(:func:`sharded_gmres`, every sharding option) and the multi-chip layout
+of ``solve_program`` (:func:`sharded_program`: contiguous slabs, halo
+SpMV, plain transport) both plan, resolve the pipeline
+(:func:`repro.solver.gmres._resolve`) and look up one cached program in
+:func:`_sharded_setup`, built by :func:`_build_sharded_solve` as
+``solve(b, x0, operand)`` with ``x`` leaving the padded coordinates
+inside the program; both place ``b`` and ``x0`` by slab.  The same plan
+and pipeline therefore compile once, whichever entry point asks.
 """
 from __future__ import annotations
 
@@ -47,10 +57,8 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
 
-from repro.core.accessor import BasisAccessor, BlockBasisAccessor, ShardedFormat
-from repro.dist.context import DistContext
 from repro.dist.sharding import (
     block_driver_partition_specs,
     driver_partition_specs,
@@ -58,19 +66,11 @@ from repro.dist.sharding import (
 )
 from repro.solver.block import _block_device_solve_fn, _block_results
 from repro.solver.gmres import (
+    _cached,
     _device_result,
     _device_solve_fn,
-    _lru_cached,
-    _operator_key,
     _permuted_precond,
-)
-from repro.solver.pipeline import (
-    AdaptivePolicy,
-    StaticPolicy,
-    block_orthogonalizer_by_name,
-    orthogonalizer_by_name,
-    resolve_policy,
-    resolve_preconditioner,
+    _resolve,
 )
 from repro.sparse.csr import CSR
 from repro.sparse.plan import plan_operator
@@ -79,33 +79,6 @@ from repro.sparse.shard import partition_matvec
 __all__ = ["sharded_gmres", "sharded_program"]
 
 _TRANSPORTS = ("plain", "compressed", "compressed+norms")
-
-
-def _wrap_policy(policy, axis_name: str, compressed_dots: bool):
-    """Wrap every policy level in ShardedFormat.
-
-    The solve's ``shard_transport`` argument is the single authority on
-    the collective wire format: formats that arrive already sharded (e.g.
-    ``storage="sharded:frsz2_32"``, whose builder defaults to compressed
-    transport) are rebuilt onto the requested transport and axis, so
-    ``transport="plain"`` always means the documented exact-psum parity.
-    """
-
-    def wrap(fmt):
-        if isinstance(fmt, ShardedFormat):
-            fmt = fmt.inner
-        return ShardedFormat(inner=fmt, axis_name=axis_name,
-                             compressed_transport=compressed_dots)
-
-    fmts = tuple(wrap(f) for f in policy.formats())
-    if isinstance(policy, StaticPolicy):
-        return StaticPolicy(fmts[0])
-    if isinstance(policy, AdaptivePolicy):
-        return AdaptivePolicy(levels=fmts, thresholds=policy.thresholds)
-    raise ValueError(
-        f"cannot shard custom policy {type(policy).__name__}: give it "
-        "ShardedFormat levels explicitly")
-
 
 # one compiled shard_map program per (operator, pipeline, geometry, mesh);
 # the partitioned operand is cached alongside (ELL conversion is host work).
@@ -140,8 +113,9 @@ def sharded_gmres(A, b, *, batched: bool = False, x0=None, storage=None,
     All host-side operator prep — optional RCM reordering, padding
     geometry, bandwidth probing, matvec-mode arbitration — comes from one
     :class:`~repro.sparse.plan.OperatorPlan` (content-cached, so repeated
-    solves skip it); this driver only maps vectors through the plan and
-    splices its partition into ``shard_map``.
+    solves skip it).  The solve builds through :func:`_sharded_setup`, as
+    :func:`sharded_program` does, so the same plan and pipeline share one
+    compiled program.
     """
     if transport not in _TRANSPORTS:
         raise ValueError(f"unknown shard transport {transport!r}; "
@@ -149,8 +123,7 @@ def sharded_gmres(A, b, *, batched: bool = False, x0=None, storage=None,
     if method not in ("vmap", "block"):
         raise ValueError(f"unknown batched method {method!r}; "
                          f"expected one of ('vmap', 'block')")
-    block = method == "block"
-    if block and not batched:
+    if method == "block" and not batched:
         raise ValueError("method='block' needs batched=True (B is (p, n))")
     if matvec is not None:
         raise ValueError(
@@ -161,66 +134,18 @@ def sharded_gmres(A, b, *, batched: bool = False, x0=None, storage=None,
     if p_dev < 1 or p_dev > len(devices):
         raise ValueError(
             f"shard={p_dev} but only {len(devices)} devices are visible")
-
     b = jnp.asarray(b)
-    n = b.shape[-1]
-    plan, precond = _plan_and_precond(A, p_dev, reorder, partition_mode,
-                                      precond, pgrid)
-    if plan.n != n:
-        raise ValueError(f"b has trailing dim {n} but the operator "
-                         f"is {plan.n}x{plan.n}")
-    # vector dims that do not divide the mesh shard zero-padded: padded
-    # operator rows are masked (val 0), so every padded vector entry stays
-    # an exact zero through the whole solve and x trims back losslessly
-    n_pad, n_local = plan.n_pad, plan.n_local
-    if arith_dtype is None:
-        arith_dtype = b.dtype
+    if x0 is not None and jnp.shape(x0) != b.shape:
+        raise ValueError(f"x0 shape {jnp.shape(x0)} != b shape {b.shape}")
 
-    compressed_dots = transport in ("compressed", "compressed+norms")
-    policy = _wrap_policy(
-        resolve_policy(policy, storage, arith_dtype, target_rrn, m),
-        axis_name, compressed_dots)
-    if block:
-        p_rhs = int(b.shape[0])
-        accs = tuple(
-            BlockBasisAccessor(fmt=f, m=m + 1, p=p_rhs, n=n_local,
-                               arith_dtype=arith_dtype)
-            for f in policy.formats()
-        )
-        ortho_obj = block_orthogonalizer_by_name(ortho)
-    else:
-        accs = tuple(
-            BasisAccessor(fmt=f, m=m + 1, n=n_local,
-                          arith_dtype=arith_dtype)
-            for f in policy.formats()
-        )
-        ortho_obj = orthogonalizer_by_name(ortho)
-    precond_obj = resolve_preconditioner(precond, plan.operator).shard_local(
-        axis_name, n_local, n_pad)
-    dist = DistContext(axis_name=axis_name,
-                       compressed_norms=transport == "compressed+norms")
-
-    solve, operand = _cached_sharded_solve(
-        plan, batched, accs, policy, m, max_iters, eta, target_rrn,
-        ortho_obj, precond_obj, dist, axis_name, compressed_dots, method)
-
-    # embed() permutes into solve coordinates *and* zero-pads in one step
-    # (the block3d layout interleaves pad slots inside device chunks, so
-    # permute-then-tail-pad would scatter real entries into pad slots)
-    if x0 is None:
-        x0 = jnp.zeros(b.shape, b.dtype)
-    else:
-        x0 = jnp.asarray(x0)
-        if x0.shape != b.shape:
-            raise ValueError(f"x0 shape {x0.shape} != b shape {b.shape}")
-    b = plan.embed(b).astype(arith_dtype)
-    x0 = plan.embed(x0).astype(arith_dtype)
-
-    states = solve(operand, b, x0)
-    states = dict(states, x=plan.extract(states["x"]))
+    plan, pipe, solve, operand = _sharded_setup(
+        A, b, p_dev, reorder, partition_mode, pgrid, storage, policy,
+        precond, ortho, m, max_iters, target_rrn, arith_dtype, eta,
+        transport, axis_name, method, batched)
+    states = solve(*_place(plan, pipe, b, x0, axis_name, batched), operand)
     if not batched:
         return _device_result(states)
-    if block:
+    if method == "block":
         return _block_results(states)
     return [
         _device_result(jax.tree.map(lambda a: a[i], states))
@@ -241,61 +166,74 @@ def sharded_program(A, b, n_shards: int, *, x0=None, storage=None,
     The operator is planned as ``n_shards`` contiguous slabs of rows in
     its own order (no reordering, no 3-D blocks; rows zero-padded to a
     multiple of ``n_shards``), with the halo SpMV
-    (:func:`repro.sparse.shard.partition_matvec`).  ``args`` are ``(b,
+    (:func:`repro.sparse.shard.partition_matvec`) and plain transport;
+    the program is ``gmres(A, b, shard=n_shards, shard_matvec="halo",
+    reorder="none")``'s, from the same cache entry.  ``args`` are ``(b,
     x0, operand)``: ``b`` and ``x0`` padded and placed by slab, the
     operand the cached slabs of the operator, placed from the host; the
     CSR's own arrays then move to the host
     (:meth:`~repro.sparse.csr.CSR.to_host`), so that no chip holds the
-    whole operator.  The state's ``x`` is in the operator's order, trimmed
-    to ``n`` inside the program; each chip allocates its slab of the
-    Krylov store inside the program.
+    whole operator.  The state's ``x`` is in the operator's order; each
+    chip allocates its slab of the Krylov store inside the program.
 
-    Host spans (inside ``gmres.solve_program``): ``gmres.layout`` around
-    the placement of ``b`` and ``x0``, ``gmres.plan`` around the plan and
-    the pipeline, ``gmres.lookup`` around the compiled-solve cache.
+    Host spans (inside ``gmres.solve_program``): ``gmres.plan`` around the
+    plan and the pipeline, ``gmres.lookup`` around the compiled-solve
+    cache, ``gmres.layout`` around the placement of ``b`` and ``x0``.
     """
-    with jax.profiler.TraceAnnotation("gmres.plan"):
-        plan, precond = _plan_and_precond(A, n_shards, "none", "halo",
-                                          precond)
-        if arith_dtype is None:
-            arith_dtype = b.dtype
-        policy, accs, ortho_obj, precond_obj, dist = _slab_pipeline(
-            plan.operator, plan.n_local, plan.n_pad, storage, policy,
-            precond, ortho, m, target_rrn, arith_dtype, axis_name)
-    with jax.profiler.TraceAnnotation("gmres.lookup"):
-        solve, operand = _cached_sharded_solve(
-            plan, False, accs, policy, m, max_iters, eta, target_rrn,
-            ortho_obj, precond_obj, dist, axis_name, False, "vmap",
-            program=True)
-        if isinstance(A, CSR):
-            A.to_host()          # each chip holds its slab: free the rest
+    plan, pipe, solve, operand = _sharded_setup(
+        A, b, n_shards, "none", "halo", None, storage, policy, precond,
+        ortho, m, max_iters, target_rrn, arith_dtype, eta, "plain",
+        axis_name)
+    if isinstance(A, CSR):
+        A.to_host()              # each chip holds its slab: free the rest
     with jax.profiler.TraceAnnotation("gmres.layout"):
-        mesh = Mesh(np.asarray(jax.devices()[:n_shards]), (axis_name,))
-        slabs = NamedSharding(mesh, vector_partition_spec(axis_name))
-        b = jax.device_put(plan.embed(b).astype(arith_dtype), slabs)
-        if x0 is None:
-            x0 = jnp.zeros(plan.n_pad, arith_dtype, device=slabs)
-        else:
-            x0 = jax.device_put(plan.embed(x0).astype(arith_dtype), slabs)
-    return solve, (b, x0, operand)
+        return solve, _place(plan, pipe, b, x0, axis_name) + (operand,)
 
 
-def _slab_pipeline(operator, n_local, n_pad, storage, policy, precond,
-                   ortho, m, target_rrn, arith_dtype, axis_name):
-    """The pipeline of :func:`sharded_program` over slabs of ``n_local``
-    rows: the policy's formats sharded (plain transport), their accessors,
-    the orthogonalizer, the preconditioner's local part and the norms'
-    context."""
-    policy = _wrap_policy(
-        resolve_policy(policy, storage, arith_dtype, target_rrn, m),
-        axis_name, False)
-    accs = tuple(BasisAccessor(fmt=f, m=m + 1, n=n_local,
-                               arith_dtype=arith_dtype)
-                 for f in policy.formats())
-    precond = resolve_preconditioner(precond, operator).shard_local(
-        axis_name, n_local, n_pad)
-    return (policy, accs, orthogonalizer_by_name(ortho), precond,
-            DistContext(axis_name=axis_name))
+def _sharded_setup(A, b, n_shards, reorder, partition_mode, pgrid, storage,
+                   policy, precond, ortho, m, max_iters, target_rrn,
+                   arith_dtype, eta, transport, axis_name, method="vmap",
+                   batched=False):
+    """The one set-up of a sharded solve: the plan, the pipeline over the
+    plan's slabs (:func:`repro.solver.gmres._resolve`) and the cached
+    program ``solve(b, x0, operand)``.  Returns ``(plan, pipe, solve,
+    operand)``."""
+    with jax.profiler.TraceAnnotation("gmres.plan"):
+        plan, precond = _plan_and_precond(A, n_shards, reorder,
+                                          partition_mode, precond, pgrid)
+        if plan.n != b.shape[-1]:
+            raise ValueError(f"b has trailing dim {b.shape[-1]} but the "
+                             f"operator is {plan.n}x{plan.n}")
+        pipe = _resolve(plan.operator, b, storage, policy, m, arith_dtype,
+                        None, precond, ortho, target_rrn,
+                        block=method == "block", axis_name=axis_name,
+                        rows=(plan.n_local, plan.n_pad), transport=transport)
+    with jax.profiler.TraceAnnotation("gmres.lookup"):
+        solve, operand, _ = _cached(
+            _SHARDED_CACHE, _SHARDED_CACHE_SIZE, (plan.operator, None, plan),
+            pipe, ("sharded", batched, method, m, max_iters, float(eta),
+                   float(target_rrn), plan.n_shards, axis_name, transport),
+            lambda: _build_sharded_solve(plan, pipe, batched, method, m,
+                                         max_iters, eta, target_rrn,
+                                         axis_name, transport != "plain"))
+    return plan, pipe, solve, operand
+
+
+def _place(plan, pipe, b, x0, axis_name, batched=False):
+    """``b`` and ``x0`` (zeros by default) in the solve's coordinates and
+    arithmetic dtype, placed by slab.
+
+    ``embed()`` permutes into solve coordinates *and* zero-pads in one
+    step (the block3d layout interleaves pad slots inside device chunks,
+    so permute-then-tail-pad would scatter real entries into pad slots).
+    """
+    ad = pipe.accs[0].arith_dtype
+    mesh = Mesh(np.asarray(jax.devices()[:plan.n_shards]), (axis_name,))
+    slabs = NamedSharding(mesh, vector_partition_spec(axis_name, batched))
+    b = jax.device_put(plan.embed(b).astype(ad), slabs)
+    if x0 is None:
+        return b, jnp.zeros(b.shape, ad, device=slabs)
+    return b, jax.device_put(plan.embed(x0).astype(ad), slabs)
 
 
 def _plan_and_precond(A, p_dev, reorder, partition_mode, precond,
@@ -328,9 +266,12 @@ def _plan_and_precond(A, p_dev, reorder, partition_mode, precond,
         return plan, _permuted_precond(precond, plan)
 
 
-def _build_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
-                         target_rrn, ortho, precond, dist, axis_name,
-                         compressed_halo, method):
+def _build_sharded_solve(plan, pipe, batched, method, m, max_iters, eta,
+                         target_rrn, axis_name, compressed_halo):
+    """Partition the operator over the plan's slabs and build the program
+    ``solve(b, x0, operand) -> state``, the state's ``x`` in the
+    operator's order (``plan.extract`` inside the program).  Returns
+    ``(solve, operand)``."""
     mesh = Mesh(np.asarray(jax.devices()[:plan.n_shards]), (axis_name,))
     operand, op_specs, local_mv = partition_matvec(
         plan=plan, axis_name=axis_name, mesh=mesh,
@@ -339,10 +280,16 @@ def _build_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
     # matvecs; the explicit residual recomputations always ride an exact
     # exchange, else the codec error floors the attainable rrn (same split
     # as lossy basis storage vs exact arithmetic in CB-GMRES itself)
-    sm = _sharded_fn(mesh, op_specs, local_mv, local_mv.exact, batched,
-                     accs, policy, m, max_iters, eta, target_rrn, ortho,
-                     precond, dist, axis_name, method)
-    return jax.jit(sm), operand
+    run = jax.jit(_sharded_fn(mesh, op_specs, local_mv, local_mv.exact,
+                              batched, pipe.accs, pipe.policy, m, max_iters,
+                              eta, target_rrn, pipe.ortho, pipe.precond,
+                              pipe.dist, axis_name, method))
+
+    def solve(b, x0, op):
+        state = run(op, b, x0)
+        return dict(state, x=plan.extract(state["x"]))
+
+    return jax.jit(solve), operand
 
 
 def _sharded_fn(mesh, op_specs, local_mv, local_rmv, batched, accs, policy,
@@ -390,43 +337,3 @@ def _sharded_fn(mesh, op_specs, local_mv, local_rmv, batched, accs, policy,
                          in_specs=(op_specs, vec_spec, vec_spec),
                          out_specs=state_specs, axis_names={axis_name},
                          check_vma=False)
-
-
-def _cached_sharded_solve(plan, batched, accs, policy, m, max_iters, eta,
-                          target_rrn, ortho, precond, dist, axis_name,
-                          compressed_halo, method, program: bool = False):
-    """The compiled sharded solve and its operand (cached): ``solve(operand,
-    b, x0)``, or with ``program`` ``solve(b, x0, operand)`` with ``x``
-    trimmed to ``n`` inside, as :func:`sharded_program` returns it."""
-    pins: tuple = ()
-
-    def make_key():
-        nonlocal pins
-        # the plan's key already folds in the operator content fingerprint,
-        # the executed reorder, and the resolved matvec mode; operators
-        # without a fingerprint fall back to identity keying (pinned)
-        op_key, pins = _operator_key(plan.operator, None, plan)
-        pins = pins + (precond,)
-        return (op_key, batched, method, getattr(accs[0], "p", 0),
-                policy.spec(), ortho.name, precond.spec(),
-                dist.spec(), accs[0].m, accs[0].n,
-                jnp.dtype(accs[0].arith_dtype).name, m, max_iters,
-                float(eta), float(target_rrn), plan.n_shards, axis_name,
-                compressed_halo, program)
-
-    def build():
-        solve, operand = _build_sharded_solve(
-            plan, batched, accs, policy, m, max_iters, eta, target_rrn,
-            ortho, precond, dist, axis_name, compressed_halo, method)
-        if program:
-            run, n = solve, plan.n
-
-            def solve(b, x0, op):
-                state = run(op, b, x0)
-                return dict(state, x=state["x"][:n])
-
-            solve = jax.jit(solve)
-        return solve, operand, pins
-
-    ent = _lru_cached(_SHARDED_CACHE, _SHARDED_CACHE_SIZE, make_key, build)
-    return ent[0], ent[1]

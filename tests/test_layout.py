@@ -169,6 +169,24 @@ for s in (16, 15):
         it=res.iterations, it_one=one.iterations,
         host_csr=host_csr,
         one_device=len(args1[0].sharding.device_set) == 1))
+
+# one path: gmres(shard=P) on solve_program's slab plan builds through the
+# same sharded builder and runs solve_program's cached program
+from repro.solver import sharded
+A, _ = make_problem("synth:atmosmod", 12 ** 3)
+b = jnp.asarray(reference.matvec64(*host(A),
+                                   rng.standard_normal(A.shape[0])))
+layout.bytes_limit = lambda device: 1000
+sharded._SHARDED_CACHE.clear()
+solve, args, _ = solve_program(A, b, **kw)
+res = _device_result(solve(*args))
+forced = gmres(A, b, shard=len(jax.devices()), shard_matvec="halo",
+               reorder="none", **kw)
+out["one_path"] = dict(
+    entries=len(sharded._SHARDED_CACHE), it=res.iterations,
+    it_forced=forced.iterations,
+    same_x=bool(np.array_equal(np.asarray(res.x), np.asarray(forced.x))))
+layout.bytes_limit = lambda device: None
 print(json.dumps(out))
 """
 
@@ -211,3 +229,13 @@ def test_solve_program_runs_on_every_device_past_the_limit(multidevice):
         assert case["rrn"] < 1e-10 and case["rrn_gmres"] < 1e-10, case
         assert abs(case["it"] - case["it_one"]) <= 1, case
         assert case["one_device"], case
+
+
+def test_gmres_shard_and_solve_program_share_one_program(multidevice):
+    """``gmres(shard=P)`` with ``solve_program``'s slab plan (halo SpMV, no
+    reordering) reuses the program ``solve_program`` built: one cache
+    entry, the same iterations, the same ``x`` bit for bit."""
+    case = multidevice["one_path"]
+    assert case["entries"] == 1, case
+    assert case["it"] == case["it_forced"], case
+    assert case["same_x"], case

@@ -410,6 +410,46 @@ def test_solve_cache_eviction_is_bounded():
         assert len(_SOLVE_CACHE) <= _SOLVE_CACHE_SIZE
 
 
+def _solve_through(cache, A, b, **kw):
+    """One solve through the entry point that fills ``cache``."""
+    from repro.solver.block import gmres_block
+
+    if cache == "block":
+        return gmres_block(A, jnp.stack([b, 2.0 * b]), **kw)
+    if cache == "host":
+        return gmres(A, b, driver="host", **kw)
+    if cache == "sharded":
+        return gmres(A, b, shard=1, **kw)
+    return gmres(A, b, **kw)
+
+
+@pytest.mark.parametrize("cache, module, name", [
+    ("device", "repro.solver.gmres", "_SOLVE_CACHE"),
+    ("block", "repro.solver.gmres", "_SOLVE_CACHE"),
+    ("host", "repro.solver.gmres", "_HOST_KERNEL_CACHE"),
+    ("sharded", "repro.solver.sharded", "_SHARDED_CACHE"),
+])
+def test_compiled_solve_key_holds_the_pipeline(cache, module, name):
+    """Every compiled-solve cache keys on the whole resolved pipeline: an
+    identical second solve adds no entry, and a solve that differs in one
+    pipeline field (the orthogonalizer, the target) adds exactly one."""
+    import importlib
+
+    entries = getattr(importlib.import_module(module), name)
+    A, _ = make_problem("synth:atmosmod", 64)
+    b, _ = rhs_for(A)
+    kw = dict(m=4, max_iters=8, target_rrn=1e-30)
+    entries.clear()
+    _solve_through(cache, A, b, **kw)
+    assert len(entries) == 1
+    _solve_through(cache, A, b, **kw)
+    assert len(entries) == 1
+    _solve_through(cache, A, b, **dict(kw, ortho="cgs2"))
+    assert len(entries) == 2
+    _solve_through(cache, A, b, **dict(kw, target_rrn=1e-29))
+    assert len(entries) == 3
+
+
 def test_fingerprint_distinguishes_content():
     A0, _ = make_problem("synth:atmosmod", 64)
     from repro.sparse.csr import CSR

@@ -208,10 +208,13 @@ def _chip_bytes(compiled) -> int:
 
 
 def _float32_pipeline(n_local):
-    from repro.solver.sharded import _slab_pipeline
+    """The pipeline of the four-chip cell's slabs, from the one resolver."""
+    from repro.solver.gmres import _resolve
 
-    return _slab_pipeline(None, n_local, n_local * 4, "float32", None,
-                          None, "mgs", 100, 1.15e-6, jnp.float32, "basis")
+    b = jax.ShapeDtypeStruct((n_local * 4,), jnp.float32)
+    pipe = _resolve(None, b, "float32", None, 100, None, None, None, "mgs",
+                    1.15e-6, axis_name="basis", rows=(n_local, n_local * 4))
+    return pipe.policy, pipe.accs, pipe.ortho, pipe.precond, pipe.dist
 
 
 def test_four_chip_program_at_336_cubed_fits_each_chip(four_chips,
