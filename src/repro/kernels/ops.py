@@ -127,47 +127,50 @@ def decompress(bc: F.BlockCompressed, *, interpret: bool | None = None) -> jax.A
 # ---------------------------------------------------------------------------
 
 
-# A whole reduction axis up to this size runs as ONE kernel tile (a single
-# MXU contraction, no cross-tile accumulation).  Longer axes tile at the
-# largest multiple of ``128 * bs`` (lane-aligned exponent tiles) up to 4096
-# values, or at ``128 * bs`` itself when that is larger.
+# A whole reduction axis up to this size runs as ONE kernel tile (its
+# chunks unrolled, no cross-tile accumulation).  Longer axes tile at the
+# largest multiple of ``128 * bs`` (lane-aligned exponent tiles) up to
+# ``TILE_N`` values and to what ``TILE_BYTES`` holds at 128 rows, or at
+# ``128 * bs`` itself when that is larger.  ``TILE_N`` keeps the grid
+# short: at the 108^3 basis (101 rows of 1,259,712 16-bit codes, bs 32) a
+# pass runs 77 grid steps of 3.3 MB of codes, where a 4096-lane tile ran
+# 308.  Timed on one TPU v5e at that basis (dots / combine, ms a pass, a
+# loop's overhead included): 4096 lanes 0.459 / 0.452, 8192 0.418 /
+# 0.417, 16384 0.404 / 0.400, 24576 0.461 / 0.486 and 32768 0.459 / 0.482
+# (past 16384 the byte budget halves the row tile, so a pass runs two).
 MAX_SINGLE_TILE = 8192
-#: f32 bytes of one decoded tile; sizes the row tile (VMEM budget).
+TILE_N = 16384
+#: bytes of one code tile; sizes the tiles within the chip's scoped VMEM
+#: (inputs are double-buffered, and a chunk decodes in registers).
 TILE_BYTES = 4 * 1024 * 1024
 
 
-def _tile_n(n: int, bs: int) -> int:
+def _tile_n(n: int, bs: int, item: int) -> int:
     unit = LANES * bs
     if n <= max(MAX_SINGLE_TILE, unit):
         return n
-    return unit * max(1, 4096 // unit)
+    lanes = min(TILE_N, n, TILE_BYTES // (item * LANES))
+    return unit * max(1, lanes // unit)
 
 
-def _tile_m(m: int, bn: int, align: int) -> int:
-    """Rows per tile: all ``m`` when the decoded tile fits the budget, else
-    the largest multiple of ``align`` that does."""
-    cap = max(align, TILE_BYTES // (4 * bn) // align * align)
+def _tile_m(m: int, bn: int, align: int, item: int) -> int:
+    """Rows per tile: all ``m`` when a tile of ``item``-byte elements fits
+    the budget, else the largest multiple of ``align`` that does."""
+    cap = max(align, TILE_BYTES // (item * bn) // align * align)
     return m if m <= cap else cap
 
 
 @functools.lru_cache(maxsize=4096)
-def _dot_layout(m: int, n: int, bs: int) -> tuple[int, int]:
-    """``(bm, bn)`` of the dots kernel over an ``(m, n)`` code matrix.
+def _dot_layout(m: int, n: int, bs: int, item: int) -> tuple[int, int]:
+    """``(bm, bn)`` of the fused contractions over an ``(m, n)`` matrix of
+    ``item``-byte codes.
 
     Row tiles are multiples of 32 (the sublane tile of 8-bit codes).
     Memoized on the shape key: repeated same-shape solves — every warm
     GMRES cycle — skip the host-side tile arithmetic entirely.
     """
-    bn = _tile_n(n, bs)
-    return _tile_m(m, bn, 32), bn
-
-
-@functools.lru_cache(maxsize=4096)
-def _reduce_layout(m: int, n: int, bs: int) -> tuple[int, int]:
-    """``(bm, bn)`` of the combine kernel: its row tile is also the lane
-    dim of the coefficient block, so a partial row tile is 128-aligned."""
-    bn = _tile_n(n, bs)
-    return _tile_m(m, bn, LANES), bn
+    bn = _tile_n(n, bs, item)
+    return _tile_m(m, bn, 32, item), bn
 
 
 def _basis_2d(bc: F.BlockCompressed):
@@ -177,13 +180,13 @@ def _basis_2d(bc: F.BlockCompressed):
 
 
 def _dots(codes, exps, W, spec, interpret):
-    bm, bn = _dot_layout(*codes.shape, spec.bs)
+    bm, bn = _dot_layout(*codes.shape, spec.bs, codes.dtype.itemsize)
     return KD.dots_2d(codes, exps, W.astype(spec.dtype), spec, bm=bm, bn=bn,
                       interpret=_resolve_interpret(interpret))
 
 
 def _combine(codes, exps, Y, spec, interpret):
-    bm, bn = _reduce_layout(*codes.shape, spec.bs)
+    bm, bn = _dot_layout(*codes.shape, spec.bs, codes.dtype.itemsize)
     return KD.combine_2d(codes, exps, Y.astype(spec.dtype), spec, bm=bm,
                          bn=bn, interpret=_resolve_interpret(interpret))
 

@@ -38,20 +38,20 @@ def decompress_ref(codes: jax.Array, exps: jax.Array, spec: F.FrszSpec,
 
 
 def matvec_ref(codes, exps, x, spec: F.FrszSpec):
-    """y[i] = sum_j decompress(V)[i, j] * x[j].
+    """y[i] = sum_j decompress(V)[i, j] * x[j], contracted in float64.
 
     codes: (m, nb, bs); exps: (m, nb); x: (nb*bs,)  ->  y: (m,)
     """
-    V = decompress_ref(codes, exps, spec)  # (m, n_pad)
+    V = decompress_ref(codes, exps, spec).astype(jnp.float64)  # (m, n_pad)
     return V @ x.astype(V.dtype)
 
 
 def rmatvec_ref(codes, exps, h, spec: F.FrszSpec):
-    """y[j] = sum_i h[i] * decompress(V)[i, j].
+    """y[j] = sum_i h[i] * decompress(V)[i, j], contracted in float64.
 
     codes: (m, nb, bs); exps: (m, nb); h: (m,)  ->  y: (nb*bs,)
     """
-    V = decompress_ref(codes, exps, spec)
+    V = decompress_ref(codes, exps, spec).astype(jnp.float64)
     return h.astype(V.dtype) @ V
 
 

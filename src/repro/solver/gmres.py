@@ -109,7 +109,7 @@ from repro.solver.pipeline import (
     resolve_policy,
     resolve_preconditioner,
 )
-from repro.sparse.csr import operator_matvec
+from repro.sparse.csr import CSR, operator_matvec
 
 __all__ = ["GmresResult", "gmres", "gmres_batched", "cb_gmres", "solve_program"]
 
@@ -1110,7 +1110,10 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
     program; one that does not runs on every local chip
     (:func:`repro.solver.sharded.sharded_program`), behind the same
     contract: ``plan`` is ``None``, the state's ``x`` is in the operator's
-    order.
+    order.  Either way a CSR's own arrays then move to the host
+    (:meth:`~repro.sparse.csr.CSR.to_host`) where the operand holds the
+    operator in another form (its slabs, or its DIA form on one chip), so
+    that no device holds the operator twice.
 
     Host spans: ``gmres.solve_program`` around it all, ``gmres.layout``
     around the choice of chips (and on several, the placement of ``b``),
@@ -1137,6 +1140,9 @@ def solve_program(A, b, *, x0=None, storage=None, policy=None, precond=None,
         with jax.profiler.TraceAnnotation("gmres.lookup"):
             solve, operand = _cached_solve((A, matvec, plan), pipe, False, m,
                                            max_iters, eta, target_rrn)
+        if (operand is not None and isinstance(A, CSR)
+                and A.to_dia() is not None):
+            A.to_host()          # the operand is its DIA form: free the CSR
         return solve, (b, x0, operand), plan
 
 
@@ -1146,7 +1152,6 @@ def _layout(A, b, storage, policy, m, arith_dtype, matvec, target_rrn,
     operator can be split by rows (a CSR, no user ``matvec``, no forced
     reordering) and the one-device solve would not fit a chip."""
     from repro.solver.layout import solve_chips
-    from repro.sparse.csr import CSR
 
     if matvec is not None or reorder == "rcm" or not isinstance(A, CSR):
         return 1

@@ -95,12 +95,12 @@ class CSR:
 
     def to_host(self) -> None:
         """Move this operator's arrays to the host (numpy), freeing their
-        device copies and its cached DIA form's.  A solve across chips
-        calls it once each chip holds its slab, so that no chip holds the
-        whole operator; every method works on host arrays."""
+        device copies; a cached DIA form (:meth:`to_dia`) stays.  A solve
+        calls it once its operand holds the operator in another form (each
+        chip its slab, or one chip the DIA form), so that no device holds
+        the operator twice; every method works on host arrays."""
         self.indptr, self.indices, self.data = (
             np.asarray(a) for a in (self.indptr, self.indices, self.data))
-        self.__dict__.pop("_dia", None)
 
     def bandwidth(self) -> int:
         """max |col - row| over nonzero entries (host-side, cached).

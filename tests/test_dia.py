@@ -130,6 +130,29 @@ def test_second_solve_program_reuses_the_dia_and_skips_row_ids(monkeypatch):
     assert solve2(*args)["x"].shape == b.shape
 
 
+@pytest.mark.parametrize("name,freed", [("synth:atmosmod", True),
+                                        ("synth:unstructured", False)])
+def test_solve_program_frees_the_csr_its_dia_form_replaces(name, freed):
+    """Where the solve's operand is the DIA form, the CSR's own arrays move
+    to the host, so the device holds the operator once, and the next solve
+    reuses the program and the DIA form; where the SpMV reads the CSR, its
+    arrays stay on the device."""
+    A, _ = make_problem(name, 8 ** 3)
+    b = A.matvec(jnp.asarray(_x(A.shape[0])))
+    kw = dict(m=20, max_iters=200, target_rrn=1e-10)
+    solve1, args1, _ = solve_program(A, b, **kw)
+    arrays = (A.indptr, A.indices, A.data)
+    assert [isinstance(a, np.ndarray) for a in arrays] == [freed] * 3
+    assert [isinstance(a, jax.Array) for a in arrays] == [not freed] * 3
+    if freed:
+        dia = A.to_dia()
+        assert args1[2].args[0] is dia
+        solve2, args2, _ = solve_program(A, b, **kw)
+        assert solve2 is solve1 and A.to_dia() is dia
+        np.testing.assert_array_equal(np.asarray(solve2(*args2)["x"]),
+                                      np.asarray(solve1(*args1)["x"]))
+
+
 def test_device_solves_agree_on_both_paths():
     A, _ = make_problem("synth:atmosmod", 16 ** 3)
     x_sol = _x(A.shape[0])
